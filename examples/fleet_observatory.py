@@ -25,10 +25,6 @@ import urllib.request
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from examples._cpu_pin import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import numpy as np
 
 

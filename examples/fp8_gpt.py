@@ -13,10 +13,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from examples._cpu_pin import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import numpy as np
 
 import paddle_tpu as paddle

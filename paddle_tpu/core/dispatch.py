@@ -478,8 +478,8 @@ def _cell_key(v, _seen=None):
     op to the eager-vjp path."""
     if v is None or isinstance(v, (bool, int, float, complex, str, bytes)):
         return v
-    if isinstance(v, np.dtype):
-        return v
+    if isinstance(v, (np.dtype, jax.sharding.Mesh)):
+        return v  # immutable, hashed by value
     if isinstance(v, type) and issubclass(v, (np.generic, bool, int,
                                               float, complex)):
         # dtype-like classes only (jnp.float32 etc). An arbitrary class

@@ -106,8 +106,8 @@ define_flag("FLAGS_benchmark", False, "per-op timing")
 define_flag("FLAGS_use_stride_kernel", True, "strided view kernels")
 define_flag("FLAGS_eager_defer", True,
             "batch consecutive no-grad elementwise eager ops into one "
-            "jitted dispatch (core/deferred.py) — hides per-op transport "
-            "RTT on remote-attached devices")
+            "jitted dispatch (core/deferred.py) — one dispatch per "
+            "chain instead of one per op")
 define_flag("FLAGS_deferred_passes",
             os.environ.get("PADDLE_TPU_PASSES", "1").lower()
             not in ("0", "false", "off", "no"),
@@ -411,8 +411,8 @@ define_flag("FLAGS_serving_mesh", "",
             "e.g. '1x8' tensor-parallels the served Llama over 8 "
             "devices — attention heads, MLP hidden dims and the paged "
             "KV pool's kv-head axis shard along the model axis via "
-            "NamedSharding (shard_map attention where "
-            "capability.has_jax_shard_map), while the data axis "
+            "NamedSharding (decode attention under an explicit "
+            "jax.shard_map), while the data axis "
             "partitions scheduler slots/blocks into capacity slices. "
             "Axis sizes must divide jax.device_count() and the model "
             "axis must divide num_heads/num_kv_heads/intermediate_size "

@@ -6,8 +6,7 @@ from .api import (  # noqa: F401
     shard_tensor,
 )
 from .capability import (  # noqa: F401
-    has_jax_shard_map, has_multiprocess_collectives,
-    has_partitioning_sharding_rule, has_pinned_host_memory,
+    has_multiprocess_collectives, has_pinned_host_memory,
 )
 from .collective import (  # noqa: F401
     Group, ReduceOp, all_gather, all_gather_object, all_reduce, all_to_all,

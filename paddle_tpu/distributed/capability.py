@@ -1,50 +1,16 @@
-"""Runtime capability probes for the distributed surface.
+"""Backend capability probes for the distributed surface.
 
-Feature detection, NOT version pins: jax moves APIs between releases
-(``jax.shard_map`` graduated from ``jax.experimental``; host-pinned
-memory kinds appear per backend), and a version comparison would rot the
-moment a distro backports or renames. Each probe answers "can THIS
-runtime do it" by looking for the feature itself, and callers — the
-shard_map-dependent distributed tests above all — skip as "capability
-absent" instead of failing as noise when it is missing.
+What a backend can do differs between the CPU the tests run on and the
+TPU (multi-controller collectives, host-pinned memory). Each probe
+answers "can THIS backend do it" by looking for the feature itself, and
+the tests that need it skip as "capability absent" where it is missing.
+The jax API itself is not probed: the repo targets the one installed
+jax.
 """
 
 from __future__ import annotations
 
-__all__ = [
-    "has_jax_shard_map", "has_pinned_host_memory",
-    "has_partitioning_sharding_rule", "has_multiprocess_collectives",
-]
-
-
-def has_jax_shard_map():
-    """True when the runtime jax exposes the stable ``jax.shard_map``
-    entry point (with its current kwargs surface, e.g. ``check_vma``)
-    that paddle_tpu.distributed.pipeline / ring_attention and their
-    tests drive. Older jax raises a deprecation-shim AttributeError
-    here, which is exactly the condition tier-1 should SKIP on rather
-    than fail on."""
-    import jax
-
-    try:
-        return callable(getattr(jax, "shard_map", None))
-    except Exception:  # noqa: BLE001 — deprecation shims raise on getattr
-        return False
-
-
-def has_partitioning_sharding_rule():
-    """True when ``custom_partitioning.def_partition`` accepts the
-    ``sharding_rule`` kwarg the Pallas flash-attention GSPMD rules pass
-    (kernels/pallas/flash_attention.py) — probed from the actual call
-    signature, so a backport or rename is detected either way."""
-    import inspect
-
-    try:
-        from jax.experimental.custom_partitioning import custom_partitioning
-        sig = inspect.signature(custom_partitioning.def_partition)
-        return "sharding_rule" in sig.parameters
-    except Exception:  # noqa: BLE001 — absent API means absent feature
-        return False
+__all__ = ["has_pinned_host_memory", "has_multiprocess_collectives"]
 
 
 def has_multiprocess_collectives():

@@ -79,6 +79,15 @@ class ShardedTrainStep(TrainStep):
         assert offload in (None, "os", "os+params"), offload
         self._offload = offload
 
+    def _kernel_mesh(self):
+        """Pallas kernels in the step run under shard_map over this mesh,
+        the batch split as the data placements split dim 0."""
+        from ..kernels.on_mesh import kernel_mesh
+        batch_axes = [name for name, pl in zip(self._mesh.dim_names,
+                                               self._data_placements)
+                      if pl.is_shard(0)]
+        return kernel_mesh(self._mesh.jax_mesh, batch_axes)
+
     def _out_shardings(self):
         """Pin updated params (and their slots) to their declared
         placements so a step never silently re-lays-out the model; loss /
@@ -158,8 +167,9 @@ class ShardedTrainStep(TrainStep):
                     p._data,
                     p._data.sharding.with_memory_kind("pinned_host")))
 
-    def __call__(self, *batch):
-        # place params (idempotent: already committed), slots, and batch
+    def _place(self, batch):
+        """Place params (idempotent: already committed), slots, and the
+        batch on the mesh; returns the placed batch."""
         for _, p in self._params:
             if p._dist_attr is not None:
                 self._place_slots(p)
@@ -170,6 +180,15 @@ class ShardedTrainStep(TrainStep):
             sharding = named_sharding(self._mesh, self._data_placements,
                                       t.ndim)
             placed.append(Tensor(jax.device_put(t._data, sharding)))
+        return placed
+
+    def lower(self, *batch):
+        placed = self._place(batch)
+        with self._mesh.jax_mesh:
+            return super().lower(*placed)
+
+    def __call__(self, *batch):
+        placed = self._place(batch)
         with self._mesh.jax_mesh:
             out = super().__call__(*placed)
         self._flush_to_host()

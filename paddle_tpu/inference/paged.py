@@ -110,16 +110,12 @@ def kernel_route(mode=None):
     m = resolve_paged_kernel(mode)
     if m == "dense":
         return "dense"
-    try:
-        cpu = jax.default_backend() == "cpu"
-    except RuntimeError:  # pragma: no cover
-        cpu = True
     if m == "pallas":
         # the kernels' own interpret pick (PADDLE_PALLAS_FORCE_COMPILE
         # forces real Mosaic lowering even on a CPU host)
-        from ..kernels.pallas.paged_attention import _interpret
+        from ..kernels.pallas.flash_attention import _interpret
         return "interpret" if _interpret() else "pallas"
-    return "dense" if cpu else "pallas"
+    return "dense" if jax.default_backend() == "cpu" else "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -338,9 +334,8 @@ class PagedKVCache:
         # valid indices; the length mask hides its contents
         self._free = list(range(num_blocks - 1, 0, -1))
         # HOST-side metadata (numpy, not device arrays): block tables and
-        # lengths mutate every step from python, and on a remote-attached
-        # chip every .at[].set / device fetch is a transport round trip.
-        # They upload as (tiny) jit-call arguments instead.
+        # lengths mutate every step from python; they upload as (tiny)
+        # jit-call arguments instead of paying a device .at[].set each.
         self.block_tables = np.zeros((max_batch, max_blocks_per_seq),
                                      np.int32)
         self.seq_lens = np.zeros((max_batch,), np.int32)
@@ -1101,9 +1096,8 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, seq_lens,
     embarrassingly parallel over heads (GQA groups never cross a
     kv-head), so the body needs NO collective; the all_gather /
     psum_scatter pair lives at the o_proj boundary, where GSPMD puts
-    it. Only called when ``capability.has_jax_shard_map`` (the stable
-    entry point) — everywhere else the same layout rides NamedSharding
-    inputs + GSPMD propagation (``ServingMesh.shard_map_armed``)."""
+    it. Called whenever the mesh's model axis splits the heads
+    (``ServingMesh.shard_map_armed``)."""
     from jax.sharding import PartitionSpec as P
 
     jm = mesh.jax_mesh
@@ -1123,7 +1117,7 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, seq_lens,
         f = jax.shard_map(local, mesh=jm,
                           in_specs=(head, pool, pool, srow, srow,
                                     rep, rep),
-                          out_specs=head)
+                          out_specs=head, check_vma=False)
         return f(q, k_pool, v_pool, k_scale, v_scale, block_tables,
                  seq_lens)
 
@@ -1133,7 +1127,7 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, seq_lens,
 
     f = jax.shard_map(local, mesh=jm,
                       in_specs=(head, pool, pool, rep, rep),
-                      out_specs=head)
+                      out_specs=head, check_vma=False)
     return f(q, k_pool, v_pool, block_tables, seq_lens)
 
 

@@ -13,6 +13,7 @@ analogue of a whole PirInterpreter Plan, minus the per-op dispatch loop).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -443,14 +444,23 @@ class TrainStep:
                     obj.grad = grad
                     obj.stop_gradient = sg
 
+        def traced(*args):
+            with self._kernel_mesh():
+                return pure(*args)
+
         donate = (0, 1) if self._donate else ()
-        self._pure = pure
-        self._jitted = jax.jit(pure, donate_argnums=donate,
+        self._pure = traced
+        self._jitted = jax.jit(traced, donate_argnums=donate,
                                out_shardings=self._out_shardings())
 
     def _out_shardings(self):
         """None everywhere (XLA's choice); ShardedTrainStep pins params."""
         return None
+
+    def _kernel_mesh(self):
+        """Context the step is traced in; ShardedTrainStep declares its
+        mesh to the Pallas kernels here (kernels/on_mesh.py)."""
+        return contextlib.nullcontext()
 
     def _prepare_state(self, param_arrays, slot_states):
         """Hook run inside the traced step before any compute; sharded
@@ -458,29 +468,46 @@ class TrainStep:
         device."""
         return param_arrays, slot_states
 
-    def __call__(self, *batch):
-        if self._jitted is None:
-            self._build()
+    def _step_args(self, batch, key):
+        """The jitted step's argument tuple for ``batch`` at the
+        optimizer's current step count."""
         opt = self._opt
         param_objs = [p for _, p in self._params]
         # materialize slot dicts in param order
         slot_states = [opt._slots_for(p) for p in param_objs]
         param_arrays = [p._data for p in param_objs]
         buffer_arrays = [b._data for _, b in self._buffers]
-        opt._global_step += 1
         if opt._lr_scheduler is not None:
             lr = opt._lr_scheduler.last_lr
         else:
             lr = opt._lr
         t = jnp.asarray(opt._global_step, jnp.float32)
-        key = random_mod.next_key()
         batch_arrays = jax.tree.map(_tree_unwrap, batch,
                                     is_leaf=lambda x: isinstance(x, Tensor))
+        return (param_arrays, slot_states, buffer_arrays, t,
+                jnp.asarray(lr, jnp.float32), key, batch_arrays)
+
+    def lower(self, *batch):
+        """``jax.stages.Lowered`` of the step for ``batch`` — the AOT
+        view (``.as_text()``, ``.compile().as_text()``) of exactly the
+        program ``__call__`` dispatches. Advances no state: neither the
+        step count nor the RNG stream moves."""
+        if self._jitted is None:
+            self._build()
+        return self._jitted.lower(*self._step_args(
+            batch, random_mod.default_generator().get_state()))
+
+    def __call__(self, *batch):
+        if self._jitted is None:
+            self._build()
+        opt = self._opt
+        param_objs = [p for _, p in self._params]
+        opt._global_step += 1
+        args = self._step_args(batch, random_mod.next_key())
         from ..distributed.watchdog import watch_step
         with watch_step("TrainStep") as w:
             loss, aux, new_params, new_slots, new_buffers = self._jitted(
-                param_arrays, slot_states, buffer_arrays, t,
-                jnp.asarray(lr, jnp.float32), key, batch_arrays)
+                *args)
             if w is not None:  # watchdog on: surface hangs at this step
                 jax.block_until_ready(loss)
         for p, arr, st in zip(param_objs, new_params, new_slots):

@@ -3,8 +3,8 @@
 Capability parity with the reference's `flash_attn_kernel.cu:128` (FA2
 dynload) and `python/paddle/nn/functional/flash_attention.py`. Two paths:
 
-- `sdpa_xla`: straight jnp attention — XLA fuses well and serves as the
-  numeric oracle and CPU/interpret fallback.
+- `sdpa_xla`: straight jnp attention — the numeric oracle, the CPU
+  backend's route, and the route for masks/dropout the kernel lacks.
 - Pallas TPU kernel (`paddle_tpu/kernels/pallas/flash_attention.py`), used
   automatically on TPU for supported shapes/dtypes.
 
@@ -13,7 +13,6 @@ Layout is paddle's: [batch, seq, num_heads, head_dim].
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -21,6 +20,7 @@ import jax.numpy as jnp
 
 from ..core.dispatch import apply, unwrap
 from ..core.random import next_key
+from . import on_mesh
 
 
 def _use_pallas(q) -> bool:
@@ -29,11 +29,8 @@ def _use_pallas(q) -> bool:
     force = os.environ.get("PADDLE_FLASH_FORCE")  # A/B switch: pallas|xla
     if force == "xla":
         return False
-    try:
-        if jax.default_backend() == "cpu":
-            return force == "pallas"
-    except RuntimeError:
-        return False
+    if jax.default_backend() == "cpu":
+        return force == "pallas"
     # MXU-friendly: head_dim multiple of 128 handled by kernel padding; seq
     # must be tile-divisible. The pallas kernel pads internally; gate only on
     # dtype support.
@@ -105,17 +102,17 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     # cell (bool) it is part of _fn_key.
     route_pallas = (_use_pallas(unwrap(query)) and mask_arr is None
                     and not use_dropout)
+    # the declared device mesh rides as a closure cell for the same
+    # reason: a model first served on one chip and then on four must not
+    # cache-hit the unsharded trace
+    mesh = on_mesh.current() if route_pallas else None
 
     def _sdpa(q, k, v):
         if route_pallas:
             # native-GQA Pallas kernel: grouped KV heads are never expanded
-            try:
-                from .pallas.flash_attention import (
-                    flash_attention as pallas_flash)
-            except ImportError:
-                pallas_flash = None
-            if pallas_flash is not None:
-                return pallas_flash(q, k, v, causal=is_causal)
+            from .pallas.flash_attention import (
+                flash_attention as pallas_flash)
+            return pallas_flash(q, k, v, causal=is_causal, on_mesh=mesh)
         qh, kh = q.shape[2], k.shape[2]
         if kh != qh:  # GQA on the XLA fallback path: repeat kv heads
             rep = qh // kh

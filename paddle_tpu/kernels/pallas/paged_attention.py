@@ -56,6 +56,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _interpret
+
 __all__ = ["paged_decode_attention_kernel",
            "paged_decode_attention_chunked", "pick_chunk_pages"]
 
@@ -65,18 +67,6 @@ _NEG = np.float32(-1e30)
 _ZERO = np.float32(0.0)
 _ONE = np.float32(1.0)
 _I0 = np.int32(0)
-
-
-def _interpret() -> bool:
-    import os
-    if os.environ.get("PADDLE_PALLAS_FORCE_COMPILE"):
-        # cross-lowering gate (tools/tpu_lowering_gate.py): run the real
-        # Mosaic pipeline even on a CPU host so legalization is proven
-        return False
-    try:
-        return jax.default_backend() == "cpu"
-    except RuntimeError:  # pragma: no cover
-        return True
 
 
 def _page_update(q_ref, k_blk, v_blk, acc, m_scr, l_scr, valid, *,
@@ -229,31 +219,6 @@ def _decode_kernel_chunked(tables_ref, lens_ref, q_ref, *refs, hk, g,
         _finalize_out(o_ref, acc, l_scr)
 
 
-def _gspmd_decode(core, quantized):
-    """The decode-serving GSPMD rule (the flash-attention SPMD rule's
-    analogue): request batch b may be sharded (DP serving over chips);
-    the page pools (and, quantized, their scale rows) are replicated —
-    every shard's block table indexes the full pool. Head/page dims
-    declared need-replication."""
-    from .flash_attention import _gspmd_wrap
-    if quantized:
-        return _gspmd_wrap(
-            core,
-            "b m, b, b hq d, nb bs hk d, nb bs hk d, nb bs hk, "
-            "nb bs hk -> b hq d",
-            ("m", "hq", "d", "nb", "bs", "hk"),
-            arg_keeps=[(0, None), (0, None), (0, None), (None, None),
-                       (None, None), (None, None), (None, None)],
-            out_keeps=[(0, None)])
-    return _gspmd_wrap(
-        core,
-        "b m, b, b hq d, nb bs hk d, nb bs hk d -> b hq d",
-        ("m", "hq", "d", "nb", "bs", "hk"),
-        arg_keeps=[(0, None), (0, None), (0, None), (None, None),
-                   (None, None)],
-        out_keeps=[(0, None)])
-
-
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
                                   seq_lens, scale=None, interpret=None,
@@ -305,21 +270,16 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
     kernel = functools.partial(body, hk=hk, g=g, bs=bs,
                                npages=npages, scale=sm_scale)
 
-    def core(tbl, lens, qq, kp, vp, *scales):
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(qq.shape, qq.dtype),
-            interpret=interpret,
-        )(tbl, lens, qq, kp, vp, *scales)
-
-    sharded = _gspmd_decode(core, quantized)
-    args = (block_tables.astype(jnp.int32),
-            seq_lens.astype(jnp.int32), q, k_pool, v_pool)
-    if quantized:
-        args += (k_scale.astype(jnp.float32),
-                 v_scale.astype(jnp.float32))
-    return sharded(*args)
+    scales = (k_scale.astype(jnp.float32),
+              v_scale.astype(jnp.float32)) if quantized else ()
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="paged_decode_q8" if quantized else "paged_decode",
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q,
+      k_pool, v_pool, *scales)
 
 
 # chunk candidates and the per-core VMEM budget the K+V tile may take
@@ -410,23 +370,17 @@ def paged_decode_attention_chunked(q, k_pool, v_pool, block_tables,
                                bs=bs, cpp=cpp, nchunks=nchunks,
                                scale=sm_scale, quantized=quantized)
 
-    def core(tbl, lens, qq, kp, vp, *scales):
-        ins = [qq] + [kp] * cpp + [vp] * cpp
-        if scales:
-            ins += [scales[0]] * cpp + [scales[1]] * cpp
-        # the SAME pool array backs every per-page input; only the
-        # BlockSpec index maps differ, so nothing is copied host-side
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(qq.shape, qq.dtype),
-            interpret=interpret,
-        )(tbl, lens, *ins)
-
-    sharded = _gspmd_decode(core, quantized)
-    args = (block_tables.astype(jnp.int32),
-            seq_lens.astype(jnp.int32), q, k_pool, v_pool)
+    # the SAME pool array backs every per-page input; only the BlockSpec
+    # index maps differ, so nothing is copied host-side
+    ins = [q] + [k_pool] * cpp + [v_pool] * cpp
     if quantized:
-        args += (k_scale.astype(jnp.float32),
-                 v_scale.astype(jnp.float32))
-    return sharded(*args)
+        ins += [k_scale.astype(jnp.float32)] * cpp \
+            + [v_scale.astype(jnp.float32)] * cpp
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name=("paged_decode_chunked_q8" if quantized
+              else "paged_decode_chunked"),
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *ins)

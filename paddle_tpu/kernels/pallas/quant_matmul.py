@@ -19,7 +19,7 @@ Numerics match the XLA dequant-then-matmul form exactly in spirit and
 bitwise-closely in practice (same f32 contraction,
 `preferred_element_type=f32`); tests/framework/test_pallas_kernels.py
 pins one-vs-other. Runs under ``interpret=True`` on CPU like the other
-serving kernels (`paged_attention._interpret`).
+serving kernels (`flash_attention._interpret`).
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .paged_attention import _interpret
+from .flash_attention import _interpret
 
 __all__ = ["quant_matmul"]
 
@@ -39,6 +39,9 @@ __all__ = ["quant_matmul"]
 # token-batch-thin (decode M = batch size)
 _BM = 128
 _BN = 128
+# index-map literal: a bare 0 is i64 under jax_enable_x64, which Mosaic
+# cannot legalize (see flash_attention.py)
+_I0 = np.int32(0)
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref):
@@ -82,12 +85,13 @@ def quant_matmul(x, w_int8, w_scales, interpret=None):
         _qmm_kernel,
         grid=(mp // bm, np_ // bn),
         in_specs=[
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, k), lambda i, j: (i, _I0)),
+            pl.BlockSpec((k, bn), lambda i, j: (_I0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (_I0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         interpret=interpret,
+        name="quant_matmul",
     )(x2, w, s)
     return out[:m, :n].reshape(*orig_shape[:-1], n)

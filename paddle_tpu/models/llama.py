@@ -33,11 +33,8 @@ def _aot_wrap(jitted, tag):
     wrapper forwards straight to ``jitted`` until a cache dir is
     configured (FLAGS_serving_aot_cache / FLAGS_aot_cache_dir), so the
     production default is byte-for-byte plain jax.jit."""
-    try:
-        from ..serving.aot_cache import wrap
-        return wrap(jitted, tag)
-    except Exception:  # noqa: BLE001 — a broken cache layer must not block serving
-        return jitted
+    from ..serving.aot_cache import wrap
+    return wrap(jitted, tag)
 
 
 @dataclasses.dataclass
@@ -712,9 +709,8 @@ class Llama(nn.Layer):
             hd = cfg.hidden_size // hq
             # mesh-sharded serving: captured at build time — the jit is
             # rebuilt (apply_serving_mesh clears it) when the mesh
-            # changes. With stable shard_map available the attention
-            # runs explicitly sharded per kv-head; otherwise the same
-            # layout rides the NamedSharding inputs + GSPMD.
+            # changes. A model-sharded mesh runs the attention
+            # explicitly sharded per kv-head under shard_map.
             mesh = self.__dict__.get("_serving_mesh")
             use_tp = mesh is not None and mesh.shard_map_armed
 
@@ -975,10 +971,14 @@ class Llama(nn.Layer):
 
     def forward_hidden(self, input_ids, kv_sink=None):
         """Decoder stack output (post final RMSNorm), before the head."""
-        x = self.embed_tokens(input_ids)
-        for block in self.layers:
-            x = block(x, kv_sink=kv_sink)
-        return self.norm(x)
+        from ..kernels.on_mesh import kernel_mesh
+        mesh = self.serving_mesh()
+        # a mesh-served model's attention kernel runs per head shard
+        with kernel_mesh(mesh.jax_mesh if mesh is not None else None):
+            x = self.embed_tokens(input_ids)
+            for block in self.layers:
+                x = block(x, kv_sink=kv_sink)
+            return self.norm(x)
 
     def loss(self, input_ids, labels):
         if self.config.fused_head_ce:

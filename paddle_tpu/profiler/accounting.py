@@ -54,7 +54,8 @@ from collections import deque
 from . import metrics as _metrics
 
 __all__ = ["CostReport", "Accountant", "NULL", "flops_per_token",
-           "matmul_params", "detect_peak_flops"]
+           "matmul_params", "detect_peak_flops", "peak_bf16_flops",
+           "PEAK_BF16_FLOPS"]
 
 # engine-level aggregates (registry: rendered by the summary "Goodput"
 # section, scraped from /metrics; multiple engines sum into one family)
@@ -195,38 +196,46 @@ def flops_per_token(config):
     return None if p is None else 2.0 * p
 
 
-# bf16 peak FLOPs by device kind substring (lowercase); an estimate for
-# the MFU gauge, overridable via ACCOUNTING_PEAK_FLOPS
-_PEAK_FLOPS = (
-    ("v5 lite", 197e12), ("v5e", 197e12),
-    ("v5p", 459e12), ("v5", 459e12),
-    ("v6", 918e12), ("trillium", 918e12),
-    ("v4", 275e12), ("v3", 123e12),
-)
+# bf16 peak FLOP/s per chip, keyed by the exact ``device_kind`` jax
+# reports (names as in jax._src.test_util.is_device_tpu: v5e is "TPU v5
+# lite", v5p is "TPU v5", v6e is "TPU v6 lite"; peaks from the Google
+# Cloud TPU documentation of each generation). The one peak table of the
+# repo: bench.py reads it too.
+PEAK_BF16_FLOPS = {
+    "TPU v3": 123e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5": 459e12,
+    "TPU v6 lite": 918e12,
+}
+
+
+def peak_bf16_flops(device_kind):
+    """Peak bf16 FLOP/s of a ``device_kind``. A device that is not in the
+    table is an error, never a default: a utilization over a guessed
+    peak is a wrong number under a device metric's name."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no bf16 peak known for device_kind {device_kind!r}; add it "
+            f"to profiler.accounting.PEAK_BF16_FLOPS with its source"
+        ) from None
 
 
 def detect_peak_flops():
     """Peak device FLOPs for the MFU estimate: the
-    ``ACCOUNTING_PEAK_FLOPS`` env override, else a device-kind table;
-    None (MFU unreported) on CPU or unknown hardware."""
+    ``ACCOUNTING_PEAK_FLOPS`` env override, else the table entry of the
+    default device; None (MFU unreported) on CPU. An accelerator that
+    is not in the table raises."""
     env = os.environ.get("ACCOUNTING_PEAK_FLOPS")
     if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
-        import jax
-        dev = jax.devices()[0]
-        if dev.platform == "cpu":
-            return None
-        kind = getattr(dev, "device_kind", "").lower()
-        for sub, peak in _PEAK_FLOPS:
-            if sub in kind:
-                return peak
-    except Exception:  # noqa: BLE001 — accounting must never break serving
-        pass
-    return None
+        return float(env)
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return peak_bf16_flops(dev.device_kind)
 
 
 class _Note:

@@ -13,13 +13,11 @@ module points the training-side mesh machinery (``distributed/mesh``,
   **kv-head** along the same axis, so the attention gather + einsum is
   embarrassingly parallel over heads (no collective inside attention;
   the all_gather/psum_scatter pair lives at the projection
-  boundaries). Where the runtime jax exposes stable ``jax.shard_map``
-  (``distributed.capability.has_jax_shard_map``) the decode attention
-  runs under an explicit shard_map so each shard routes its local pool
-  through ``kernels/pallas/paged_attention.py``; everywhere else the
-  same sharding is expressed through ``NamedSharding`` on the program
-  inputs and GSPMD propagation — numerically the same partitioning,
-  chosen by the compiler.
+  boundaries). The decode attention runs under an explicit
+  ``jax.shard_map`` so each shard routes its local pool through
+  ``kernels/pallas/paged_attention.py``; everything else is expressed
+  through ``NamedSharding`` on the program inputs and GSPMD
+  propagation.
 - the **data axis** partitions the scheduler's capacity into
   *slices*: decode slots and pool blocks are divided across
   ``data`` slices, new requests bind to the least-loaded slice, and
@@ -108,7 +106,6 @@ class ServingMesh:
         devices = np.array(jax.devices()[:n], dtype=object).reshape(
             self.data, self.model)
         self.jax_mesh = Mesh(devices, axis_names=self.AXES)
-        self._shard_map = None  # capability probe, memoized
 
     # -- identity ------------------------------------------------------
 
@@ -176,20 +173,11 @@ class ServingMesh:
                     f"divides the head and hidden extents",
                     axis="model", size=m, device_count=self.devices)
 
-    # -- shard_map capability ------------------------------------------
-
     @property
     def shard_map_armed(self):
-        """True when the decode attention should run under an explicit
-        ``jax.shard_map`` (stable entry point present AND the model
-        axis actually splits anything). Where absent, the same layout
-        rides NamedSharding inputs + GSPMD propagation — the graceful
-        gate for runtimes whose jax lacks shard_map."""
-        if self._shard_map is None:
-            from ..distributed import capability
-            self._shard_map = (self.model > 1
-                               and capability.has_jax_shard_map())
-        return self._shard_map
+        """True when the decode attention runs under an explicit
+        ``jax.shard_map``: whenever the model axis splits anything."""
+        return self.model > 1
 
 
 def resolve_serving_mesh(mesh=None):

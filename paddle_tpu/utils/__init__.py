@@ -2,6 +2,7 @@
 
 from . import dlpack  # noqa: F401
 from . import cpp_extension  # noqa: F401
+from .compile_cache import configure_compile_cache  # noqa: F401
 from .flops import flops  # noqa: F401
 
 

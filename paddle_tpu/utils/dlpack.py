@@ -6,10 +6,8 @@ from ..core.tensor import Tensor
 
 
 def to_dlpack(x):
-    import jax
     arr = x._data if isinstance(x, Tensor) else x
-    return jax.dlpack.to_dlpack(arr) if hasattr(jax.dlpack, "to_dlpack") \
-        else arr.__dlpack__()
+    return arr.__dlpack__()
 
 
 def from_dlpack(capsule):

@@ -18,7 +18,6 @@ import sys
 import time
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.pop("PJRT_LIBRARY_PATH", None)
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=1").strip()

@@ -12,7 +12,6 @@ import sys
 import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.pop("PJRT_LIBRARY_PATH", None)
 # one CPU device per process -> the 2-process mesh is a real 2-host mesh
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")

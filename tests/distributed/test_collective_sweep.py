@@ -23,11 +23,6 @@ def _run(body, x, n=4):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    # capability probe, not a version pin: every mesh-driven sweep test
-    # funnels through this helper, and absent the stable jax.shard_map
-    # entry point those are known noise, not signal
-    if not dist.has_jax_shard_map():
-        pytest.skip("jax.shard_map capability absent (feature probe)")
     mesh = _mesh(n)
     return np.asarray(jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P("x"), out_specs=P("x")))(x))

@@ -21,9 +21,6 @@ def test_module_paths():
     assert dist.communication.group.destroy_process_group() is None
 
 
-@pytest.mark.skipif(
-    not dist.has_jax_shard_map(),
-    reason="jax.shard_map capability absent (feature probe)")
 def test_stream_all_reduce_inside_shard_map():
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
@@ -71,10 +68,6 @@ def test_autotune_set_config():
         autotune.set_config({"kernel": {"enable": False}})
 
 
-@pytest.mark.skipif(
-    not dist.has_partitioning_sharding_rule(),
-    reason="custom_partitioning sharding_rule kwarg absent "
-           "(feature probe; the pallas kernel's GSPMD rule needs it)")
 def test_flash_attention_with_autotune_on_cpu_falls_back():
     """On CPU (interpret mode) the sweep is skipped; results stay exact."""
     import jax.numpy as jnp

@@ -91,7 +91,6 @@ def test_launch_elastic_restart_e2e(tmp_path):
         "    sys.exit(17)\n"
         "open(sys.argv[1] + f'/ok.{rank}', 'w').write('done')\n")
     env = dict(os.environ)
-    env.pop("PJRT_LIBRARY_PATH", None)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(

@@ -34,7 +34,6 @@ WORKER = Path(__file__).resolve().parent / "elastic_worker.py"
 
 def _clean_env(log_dir):
     env = dict(os.environ)
-    env.pop("PJRT_LIBRARY_PATH", None)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     env["PADDLE_LOG_DIR"] = str(log_dir)

@@ -12,14 +12,6 @@ from paddle_tpu import distributed as dist
 from paddle_tpu import optimizer
 from paddle_tpu.models import Llama, LlamaConfig
 
-# capability probe, not a version pin: the kernel's GSPMD
-# custom_partitioning rules pass sharding_rule= at registration
-pytestmark = pytest.mark.skipif(
-    not dist.has_partitioning_sharding_rule(),
-    reason="custom_partitioning sharding_rule kwarg absent "
-           "(feature probe)")
-
-
 @pytest.fixture
 def force_pallas(monkeypatch):
     # CPU backend routes to XLA sdpa by default; force the Pallas

@@ -41,7 +41,6 @@ def _free_port():
 
 def _clean_env(log_dir):
     env = dict(os.environ)
-    env.pop("PJRT_LIBRARY_PATH", None)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     env["PADDLE_LOG_DIR"] = str(log_dir)

@@ -12,11 +12,6 @@ from paddle_tpu import nn, optimizer
 from paddle_tpu import distributed as dist
 from paddle_tpu.distributed.pipeline import PipelineDecoderLM
 
-# capability probe, not a version pin: the pipeline engine drives the
-# stable jax.shard_map entry point — absent it, these are known noise
-pytestmark = pytest.mark.skipif(
-    not dist.has_jax_shard_map(),
-    reason="jax.shard_map capability absent (feature probe)")
 from paddle_tpu.models import Llama, LlamaConfig
 from paddle_tpu.nn import functional as F
 

@@ -101,20 +101,10 @@ def test_1f1b_bubble_smaller_than_fthenb_span():
     assert s1.T <= sf.T  # same or tighter makespan
 
 
-# capability probe, not a version pin: tests that EXECUTE the pipeline
-# engine drive jax.shard_map — absent it they are known noise; the
-# schedule-math tests above/below run everywhere and stay unguarded
-_requires_shard_map = pytest.mark.skipif(
-    not dist.has_jax_shard_map(),
-    reason="jax.shard_map capability absent (feature probe)")
-
-
 # ---------------------------------------------------------------- acc-align
 
 @pytest.fixture(scope="module")
 def gpipe_ref():
-    if not dist.has_jax_shard_map():
-        pytest.skip("jax.shard_map capability absent (feature probe)")
     mesh = dist.init_mesh([2, 4], ["dp", "pp"])
     pg = _make(mesh, "gpipe", 4)
     ids = _ids()
@@ -219,7 +209,6 @@ def test_1f1b_under_sharded_train_step(gpipe_ref):
 
 # ----------------------------------------------------------- descriptors
 
-@_requires_shard_map
 def test_shared_layer_desc_ties_embedding():
     mesh = dist.init_mesh([1, 4], ["dp", "pp"])
     cfg = CFG
@@ -275,7 +264,6 @@ def test_shared_layer_desc_ties_embedding():
     np.testing.assert_allclose(g_tied, g_sum, rtol=2e-4, atol=2e-5)
 
 
-@_requires_shard_map
 def test_uneven_layers_padded():
     """6 layers over pp=4: pads to 8 rows, identity-masked (reference
     SegmentLayers uneven partition capability)."""
@@ -300,7 +288,6 @@ def test_uneven_layers_padded():
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-@_requires_shard_map
 def test_state_dict_schedule_independent():
     """A checkpoint saved under interleave loads into a V=1 pipeline with
     identical per-layer values (stacked params stored in original layer
@@ -320,7 +307,6 @@ def test_state_dict_schedule_independent():
     np.testing.assert_allclose(lv, l1, rtol=1e-5)
 
 
-@_requires_shard_map
 def test_shared_layer_desc_forward_func():
     """forward_func replaces the layer's forward at that pipeline
     position (reference SharedLayerDesc usage: tied embedding as head)."""

@@ -127,7 +127,6 @@ def test_ps_over_rpc_three_processes(tmp_path):
     procs = []
     for rank in range(3):
         env = dict(os.environ)
-        env.pop("PJRT_LIBRARY_PATH", None)
         env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get(
             "PYTHONPATH", "")

@@ -8,13 +8,6 @@ from paddle_tpu import distributed as dist
 from paddle_tpu.distributed.ring_attention import ring_attention
 from paddle_tpu.kernels.flash_attention import sdpa_xla
 
-# capability probe, not a version pin: ring attention shards the
-# sequence axis through jax.shard_map — absent it, known noise
-pytestmark = pytest.mark.skipif(
-    not dist.has_jax_shard_map(),
-    reason="jax.shard_map capability absent (feature probe)")
-
-
 @pytest.fixture(scope="module")
 def qkv():
     rng = np.random.default_rng(0)

@@ -27,7 +27,6 @@ def test_rpc_two_processes(tmp_path):
     procs = []
     for rank in range(2):
         env = dict(os.environ)
-        env.pop("PJRT_LIBRARY_PATH", None)
         env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get(
             "PYTHONPATH", "")

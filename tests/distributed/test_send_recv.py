@@ -30,7 +30,6 @@ def test_send_recv_two_process_e2e(tmp_path):
     port = _free_port()
     log_dir = tmp_path / "logs"
     env = dict(os.environ)
-    env.pop("PJRT_LIBRARY_PATH", None)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     cmd = [

@@ -112,7 +112,6 @@ def test_trainstep_integration_records_steps():
 WEDGED = r"""
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.pop("PJRT_LIBRARY_PATH", None)
 import jax
 jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp

@@ -1,0 +1,72 @@
+"""chip_smoke.py's phases at tiny widths on the CPU, with the Pallas
+kernels interpreted — so the script that spends chip time is itself
+tested before it is sent there — and its refusal to run without a TPU.
+"""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+
+import chip_smoke  # noqa: E402
+from paddle_tpu.models import GPTConfig, LlamaConfig  # noqa: E402
+
+
+def test_main_refuses_without_tpu(capsys):
+    assert chip_smoke.main() == 2
+    out = capsys.readouterr()
+    assert out.out == ""  # no result line to mistake for a pass
+    assert len(out.err.strip().splitlines()) == 1
+    assert "not 'tpu'" in out.err
+
+
+def test_train_phase_tiny(monkeypatch):
+    # the CPU backend routes attention to XLA unless forced: the phase
+    # must drive the flash fwd/dq/dkv kernels (interpreted here)
+    monkeypatch.setenv("PADDLE_FLASH_FORCE", "pallas")
+    facts = chip_smoke.train_phase(
+        GPTConfig.tiny(), 2, 64, 4, dtype="float32", platform="cpu",
+        expect_kernels=())
+    assert len(facts["losses"]) == 4
+    assert facts["losses"][-1] < facts["losses"][0]
+
+
+def test_phases_fail_when_parameters_are_not_on_the_platform():
+    # parameters on the CPU are not "on the chip"
+    with pytest.raises(chip_smoke.SmokeFailure, match="parameters live"):
+        chip_smoke.train_phase(
+            GPTConfig.tiny(), 2, 64, 3, dtype="float32", platform="tpu",
+            expect_kernels=())
+    with pytest.raises(chip_smoke.SmokeFailure, match="parameters live"):
+        chip_smoke.serve_phase(
+            LlamaConfig.tiny(), [], 2, dtype="float32", platform="tpu",
+            paged_kernel="pallas", interpret=True, tol=1e-4)
+
+
+def test_train_phase_fails_without_expected_kernels(monkeypatch):
+    """Interpreted kernels leave no Mosaic call in the executable: the
+    check that the chip run relies on must notice."""
+    monkeypatch.setenv("PADDLE_FLASH_FORCE", "pallas")
+    with pytest.raises(chip_smoke.SmokeFailure, match="expected"):
+        chip_smoke.train_phase(
+            GPTConfig.tiny(), 2, 64, 3, dtype="float32", platform="cpu",
+            expect_kernels=("flash_fwd",))
+
+
+def test_serve_phase_tiny():
+    # 16 pages of 2 tokens: the table length at which the engine routes
+    # decode to the chunked kernel (inference.paged._CHUNK_MIN_PAGES),
+    # the form the default 2048-token engine takes on the chip; the
+    # per-page form is the one every other serving test runs
+    engines = [("chunked", dict(max_batch=2, block_size=2, max_seq_len=32,
+                                bucket_cap=32), (5, 12))]
+    facts, model, prompt, logits = chip_smoke.serve_phase(
+        LlamaConfig.tiny(), engines, 4, dtype="float32", platform="cpu",
+        paged_kernel="pallas", interpret=True, tol=1e-4)
+    assert facts["chunked"]["table_pages"] == 16
+    assert facts["chunked"]["prefix_hit_blocks"] > 0
+    assert facts["chunked"]["pallas_vs_dense_rel_err"] <= 1e-4
+    assert logits.shape == (model.config.vocab_size,)

@@ -6,19 +6,16 @@ The pytest harness forces 8 host devices (tests/conftest.py), so the
 1x1-vs-sharded bit-equivalence, prefix-cache hits and preemption under
 sharding, per-slice occupancy closure, AOT fingerprint separation
 across mesh shapes, and the flag-off byte-for-byte revert with
-``serving.mesh.*`` counter silence. The shard_map attention fast path
-is additionally pinned where the runtime jax exposes the stable entry
-point (``distributed.capability.has_jax_shard_map`` — skip-guarded,
-like the shard_map-dependent distributed tests); everywhere else the
-same layout rides NamedSharding + GSPMD, which these tests exercise
-unguarded. tools/mesh_gate.py re-proves the corpus cross-process.
+``serving.mesh.*`` counter silence. The decode attention of a
+model-sharded mesh runs under an explicit ``jax.shard_map``
+(``ServingMesh.shard_map_armed``). tools/mesh_gate.py re-proves the
+corpus cross-process.
 """
 
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.distributed import capability
 from paddle_tpu.distributed.mesh import MeshAxisError, init_mesh
 from paddle_tpu.profiler import metrics
 from paddle_tpu.serving.mesh import (ServingMesh, parse_mesh_spec,
@@ -34,7 +31,8 @@ def _model():
     return m
 
 
-def _serve(mesh, prompts, max_new=8, num_blocks=None, max_seq_len=64):
+def _serve(mesh, prompts, max_new=8, num_blocks=None, max_seq_len=64,
+           paged_kernel=None):
     import jax.numpy as jnp
 
     from paddle_tpu.serving import ServingEngine
@@ -43,7 +41,7 @@ def _serve(mesh, prompts, max_new=8, num_blocks=None, max_seq_len=64):
                         max_seq_len=max_seq_len, temperature=0.0,
                         bucket_cap=32, background=False,
                         dtype=jnp.float32, mesh=mesh,
-                        num_blocks=num_blocks)
+                        num_blocks=num_blocks, paged_kernel=paged_kernel)
     s0 = metrics.snapshot("serving.")
     hs = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
     eng.run_until_idle()
@@ -118,27 +116,24 @@ def test_mesh_axis_validation_is_structured():
 
 def test_mesh_serving_greedy_matches_1x1(mixed_base):
     """The core mesh pin: a 1x8 tensor-parallel serve (params sharded
-    by head/hidden, KV pool by kv-head) emits the same greedy tokens
-    as the single-device run — via NamedSharding + GSPMD on runtimes
-    without stable shard_map."""
+    by head/hidden, KV pool by kv-head, decode attention under
+    jax.shard_map) emits the same greedy tokens as the single-device
+    run."""
     shard, _ = _serve("1x8", _mixed())
     assert shard == mixed_base
     # armed engines move the mesh gauges
     assert metrics.snapshot("serving.mesh.")["serving.mesh.devices"] == 8
 
 
-@pytest.mark.skipif(not capability.has_jax_shard_map(),
-                    reason="stable jax.shard_map absent — the mesh "
-                           "rides NamedSharding+GSPMD here (covered "
-                           "by the unguarded equivalence test)")
-def test_sharded_greedy_bit_equivalence_shard_map(mixed_base):
-    """Where stable shard_map exists, the decode attention runs under
-    an explicit jax.shard_map (ServingMesh.shard_map_armed) — same
-    greedy bit-equivalence contract."""
-    mesh = ServingMesh(1, 8)
-    assert mesh.shard_map_armed
-    shard, _ = _serve("1x8", _mixed())
+def test_sharded_greedy_bit_equivalence_pallas_per_shard(mixed_base):
+    """The route the chip takes: each shard of the shard_map decode
+    attention runs the Pallas kernel on its local kv-heads (interpret
+    mode here) — same greedy bit-equivalence contract."""
+    assert ServingMesh(1, 8).shard_map_armed
+    shard, d = _serve("1x8", _mixed(), paged_kernel="pallas")
     assert shard == mixed_base
+    assert d("serving.kernel.pallas") > 0
+    assert d("serving.kernel.dense") == 0
 
 
 # The three tests below each build two full engines (the dominant cost

@@ -1,7 +1,9 @@
-"""GSPMD sharding rule for the Pallas flash kernel: batch/head-sharded
-execution under jit over a mesh must match the unsharded kernel, forward
-and backward (the TPU analogue of the reference's flash-attention SPMD
-rule, `paddle/phi/infermeta/spmd_rules/flash_attention.cc`)."""
+"""The Pallas flash kernel on a device mesh: with the mesh declared
+(``kernels.on_mesh``) the call runs under ``jax.shard_map``, batch rows
+and KV-head groups per device, and must match the unsharded kernel,
+forward and backward — the TPU analogue of the reference's
+flash-attention SPMD rule
+(`paddle/phi/infermeta/spmd_rules/flash_attention.cc`)."""
 
 import jax
 import jax.numpy as jnp
@@ -9,115 +11,110 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from paddle_tpu.kernels import on_mesh
 from paddle_tpu.kernels.pallas.flash_attention import flash_attention
 
 
-@pytest.fixture
-def mesh():
+def _mesh(dp, tp):
     devs = jax.devices()
-    if len(devs) < 8:
-        pytest.skip("needs 8 virtual devices")
-    return jax.sharding.Mesh(np.array(devs[:8]).reshape(2, 4),
+    if len(devs) < dp * tp:
+        pytest.skip(f"needs {dp * tp} virtual devices")
+    return jax.sharding.Mesh(np.array(devs[:dp * tp]).reshape(dp, tp),
                              ("dp", "tp"))
 
 
 def _mk(b, s, hq, hk, d, seed=0):
     r = np.random.default_rng(seed)
-    q = r.standard_normal((b, s, hq, d)).astype(np.float32)
-    k = r.standard_normal((b, s, hk, d)).astype(np.float32)
-    v = r.standard_normal((b, s, hk, d)).astype(np.float32)
-    return q, k, v
+    return [jnp.asarray(r.standard_normal(shape).astype(np.float32))
+            for shape in ((b, s, hq, d), (b, s, hk, d), (b, s, hk, d))]
 
 
-def test_batch_and_head_sharded_forward_matches(mesh):
+def _on(mesh):
+    return (mesh, ("dp",))
+
+
+def test_axes_follow_what_divides():
+    mesh = _mesh(2, 4)
+    assert on_mesh.batch_head_axes(_on(mesh), 4, 8) == (("dp",), ("tp",))
+    # 2 kv heads do not split 4 ways, an odd batch not 2 ways: replicate
+    assert on_mesh.batch_head_axes(_on(mesh), 4, 2) == (("dp",), None)
+    assert on_mesh.batch_head_axes(_on(mesh), 3, 8) == (None, ("dp", "tp"))
+    # a serving mesh declares no batch axis: heads over the whole mesh
+    assert on_mesh.batch_head_axes((mesh, ()), 1, 8) == (None, ("dp", "tp"))
+
+
+def test_batch_and_head_sharded_forward_matches():
+    mesh = _mesh(2, 4)
     q, k, v = _mk(4, 256, 8, 8, 128)
-    ref = np.asarray(flash_attention(jnp.asarray(q), jnp.asarray(k),
-                                     jnp.asarray(v), causal=True))
+    ref = np.asarray(flash_attention(q, k, v, causal=True))
     sh = NamedSharding(mesh, P("dp", None, "tp", None))
-    qs = jax.device_put(jnp.asarray(q), sh)
-    ks = jax.device_put(jnp.asarray(k), sh)
-    vs = jax.device_put(jnp.asarray(v), sh)
-    with mesh:
-        out = jax.jit(lambda a, b, c: flash_attention(a, b, c,
-                                                      causal=True))(
-            qs, ks, vs)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
-                               atol=2e-3)
+    out = jax.jit(lambda a, b, c: flash_attention(
+        a, b, c, causal=True, on_mesh=_on(mesh)))(
+        *(jax.device_put(a, sh) for a in (q, k, v)))
+    assert out.sharding.is_equivalent_to(sh, 4)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3, atol=2e-3)
 
 
-def test_sharded_backward_matches(mesh):
+def test_sharded_backward_matches():
+    mesh = _mesh(2, 4)
     q, k, v = _mk(4, 256, 8, 8, 128, seed=1)
 
-    def loss(a, b, c):
-        return jnp.sum(flash_attention(a, b, c, causal=True)
-                       .astype(jnp.float32) ** 2)
+    def loss(ctx):
+        return lambda a, b, c: jnp.sum(flash_attention(
+            a, b, c, causal=True, on_mesh=ctx).astype(jnp.float32) ** 2)
 
-    g_ref = jax.grad(loss, argnums=(0, 1, 2))(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    g_ref = jax.grad(loss(None), argnums=(0, 1, 2))(q, k, v)
     sh = NamedSharding(mesh, P("dp", None, "tp", None))
-    args = [jax.device_put(jnp.asarray(a), sh) for a in (q, k, v)]
-    with mesh:
-        g_sh = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+    g_sh = jax.jit(jax.grad(loss(_on(mesh)), argnums=(0, 1, 2)))(
+        *(jax.device_put(a, sh) for a in (q, k, v)))
     for a, b in zip(g_sh, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=5e-3)
 
 
-def test_gqa_head_sharded(mesh):
-    # GQA: 8 query heads, 2 kv heads, kv heads sharded over tp=2 slice
+def test_gqa_head_sharded():
+    # GQA: 8 query heads in 2 kv groups, one group per tp shard
+    mesh = _mesh(2, 2)
     q, k, v = _mk(2, 256, 8, 2, 128, seed=2)
-    ref = np.asarray(flash_attention(jnp.asarray(q), jnp.asarray(k),
-                                     jnp.asarray(v), causal=True))
-    m2 = jax.sharding.Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
-                           ("dp", "tp"))
-    shq = NamedSharding(m2, P("dp", None, "tp", None))
-    shk = NamedSharding(m2, P("dp", None, "tp", None))
-    with m2:
-        out = jax.jit(lambda a, b, c: flash_attention(a, b, c,
-                                                      causal=True))(
-            jax.device_put(jnp.asarray(q), shq),
-            jax.device_put(jnp.asarray(k), shk),
-            jax.device_put(jnp.asarray(v), shk))
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
-                               atol=2e-3)
+    ref = np.asarray(flash_attention(q, k, v, causal=True))
+    sh = NamedSharding(mesh, P("dp", None, "tp", None))
+    out = jax.jit(lambda a, b, c: flash_attention(
+        a, b, c, causal=True, on_mesh=_on(mesh)))(
+        *(jax.device_put(a, sh) for a in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3, atol=2e-3)
 
 
-def test_paged_decode_batch_sharded(mesh):
-    """DP serving: requests sharded over chips, page pools replicated."""
-    from paddle_tpu.kernels.pallas.paged_attention import (
-        paged_decode_attention_kernel)
-
-    r = np.random.default_rng(5)
-    B, HQ, HK, D, BS, NB, MBPS = 8, 4, 4, 128, 16, 32, 4
-    q = jnp.asarray(r.standard_normal((B, HQ, D)), jnp.float32)
-    kp = jnp.asarray(r.standard_normal((NB, BS, HK, D)), jnp.float32)
-    vp = jnp.asarray(r.standard_normal((NB, BS, HK, D)), jnp.float32)
-    tbl = jnp.asarray(r.integers(0, NB, (B, MBPS)), jnp.int32)
-    lens = jnp.asarray(r.integers(1, MBPS * BS, (B,)), jnp.int32)
-    ref = np.asarray(paged_decode_attention_kernel(q, kp, vp, tbl, lens))
-    shb = NamedSharding(mesh, P("dp"))
-    with mesh:
-        out = jax.jit(paged_decode_attention_kernel)(
-            jax.device_put(q, NamedSharding(mesh, P("dp", None, None))),
-            kp, vp,
-            jax.device_put(tbl, NamedSharding(mesh, P("dp", None))),
-            jax.device_put(lens, shb))
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
-                               atol=2e-4)
-
-
-def test_seq_sharded_input_gets_resharded_not_rejected(mesh):
-    # sequence-dim sharding is declared need-replication: GSPMD must
-    # insert a reshard (correct numerics), not fail to partition
+def test_seq_sharded_input_gets_resharded_not_rejected():
+    # the specs are constraints: an operand that arrives sequence-sharded
+    # is resharded by XLA (correct numerics), not refused
+    mesh = _mesh(2, 4)
     q, k, v = _mk(2, 256, 4, 4, 128, seed=3)
-    ref = np.asarray(flash_attention(jnp.asarray(q), jnp.asarray(k),
-                                     jnp.asarray(v), causal=True))
+    ref = np.asarray(flash_attention(q, k, v, causal=True))
     sh = NamedSharding(mesh, P(None, "dp", None, None))  # seq sharded!
-    with mesh:
-        out = jax.jit(lambda a, b, c: flash_attention(a, b, c,
-                                                      causal=True))(
-            jax.device_put(jnp.asarray(q), sh),
-            jax.device_put(jnp.asarray(k), sh),
-            jax.device_put(jnp.asarray(v), sh))
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
-                               atol=2e-3)
+    out = jax.jit(lambda a, b, c: flash_attention(
+        a, b, c, causal=True, on_mesh=_on(mesh)))(
+        *(jax.device_put(a, sh) for a in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3, atol=2e-3)
+
+
+def test_whole_mesh_manual_region_needs_no_wrap():
+    """Inside a shard_map over the whole mesh (the pipeline engine, the
+    serving mesh's decode attention) the declaration reads as absent and
+    the kernel call is local."""
+    mesh = _mesh(2, 4)
+    seen = []
+
+    def body(x):
+        seen.append(on_mesh.current())
+        return x
+
+    with on_mesh.kernel_mesh(mesh, ("dp",)):
+        assert on_mesh.current() == (mesh, ("dp",))
+        jax.shard_map(body, mesh=mesh, in_specs=P("dp", "tp"),
+                      out_specs=P("dp", "tp"))(jnp.ones((4, 4)))
+        with pytest.raises(NotImplementedError, match="manual over"):
+            jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                          out_specs=P("dp"), axis_names={"dp"})(
+                jnp.ones((4, 4)))
+    assert seen == [None]
+    assert on_mesh.current() is None

@@ -92,6 +92,69 @@ def test_paged_decode_lowers_for_tpu():
     assert n == 1
 
 
+# the widths chip_smoke.py serves (Llama-3-8B heads) over its two page
+# geometries: 128 pages of 16 tokens and 8 pages of 64
+@pytest.mark.parametrize("bs,pages", [(16, 128), (64, 8)])
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("chunked", [False, True])
+def test_paged_tier_lowers_at_smoke_widths(bs, pages, quantized, chunked):
+    from paddle_tpu.kernels.pallas.paged_attention import (
+        paged_decode_attention_chunked, paged_decode_attention_kernel)
+
+    b, hq, hk, d = 8, 32, 8, 128
+    nb = 1 + b * pages
+    pool = _aval((nb, bs, hk, d), jnp.int8 if quantized else jnp.bfloat16)
+    avals = [_aval((b, hq, d), jnp.bfloat16), pool, pool,
+             _aval((b, pages), jnp.int32), _aval((b,), jnp.int32)]
+    if quantized:
+        avals += [_aval((nb, bs, hk), jnp.float32)] * 2
+    kernel = paged_decode_attention_chunked if chunked \
+        else paged_decode_attention_kernel
+
+    def fn(q, k, v, t, l, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return kernel(q, k, v, t, l, interpret=False, **kw)
+
+    assert _lower(fn, *avals) == 1
+
+
+@pytest.mark.parametrize("k,n", [(4096, 14336), (14336, 4096)])
+def test_quant_matmul_lowers_at_smoke_widths(k, n):
+    from paddle_tpu.kernels.pallas.quant_matmul import quant_matmul
+
+    assert _lower(
+        lambda x, w, s: quant_matmul(x, w, s, interpret=False),
+        _aval((8, k), jnp.bfloat16), _aval((k, n), jnp.int8),
+        _aval((n,), jnp.float32)) == 1
+
+
+def test_flash_on_mesh_lowers_for_tpu():
+    """On a mesh the kernels sit in a shard_map: a Mosaic call left to
+    the SPMD partitioner raises at lowering."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.kernels.pallas.flash_attention import flash_attention
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    mesh = jax.sharding.Mesh(
+        np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    q = jax.ShapeDtypeStruct(
+        (4, 1024, 8, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "tp", None)))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True,
+            on_mesh=(mesh, ("dp",))).astype(jnp.float32))
+
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call",
+                          txt)) == 3
+
+
 def test_fused_linear_ce_lowers_for_tpu():
     """The blockwise fused LM-head CE (fori/scan + dynamic_slice over W,
     online-softmax carries) must legalize for TPU in fwd AND bwd — the

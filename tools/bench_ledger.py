@@ -1,10 +1,9 @@
 """Continuous-bench regression ledger: append-only JSONL of gate/bench
 measurements.
 
-Every BENCH_r0N.json in this repo is a point-in-time snapshot that
-nothing reads across runs — a PR that quietly shaved 10% off the
-headline would sail through review. The ledger fixes that: each
-gate/bench run appends ONE line (wall-clock ts, git SHA, a kind tag,
+A point-in-time snapshot that nothing reads across runs lets a PR that
+quietly shaved 10% off a gate's number sail through review. The ledger
+fixes that: each gate/bench run appends ONE line (wall-clock ts, git SHA, a kind tag,
 and a flat metrics dict) to ``BENCH_LEDGER.jsonl``, and
 ``tools/regression_gate.py`` compares the current run against the
 median of the last N same-kind entries with per-metric tolerances.
@@ -56,7 +55,6 @@ CLI::
 
 from __future__ import annotations
 
-import glob
 import json
 import os
 import subprocess
@@ -68,7 +66,7 @@ REPO = os.path.dirname(HERE)
 DEFAULT_PATH = os.path.join(REPO, "BENCH_LEDGER.jsonl")
 
 __all__ = ["append_entry", "entries", "last", "git_sha",
-           "bench_headline", "DEFAULT_PATH"]
+           "DEFAULT_PATH"]
 
 
 def git_sha(repo=REPO):
@@ -128,31 +126,6 @@ def entries(path=None, kind=None):
 def last(n=8, kind=None, path=None):
     """The most recent ``n`` entries (oldest of them first)."""
     return entries(path, kind)[-n:]
-
-
-def bench_headline(repo=REPO):
-    """The newest cached bench headline (tokens/s/chip, MFU, step time)
-    from the BENCH_r*.json round files — constant between bench runs,
-    so ledger medians pin it and any PR that moves it trips the
-    regression gate. {} when no bench file parses."""
-    best, best_round = None, -1
-    for p in glob.glob(os.path.join(repo, "BENCH_r*.json")):
-        try:
-            rnd = int(os.path.basename(p)[len("BENCH_r"):-len(".json")])
-            with open(p) as f:
-                parsed = json.load(f).get("parsed") or {}
-        except (ValueError, OSError):
-            continue
-        if "value" in parsed and rnd > best_round:
-            best, best_round = parsed, rnd
-    if not best:
-        return {}
-    out = {"headline_tokens_per_s": float(best["value"])}
-    if isinstance(best.get("mfu"), (int, float)):
-        out["headline_mfu"] = float(best["mfu"])
-    if isinstance(best.get("step_time_ms"), (int, float)):
-        out["headline_step_time_ms"] = float(best["step_time_ms"])
-    return out
 
 
 def main(argv):

@@ -42,7 +42,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 def _child_env(n_devices, extra=None):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("PJRT_LIBRARY_PATH", None)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
     env["XLA_FLAGS"] = " ".join(

@@ -10,9 +10,9 @@ until fixed or acknowledged).
 Direction is per metric: time-like metrics (``*_us``/``*_ms``/``*_s``)
 regress UPWARD, throughput-like metrics (``*tokens_per_s``, ``*_rate``,
 ``*mfu``) regress DOWNWARD. Tolerances are generous for wall-clock
-measurements on a shared CI box (default 75%) and tight for cached
-headline numbers that should be bit-stable between bench runs (5%) —
-override per-run via ``REG_GATE_TIME_TOL`` / ``REG_GATE_RATE_TOL``.
+measurements on a shared CI box (default 75%) and tight for
+``headline_*`` numbers (5%) — override per-run via
+``REG_GATE_TIME_TOL`` / ``REG_GATE_RATE_TOL``.
 
 Modes::
 
@@ -173,8 +173,7 @@ def compare(current, history, min_history=MIN_HISTORY):
 
 def measure():
     """The quick fixed corpus: a tiny-Llama serving run's warm TTFT and
-    mean step time, the disarmed-accounting overhead, plus the cached
-    bench headline (constant between bench runs — the median pins it)."""
+    mean step time, and the disarmed-accounting overhead."""
     import numpy as np
 
     import paddle_tpu as paddle
@@ -212,7 +211,6 @@ def measure():
          "serve_done": 1.0 if h.status == "DONE" else 0.0}
     from accounting_gate import measure_disarmed_us
     m["accounting_disarmed_us"] = round(measure_disarmed_us(), 4)
-    m.update(bench_ledger.bench_headline())
     return m
 
 

@@ -89,7 +89,6 @@ print("ROUTER_GATE_JSON " + json.dumps(out))
 
 def _run_child(cache_dir):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("PJRT_LIBRARY_PATH", None)
     p = subprocess.run(
         [sys.executable, "-c", _CHILD, cache_dir],
         capture_output=True, text=True, timeout=CHILD_TIMEOUT_S,

@@ -10,8 +10,7 @@ until a kernel passes the device compiler, correctness tests in a CPU
 emulator prove nothing about the device build
 (`/root/reference/paddle/phi/kernels/fusion/gpu/flash_attn_kernel.cu:128`).
 
-Run:  PADDLE_PALLAS_FORCE_COMPILE=1 PADDLE_FLASH_FORCE=pallas \
-      python tools/tpu_lowering_gate.py
+Run:  python tools/tpu_lowering_gate.py
 Writes MOSAIC_LOWERING.md (per-gate custom-call summary + module sizes).
 CI subset: tests/kernels/test_tpu_lowering.py runs the kernel gates.
 """
@@ -23,6 +22,9 @@ import re
 import sys
 import time
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
 os.environ.setdefault("PADDLE_PALLAS_FORCE_COMPILE", "1")
 os.environ.setdefault("PADDLE_FLASH_FORCE", "pallas")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -32,15 +34,15 @@ os.environ["XLA_FLAGS"] = (
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax import export  # noqa: E402
 
 
 def summarize_text(txt: str, exp) -> dict:
-    calls = sorted(set(re.findall(r"stablehlo\.custom_call @(\w+)", txt)))
+    calls = sorted(set(re.findall(r"stablehlo\.custom_call @(\w+)", txt))
+                   - {"Sharding", "SPMDFullToShardShape",
+                      "SPMDShardToFullShape"})
     return {
         "custom_calls": calls,
         "module_bytes": len(txt),
@@ -74,9 +76,10 @@ def gate(name: str, fn, *args, expect_tpu_calls: bool = True,
          extra_check=None, use_export: bool = True) -> bool:
     """extra_check(mlir_text) may raise to fail the gate or return a dict
     merged into the report row. ``use_export=False`` runs the same TPU
-    lowering pipeline through jit.trace().lower() — needed when the
-    program holds custom_partitioning callbacks, which jax.export cannot
-    serialize (the Mosaic legalization still runs either way)."""
+    lowering pipeline through jit.trace().lower() — needed for programs
+    over a concrete device mesh, which jax.export would have to
+    serialize device assignments for (the Mosaic legalization still runs
+    either way)."""
     t0 = time.time()
     try:
         if use_export:
@@ -163,27 +166,104 @@ def gate_flash() -> bool:
 # 2. paged-decode kernel
 # ---------------------------------------------------------------------------
 
+# the widths chip_smoke.py serves: Llama-3-8B heads over the two page
+# geometries its engines use (128 pages of 16 tokens -> the chunked
+# kernel, 8 pages of 64 -> the per-page one)
+SMOKE_HEADS = dict(hq=32, hk=8, d=128)
+SMOKE_PAGES = {"block16x128": (16, 128), "block64x8": (64, 8)}
+SMOKE_HIDDEN, SMOKE_INTERMEDIATE = 4096, 14336
+
+
+def paged_avals(bs, pages, quantized, b=8, hq=32, hk=8, d=128):
+    """Abstract (q, k_pool, v_pool, tables, lens[, k_scale, v_scale])."""
+    nb = 1 + b * pages
+    pool = abstract((nb, bs, hk, d), jnp.int8 if quantized else jnp.bfloat16)
+    out = [abstract((b, hq, d), jnp.bfloat16), pool, pool,
+           abstract((b, pages), jnp.int32), abstract((b,), jnp.int32)]
+    if quantized:
+        out += [abstract((nb, bs, hk), jnp.float32)] * 2
+    return out
+
+
+def paged_call(kernel):
+    def fn(q, k, v, t, l, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return kernel(q, k, v, t, l, interpret=False, **kw)
+    return fn
+
+
 def gate_paged() -> bool:
     from paddle_tpu.kernels.pallas.paged_attention import (
-        paged_decode_attention_kernel)
+        paged_decode_attention_chunked, paged_decode_attention_kernel)
+
+    ok = gate("paged_decode_mha",
+              paged_call(paged_decode_attention_kernel),
+              *paged_avals(16, 128, False, hk=32))
+    for geom, (bs, pages) in SMOKE_PAGES.items():
+        for quantized in (False, True):
+            tag = f"{geom}{'_int8' if quantized else ''}"
+            ok &= gate(f"paged_decode_{tag}",
+                       paged_call(paged_decode_attention_kernel),
+                       *paged_avals(bs, pages, quantized, **SMOKE_HEADS))
+            ok &= gate(f"paged_chunked_{tag}",
+                       paged_call(paged_decode_attention_chunked),
+                       *paged_avals(bs, pages, quantized, **SMOKE_HEADS))
+    return ok
+
+
+def gate_quant_matmul() -> bool:
+    from paddle_tpu.kernels.pallas.quant_matmul import quant_matmul
 
     ok = True
-    B, HQ, HK, D, BS, NB, MBPS = 8, 32, 32, 128, 16, 256, 128
-    q = abstract((B, HQ, D), jnp.bfloat16)
-    kp = abstract((NB, BS, HK, D), jnp.bfloat16)
-    tbl = abstract((B, MBPS), jnp.int32)
-    lens = abstract((B,), jnp.int32)
-    ok &= gate("paged_decode_bf16",
-               lambda q, k, v, t, l: paged_decode_attention_kernel(
-                   q, k, v, t, l, interpret=False),
-               q, kp, kp, tbl, lens)
+    for name, k, n in (("up", SMOKE_HIDDEN, SMOKE_INTERMEDIATE),
+                       ("down", SMOKE_INTERMEDIATE, SMOKE_HIDDEN)):
+        ok &= gate(f"quant_matmul_{name}",
+                   lambda x, w, s: quant_matmul(x, w, s, interpret=False),
+                   abstract((8, k), jnp.bfloat16), abstract((k, n), jnp.int8),
+                   abstract((n,), jnp.float32))
+    return ok
 
-    qg = abstract((B, 32, D), jnp.bfloat16)
-    kg = abstract((NB, BS, 8, D), jnp.bfloat16)
-    ok &= gate("paged_decode_gqa4",
-               lambda q, k, v, t, l: paged_decode_attention_kernel(
-                   q, k, v, t, l, interpret=False),
-               qg, kg, kg, tbl, lens)
+
+# ---------------------------------------------------------------------------
+# 2b. the kernels on a device mesh (shard_map; a Mosaic call left to the
+# SPMD partitioner raises at lowering, which is what this catches)
+# ---------------------------------------------------------------------------
+
+def gate_on_mesh() -> bool:
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.inference.paged import paged_decode_attention_tp
+    from paddle_tpu.kernels.pallas.flash_attention import flash_attention
+    from paddle_tpu.serving.mesh import ServingMesh
+
+    mesh = jax.sharding.Mesh(
+        np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    q = jax.ShapeDtypeStruct(
+        (8, 1024, 16, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "tp", None)))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True,
+            on_mesh=(mesh, ("dp",))).astype(jnp.float32))
+
+    ok = gate("flash_bwd_on_mesh_dp2tp2",
+              jax.grad(loss, argnums=(0, 1, 2)), q, q, q, use_export=False)
+
+    smesh = ServingMesh(1, 4)
+    bs, pages = SMOKE_PAGES["block16x128"]
+    avals = paged_avals(bs, pages, False, **SMOKE_HEADS)
+    avals[0] = jax.ShapeDtypeStruct(
+        avals[0].shape, avals[0].dtype,
+        sharding=smesh.sharding(None, "model", None))
+    for i in (1, 2):
+        avals[i] = jax.ShapeDtypeStruct(
+            avals[i].shape, avals[i].dtype,
+            sharding=smesh.kv_pool_sharding())
+    ok &= gate("paged_chunked_shard_map_1x4",
+               lambda q, k, v, t, l: paged_decode_attention_tp(
+                   q, k, v, t, l, smesh, kernel_mode="pallas"),
+               *avals, use_export=False)
     return ok
 
 
@@ -328,7 +408,7 @@ def gate_hybrid_step() -> bool:
         step._build()
         return gate("hybrid_dp2pp2tp2_train_step", step._jitted,
                     param_arrays, slot_states, buffer_arrays, t, lr, key,
-                    (placed,), expect_tpu_calls=False)
+                    (placed,), use_export=False)
 
 
 # ---------------------------------------------------------------------------
@@ -375,8 +455,7 @@ def gate_ep_step() -> bool:
                     param_arrays, slot_states, buffer_arrays,
                     _jnp.asarray(1.0, _jnp.float32),
                     _jnp.asarray(1e-3, _jnp.float32),
-                    random_mod.next_key(), (placed,),
-                    expect_tpu_calls=False, use_export=False)
+                    random_mod.next_key(), (placed,), use_export=False)
 
 
 # ---------------------------------------------------------------------------
@@ -387,10 +466,16 @@ def write_report(path="MOSAIC_LOWERING.md"):
         "",
         "Produced by `tools/tpu_lowering_gate.py` on a CPU host: each gate",
         "runs `jax.export.export(jax.jit(fn), platforms=['tpu'])`, which",
-        "executes the full TPU lowering pipeline including Pallas→Mosaic",
-        "legalization (kernel dtype legality, Mosaic op verification).",
-        "`tpu_custom_call` in the emitted StableHLO is the serialized",
+        "executes the TPU lowering pipeline including the Pallas→Mosaic",
+        "emission (kernel dtype legality, Mosaic op verification), or the",
+        "same through `jit.trace().lower()` for programs over a device",
+        "mesh. `tpu_custom_call` in the emitted StableHLO is the serialized",
         "Mosaic kernel; a gate failing raises at lowering time.",
+        "",
+        "What this cannot see is Mosaic's own compile inside libtpu: an",
+        "i64 index-map literal in `quant_matmul` passed here and failed on",
+        "the chip (PR 21). `chip_smoke.py` is the proof that a kernel",
+        "compiles; this is the free check before chip time is spent.",
         "",
         f"jax {jax.__version__}; generated "
         f"{time.strftime('%Y-%m-%d %H:%M:%S')}",
@@ -422,6 +507,8 @@ def main():
     ok = True
     ok &= gate_flash()
     ok &= gate_paged()
+    ok &= gate_quant_matmul()
+    ok &= gate_on_mesh()
     ok &= gate_train_step()
     ok &= gate_fp8_step()
     ok &= gate_hybrid_step()
