@@ -1,0 +1,11 @@
+"""Share of the device's busy time in the traced slice that the paged
+decode attention kernels took (``custom-call``s named ``paged_decode*``)."""
+
+
+def read(ctx):
+    trace = ctx.get("trace")
+    if not trace:
+        return None
+    kernel = sum(s for name, s in trace["op_seconds"].items()
+                 if "paged_decode" in name)
+    return 100.0 * kernel / trace["busy_s"] if kernel else None
