@@ -444,13 +444,15 @@ class TrainStep:
                     obj.grad = grad
                     obj.stop_gradient = sg
 
-        def traced(*args):
+        # the program's name in a profiler trace: ``jit_train_step`` on
+        # the device's ``XLA Modules`` line
+        def train_step(*args):
             with self._kernel_mesh():
                 return pure(*args)
 
         donate = (0, 1) if self._donate else ()
-        self._pure = traced
-        self._jitted = jax.jit(traced, donate_argnums=donate,
+        self._pure = train_step
+        self._jitted = jax.jit(train_step, donate_argnums=donate,
                                out_shardings=self._out_shardings())
 
     def _out_shardings(self):
@@ -505,7 +507,10 @@ class TrainStep:
         opt._global_step += 1
         args = self._step_args(batch, random_mod.next_key())
         from ..distributed.watchdog import watch_step
-        with watch_step("TrainStep") as w:
+        # the profiler's step line; a flag test while no session runs
+        with jax.profiler.StepTraceAnnotation(
+                "train.step", step_num=opt._global_step), \
+                watch_step("TrainStep") as w:
             loss, aux, new_params, new_slots, new_buffers = self._jitted(
                 *args)
             if w is not None:  # watchdog on: surface hangs at this step

@@ -21,6 +21,7 @@ from .. import nn
 from ..core.dispatch import apply
 from ..core.tensor import Tensor
 from ..nn import functional as F
+from ..profiler.tracing import phase as _phase
 
 # guards lazy creation of each model's paged-call lock (Llama._paged_lock)
 _PAGED_LOCK_INIT = threading.Lock()
@@ -35,6 +36,16 @@ def _aot_wrap(jitted, tag):
     production default is byte-for-byte plain jax.jit."""
     from ..serving.aot_cache import wrap
     return wrap(jitted, tag)
+
+
+def _named_jit(fn, name):
+    """``jax.jit(fn)`` as the program ``jit_<name>``: the name a profiler
+    trace shows on the device's ``XLA Modules`` line and the host's
+    ``PjitFunction(<name>)`` events, so busy time splits by program
+    (``benchmarks/span_reduce.py``). Every serving closure is called
+    ``fn`` where it is written."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
 
 
 @dataclasses.dataclass
@@ -410,64 +421,77 @@ class Llama(nn.Layer):
         from ..core.random import next_key
         from ..inference.paged import paged_prefill_write
 
-        prompt = np.asarray(prompt_ids).reshape(-1)
-        s = prompt.shape[0]
-        bs = cache.block_size
-        spad = -(-s // bs) * bs
-        if pad_to is not None:
-            cap = cache.max_blocks_per_seq * bs
-            want = min(max(int(pad_to), spad), cap)
-            spad = -(-want // bs) * bs
-        ids = np.zeros((1, spad), np.int64)
-        ids[:, :s] = prompt
+        with _phase("serving.prefill.forward"):
+            prompt = np.asarray(prompt_ids).reshape(-1)
+            s = prompt.shape[0]
+            bs = cache.block_size
+            spad = -(-s // bs) * bs
+            if pad_to is not None:
+                cap = cache.max_blocks_per_seq * bs
+                want = min(max(int(pad_to), spad), cap)
+                spad = -(-want // bs) * bs
+            ids = np.zeros((1, spad), np.int64)
+            ids[:, :s] = prompt
 
-        if not hasattr(self, "_paged_prefill_jit"):
-            rebind = self._param_rebind()
+            if not hasattr(self, "_paged_prefill_jit"):
+                self._paged_prefill_jit = self._build_prefill()
 
-            def fn(param_arrays, ids_arr, true_len, key, temp):
-                from .generation import sample_token
-                rebind(param_arrays)
-                sink = []
-                from ..core.autograd import no_grad
-                with no_grad():
-                    logits = self.forward(Tensor(ids_arr), kv_sink=sink)
-                last = jnp.take_along_axis(
-                    logits._data, (true_len - 1)[None, None, None],
-                    axis=1)[:, 0]
-                tok = jax.lax.cond(
-                    temp > 0,
-                    lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                         temperature=1.0, key=key),
-                    lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-                ks = [k._data[0] for k, _ in sink]
-                vs = [v._data[0] for _, v in sink]
-                return tok[0], ks, vs
-            self._paged_prefill_jit = _aot_wrap(
-                jax.jit(fn), self._aot_tag("llama.paged_prefill"))
+            with self._paged_lock():
+                arrs = self._param_arrays()
+                tok, ks, vs = self._paged_prefill_jit(
+                    arrs, jnp.asarray(ids), jnp.int32(s),
+                    next_key(), jnp.float32(temperature))
+                # tracing left tracers bound into the module params;
+                # restore
+                self._param_rebind()(arrs)
+        # one eager scatter a pool, each returning a new pool
+        with _phase("serving.prefill.pool_write", layers=cache.num_layers,
+                    tokens=spad):
+            row = cache.block_tables[slot]
+            for i in range(cache.num_layers):
+                if cache.quantized:
+                    from ..inference.paged import paged_prefill_write_q
+                    (cache.k_pools[i], cache.v_pools[i],
+                     cache.k_scales[i], cache.v_scales[i]) = \
+                        paged_prefill_write_q(
+                            cache.k_pools[i], cache.v_pools[i],
+                            cache.k_scales[i], cache.v_scales[i],
+                            row, ks[i], vs[i])
+                else:
+                    cache.k_pools[i], cache.v_pools[i] = \
+                        paged_prefill_write(
+                            cache.k_pools[i], cache.v_pools[i], row,
+                            ks[i], vs[i])
+            cache.seq_lens[slot] = s
+        with _phase("serving.prefill.readback"):  # waits for the device
+            return int(tok)
 
-        with self._paged_lock():
-            arrs = self._param_arrays()
-            tok, ks, vs = self._paged_prefill_jit(
-                arrs, jnp.asarray(ids), jnp.int32(s),
-                next_key(), jnp.float32(temperature))
-            # tracing left tracers bound into the module params; restore
-            self._param_rebind()(arrs)
-        row = cache.block_tables[slot]
-        for i in range(cache.num_layers):
-            if cache.quantized:
-                from ..inference.paged import paged_prefill_write_q
-                (cache.k_pools[i], cache.v_pools[i],
-                 cache.k_scales[i], cache.v_scales[i]) = \
-                    paged_prefill_write_q(
-                        cache.k_pools[i], cache.v_pools[i],
-                        cache.k_scales[i], cache.v_scales[i],
-                        row, ks[i], vs[i])
-            else:
-                cache.k_pools[i], cache.v_pools[i] = paged_prefill_write(
-                    cache.k_pools[i], cache.v_pools[i], row, ks[i],
-                    vs[i])
-        cache.seq_lens[slot] = s
-        return int(tok)
+    def _build_prefill(self):
+        """The dense causal prefill program: the prompt's logits at its
+        last true position sampled, and every layer's post-rope K and V
+        handed back for the pool write."""
+        rebind = self._param_rebind()
+
+        def fn(param_arrays, ids_arr, true_len, key, temp):
+            from ..core.autograd import no_grad
+            from .generation import sample_token
+            rebind(param_arrays)
+            sink = []
+            with no_grad():
+                logits = self.forward(Tensor(ids_arr), kv_sink=sink)
+            last = jnp.take_along_axis(
+                logits._data, (true_len - 1)[None, None, None],
+                axis=1)[:, 0]
+            tok = jax.lax.cond(
+                temp > 0,
+                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
+                                     temperature=1.0, key=key),
+                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
+            ks = [k._data[0] for k, _ in sink]
+            vs = [v._data[0] for _, v in sink]
+            return tok[0], ks, vs
+        return _aot_wrap(_named_jit(fn, "llama_paged_prefill"),
+                         self._aot_tag("llama.paged_prefill"))
 
     def paged_prefill_extend(self, cache, slot, ids, tail_start,
                              write_start, temperature=0.0, pad_to=None):
@@ -489,112 +513,113 @@ class Llama(nn.Layer):
         """
         from ..core.random import next_key
 
-        ids = np.asarray(ids).reshape(-1)
-        total = ids.shape[0]
-        bs = cache.block_size
-        s_tail = total - tail_start
-        spad = -(-s_tail // bs) * bs
-        if pad_to is not None:
-            cap = cache.max_blocks_per_seq * bs
-            want = min(max(int(pad_to), spad), cap)
-            spad = -(-want // bs) * bs
-        tail = np.zeros((1, spad), np.int64)
-        tail[0, :s_tail] = ids[tail_start:]
+        with _phase("serving.prefill.forward"):
+            ids = np.asarray(ids).reshape(-1)
+            total = ids.shape[0]
+            bs = cache.block_size
+            s_tail = total - tail_start
+            spad = -(-s_tail // bs) * bs
+            if pad_to is not None:
+                cap = cache.max_blocks_per_seq * bs
+                want = min(max(int(pad_to), spad), cap)
+                spad = -(-want // bs) * bs
+            tail = np.zeros((1, spad), np.int64)
+            tail[0, :s_tail] = ids[tail_start:]
 
-        if cache.quantized:
-            # int8 pools thread their scale arrays through the program
-            # and dequantize at the gathers; its own jit + AOT tag so a
-            # model can serve quantized and full-precision caches
-            # side by side
-            if getattr(self, "_paged_extend_q8_jit", None) is None:
-                self._paged_extend_q8_jit = self._build_extend_q8()
-            with self._paged_lock():
-                arrs = self._param_arrays()
-                tok, ks, vs, kss, vss = self._paged_extend_q8_jit(
-                    arrs, jnp.asarray(tail), jnp.int32(tail_start),
-                    jnp.int32(write_start), jnp.int32(total),
-                    jnp.asarray(cache.block_tables[slot]),
-                    cache.k_pools, cache.v_pools,
-                    cache.k_scales, cache.v_scales, next_key(),
-                    jnp.float32(temperature))
-                self._param_rebind()(arrs)
+            if cache.quantized:
+                # int8 pools thread their scale arrays through the
+                # program and dequantize at the gathers; its own jit +
+                # AOT tag so a model can serve quantized and
+                # full-precision caches side by side
+                if getattr(self, "_paged_extend_q8_jit", None) is None:
+                    self._paged_extend_q8_jit = self._build_extend_q8()
+                with self._paged_lock():
+                    arrs = self._param_arrays()
+                    tok, ks, vs, kss, vss = self._paged_extend_q8_jit(
+                        arrs, jnp.asarray(tail), jnp.int32(tail_start),
+                        jnp.int32(write_start), jnp.int32(total),
+                        jnp.asarray(cache.block_tables[slot]),
+                        cache.k_pools, cache.v_pools,
+                        cache.k_scales, cache.v_scales, next_key(),
+                        jnp.float32(temperature))
+                    self._param_rebind()(arrs)
+                cache.k_scales = list(kss)
+                cache.v_scales = list(vss)
+            else:
+                if not hasattr(self, "_paged_extend_jit"):
+                    self._paged_extend_jit = self._build_extend()
+                with self._paged_lock():
+                    arrs = self._param_arrays()
+                    tok, ks, vs = self._paged_extend_jit(
+                        arrs, jnp.asarray(tail), jnp.int32(tail_start),
+                        jnp.int32(write_start), jnp.int32(total),
+                        jnp.asarray(cache.block_tables[slot]),
+                        cache.k_pools, cache.v_pools, next_key(),
+                        jnp.float32(temperature))
+                    self._param_rebind()(arrs)
             cache.k_pools = list(ks)
             cache.v_pools = list(vs)
-            cache.k_scales = list(kss)
-            cache.v_scales = list(vss)
             cache.seq_lens[slot] = total
+        with _phase("serving.prefill.readback"):  # waits for the device
             return int(tok)
 
-        if not hasattr(self, "_paged_extend_jit"):
-            rebind = self._param_rebind()
-            cfg = self.config
-            hq = cfg.num_heads
-            hk = cfg.num_kv_heads
-            hd = cfg.hidden_size // hq
+    def _build_extend(self):
+        """The tail-extend program of ``paged_prefill_extend``: the pool
+        write is inside it (``paged_prefill_write_masked``)."""
+        rebind = self._param_rebind()
+        cfg = self.config
+        hq = cfg.num_heads
+        hk = cfg.num_kv_heads
+        hd = cfg.hidden_size // hq
 
-            def fn(param_arrays, tail_ids, t_start, w_start, t_total,
-                   row, k_pools, v_pools, key, temp):
-                from ..inference.paged import (
-                    paged_prefill_write_masked,
-                    paged_prefix_attention_dense)
-                from .generation import sample_token
-                from ..core.autograd import no_grad
-                rebind(param_arrays)
-                s = tail_ids.shape[1]
-                with no_grad():
-                    x = self.embed_tokens(Tensor(tail_ids))
-                    new_k, new_v = [], []
-                    for i, blk in enumerate(self.layers):
-                        attn = blk.self_attn
-                        h = blk.input_layernorm(x)
-                        q = attn.q_proj(h).reshape([1, s, hq, hd])
-                        k = attn.k_proj(h).reshape([1, s, hk, hd])
-                        v = attn.v_proj(h).reshape([1, s, hk, hd])
-                        q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                          position_offset=t_start)
-                        kp, vp = paged_prefill_write_masked(
-                            k_pools[i], v_pools[i], row, k._data[0],
-                            v._data[0], t_start, w_start, t_total)
-                        out = paged_prefix_attention_dense(
-                            q._data[0], kp, vp, row, t_start, t_total)
-                        x = x + attn.o_proj(
-                            Tensor(out.reshape(1, s, hq * hd)))
-                        x = x + blk.mlp(blk.post_attention_layernorm(x))
-                        new_k.append(kp)
-                        new_v.append(vp)
-                    x = self.norm(x)
-                    if self.lm_head is not None:
-                        logits = self.lm_head(x)
-                    else:
-                        from .. import ops
-                        logits = ops.matmul(x, self.embed_tokens.weight,
-                                            transpose_y=True)
-                last = jnp.take_along_axis(
-                    logits._data, (t_total - 1 - t_start)[None, None,
-                                                          None],
-                    axis=1)[:, 0]
-                tok = jax.lax.cond(
-                    temp > 0,
-                    lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                         temperature=1.0, key=key),
-                    lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-                return tok[0], new_k, new_v
-            self._paged_extend_jit = _aot_wrap(
-                jax.jit(fn), self._aot_tag("llama.paged_extend"))
-
-        with self._paged_lock():
-            arrs = self._param_arrays()
-            tok, ks, vs = self._paged_extend_jit(
-                arrs, jnp.asarray(tail), jnp.int32(tail_start),
-                jnp.int32(write_start), jnp.int32(total),
-                jnp.asarray(cache.block_tables[slot]),
-                cache.k_pools, cache.v_pools, next_key(),
-                jnp.float32(temperature))
-            self._param_rebind()(arrs)
-        cache.k_pools = list(ks)
-        cache.v_pools = list(vs)
-        cache.seq_lens[slot] = total
-        return int(tok)
+        def fn(param_arrays, tail_ids, t_start, w_start, t_total,
+               row, k_pools, v_pools, key, temp):
+            from ..core.autograd import no_grad
+            from ..inference.paged import (
+                paged_prefill_write_masked,
+                paged_prefix_attention_dense)
+            from .generation import sample_token
+            rebind(param_arrays)
+            s = tail_ids.shape[1]
+            with no_grad():
+                x = self.embed_tokens(Tensor(tail_ids))
+                new_k, new_v = [], []
+                for i, blk in enumerate(self.layers):
+                    attn = blk.self_attn
+                    h = blk.input_layernorm(x)
+                    q = attn.q_proj(h).reshape([1, s, hq, hd])
+                    k = attn.k_proj(h).reshape([1, s, hk, hd])
+                    v = attn.v_proj(h).reshape([1, s, hk, hd])
+                    q, k = apply_rope(q, k, theta=attn.rope_theta,
+                                      position_offset=t_start)
+                    kp, vp = paged_prefill_write_masked(
+                        k_pools[i], v_pools[i], row, k._data[0],
+                        v._data[0], t_start, w_start, t_total)
+                    out = paged_prefix_attention_dense(
+                        q._data[0], kp, vp, row, t_start, t_total)
+                    x = x + attn.o_proj(
+                        Tensor(out.reshape(1, s, hq * hd)))
+                    x = x + blk.mlp(blk.post_attention_layernorm(x))
+                    new_k.append(kp)
+                    new_v.append(vp)
+                x = self.norm(x)
+                if self.lm_head is not None:
+                    logits = self.lm_head(x)
+                else:
+                    from .. import ops
+                    logits = ops.matmul(x, self.embed_tokens.weight,
+                                        transpose_y=True)
+            last = jnp.take_along_axis(
+                logits._data, (t_total - 1 - t_start)[None, None, None],
+                axis=1)[:, 0]
+            tok = jax.lax.cond(
+                temp > 0,
+                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
+                                     temperature=1.0, key=key),
+                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
+            return tok[0], new_k, new_v
+        return _aot_wrap(_named_jit(fn, "llama_paged_extend"),
+                         self._aot_tag("llama.paged_extend"))
 
     def _build_extend_q8(self):
         """Quantized twin of the `_paged_extend_jit` program
@@ -657,7 +682,7 @@ class Llama(nn.Layer):
                                      temperature=1.0, key=key),
                 lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
             return tok[0], new_k, new_v, new_ks, new_vs
-        return _aot_wrap(jax.jit(fn),
+        return _aot_wrap(_named_jit(fn, "llama_paged_extend_q8"),
                          self._aot_tag("llama.paged_extend.q8"))
 
     def paged_decode_step(self, cache, last_tokens, active,
@@ -768,7 +793,8 @@ class Llama(nn.Layer):
                 return nxt, new_k, new_v
             tag = "llama.paged_decode" + (
                 "" if mode == "auto" else f".k-{mode}")
-            jits[mode] = _aot_wrap(jax.jit(fn), self._aot_tag(tag))
+            jits[mode] = _aot_wrap(_named_jit(fn, "llama_paged_decode"),
+                                   self._aot_tag(tag))
         step = jits[mode]
 
         with self._paged_lock():
@@ -861,7 +887,8 @@ class Llama(nn.Layer):
             return nxt, new_k, new_v, new_ks, new_vs
         tag = "llama.paged_decode.q8" + (
             "" if kernel_mode == "auto" else f".k-{kernel_mode}")
-        return _aot_wrap(jax.jit(fn), self._aot_tag(tag))
+        return _aot_wrap(_named_jit(fn, "llama_paged_decode_q8"),
+                         self._aot_tag(tag))
 
     # -- self-speculative decode (docs/SERVING.md "Decode speed tiers") --
 
@@ -931,14 +958,16 @@ class Llama(nn.Layer):
             nxt = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
             return nxt, new_k, new_v, new_ks, new_vs
         tag = "llama.paged_spec.q8" if quantized else "llama.paged_spec"
-        return _aot_wrap(jax.jit(fn), self._aot_tag(tag))
+        return _aot_wrap(_named_jit(fn, tag.replace(".", "_")),
+                         self._aot_tag(tag))
 
     def paged_spec_step(self, cache, last_tokens, draft_tokens, n_inputs,
                         active):
         """Speculative verify sweep: write the KV of ``1 + k``
         candidate tokens per active slot (``last_tokens[b]`` then
         ``draft_tokens[b]``) at positions ``seq_lens[b] ..`` and return
-        [B, 1 + k] greedy next tokens — ``out[b, i]`` is the token
+        [B, 1 + k] greedy next tokens (a device array: reading it is what
+        waits for the sweep) — ``out[b, i]`` is the token
         sequential greedy decode would emit after consuming input
         ``i``. ``n_inputs[b]`` (= 1 + real drafts) masks padding
         writes. Pools update in place; ``seq_lens`` do NOT advance —
@@ -967,7 +996,7 @@ class Llama(nn.Layer):
         if cache.quantized:
             cache.k_scales = list(nks)
             cache.v_scales = list(nvs)
-        return np.asarray(nxt)
+        return nxt
 
     def forward_hidden(self, input_ids, kv_sink=None):
         """Decoder stack output (post final RMSNorm), before the head."""

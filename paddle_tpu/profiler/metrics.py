@@ -34,6 +34,7 @@ is counted no matter which layer (deferred chains, lazy-vjp jits, user
 
 from __future__ import annotations
 
+import bisect
 import json
 import os
 import socket
@@ -238,12 +239,8 @@ class Histogram:
 
     def observe(self, v):
         tid = _trace_id_fn()
+        i = bisect.bisect_left(self.bounds, v)  # first bound >= v
         with self._lock:
-            i = 0
-            for b in self.bounds:
-                if v <= b:
-                    break
-                i += 1
             self._buckets[i] += 1
             self._count += 1
             self._sum += v

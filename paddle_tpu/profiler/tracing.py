@@ -43,6 +43,16 @@ Usage::
 The span catalog lives in docs/OBSERVABILITY.md; histograms link back
 here via exemplars (profiler/metrics.py) and the /metrics endpoint
 (profiler/export.py) serves ``/traces/<id>``.
+
+**Phase spans** are the other axis: a slice of the *engine's thread*
+(one scheduler step, its admission, its decode dispatch), not of a
+request. ``phase(name)`` is always on and unsampled: it enters a
+``jax.profiler.TraceAnnotation``, so while a profiler session runs the
+slice lands on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the
+device's operations, on the same clock (``benchmarks/span_reduce.py``
+reads idle-gap owners from it), and on exit it adds its elapsed time to
+the registry histogram ``serving.phase.<name>_us``. The request spans
+above carry the ``step`` of the ``serving.step`` phase that ran them.
 """
 
 from __future__ import annotations
@@ -54,10 +64,13 @@ import random
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from ..core import flags as flags_mod
 from . import metrics as _metrics
 
-__all__ = ["Span", "start_trace", "span", "record_span", "attach",
+__all__ = ["Span", "start_trace", "span", "record_span", "phase",
+           "PHASE_NAMES", "phase_histogram_name", "attach",
            "current_context", "current_trace_id", "get_trace",
            "trace_ids", "export_trace", "export_ring", "records",
            "enabled", "reset"]
@@ -178,6 +191,22 @@ class _NullSpan:
 NULL = _NullSpan()
 
 
+def _record(trace_id, span_id, parent_id, name, dur_us, status, args):
+    """Put one slice that ends NOW into the ring. The one place a ring
+    record is stamped: ``dur`` comes from the caller's monotonic clock
+    and ``ts`` is the wall clock now less ``dur``, whether the slice was
+    timed live (``Span.end``) or handed over after the fact
+    (``record_span``)."""
+    rec = {"trace": trace_id, "span": span_id, "parent": parent_id,
+           "name": name, "ts": time.time_ns() / 1000.0 - dur_us,
+           "dur": float(dur_us), "tid": threading.get_ident(),
+           "status": status}
+    if args:
+        rec["args"] = args
+    _ring.append(rec)
+    _C_SPANS.inc()
+
+
 class Span:
     """One recorded slice. Use as a context manager (sets the ambient
     context so nested spans auto-parent) or hold it and call ``end()``
@@ -185,7 +214,7 @@ class Span:
     status across threads, so it is held on the request."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "args",
-                 "_wall_us", "_start_ns", "_ended", "_token")
+                 "_start_ns", "_ended", "_token")
 
     recording = True
 
@@ -195,7 +224,6 @@ class Span:
         self.parent_id = parent_id
         self.name = name
         self.args = args
-        self._wall_us = time.time_ns() / 1000.0
         self._start_ns = time.perf_counter_ns()
         self._ended = False
         self._token = None
@@ -213,15 +241,9 @@ class Span:
         if self._ended:
             return
         self._ended = True
-        rec = {"trace": self.trace_id, "span": self.span_id,
-               "parent": self.parent_id, "name": self.name,
-               "ts": self._wall_us,
-               "dur": (time.perf_counter_ns() - self._start_ns) / 1000.0,
-               "tid": threading.get_ident(), "status": status}
-        if self.args:
-            rec["args"] = self.args
-        _ring.append(rec)
-        _C_SPANS.inc()
+        _record(self.trace_id, self.span_id, self.parent_id, self.name,
+                (time.perf_counter_ns() - self._start_ns) / 1000.0,
+                status, self.args)
 
     def context(self):
         """Picklable propagation dict (rpc wire / cross-thread)."""
@@ -286,14 +308,71 @@ def record_span(name, parent, dur_us, **attrs):
     step. No-op unless the parent is recording."""
     if not getattr(parent, "recording", False) or not _gate():
         return
-    rec = {"trace": parent.trace_id, "span": _new_id(),
-           "parent": parent.span_id, "name": name,
-           "ts": time.time_ns() / 1000.0 - dur_us, "dur": float(dur_us),
-           "tid": threading.get_ident(), "status": "ok"}
-    if attrs:
-        rec["args"] = attrs
-    _ring.append(rec)
-    _C_SPANS.inc()
+    _record(parent.trace_id, _new_id(), parent.span_id, name, dur_us,
+            "ok", attrs)
+
+
+# -- phase spans: slices of the engine's thread ------------------------------
+
+# the names are the contract (docs/OBSERVABILITY.md "Phase spans";
+# benchmarks/span_reduce.py groups idle gaps by them)
+PHASE_NAMES = (
+    "serving.engine.no_work", "serving.engine.lock_wait",
+    "serving.step", "serving.sweep", "serving.overload",
+    "serving.admit", "serving.admit.plan", "serving.admit.finish",
+    "serving.prefill.forward", "serving.prefill.pool_write",
+    "serving.prefill.readback",
+    "serving.decode", "serving.decode.prepare", "serving.decode.dispatch",
+    "serving.decode.readback", "serving.decode.emit", "serving.step_end")
+
+
+def phase_histogram_name(name):
+    """``serving.decode.dispatch`` -> ``serving.phase.decode_dispatch_us``."""
+    return "serving.phase." + \
+        name.removeprefix("serving.").replace(".", "_") + "_us"
+
+
+# 1-2-5 from 10 us to 10 s: a phase is anything from a dict lookup to a
+# cold compile
+_PHASE_BOUNDS = tuple(m * 10 ** e for e in range(1, 7) for m in (1, 2, 5)) \
+    + (10 ** 7,)
+# created once, here, like every module's histograms; ``serving.step``
+# has none of its own: the scheduler feeds ``serving.step_us`` as it
+# always did
+_PHASE_HIST = {n: _metrics.histogram(phase_histogram_name(n),
+                                     bounds=_PHASE_BOUNDS)
+               for n in PHASE_NAMES if n != "serving.step"}
+_PHASE_HIST["serving.step"] = None
+
+
+class phase:  # noqa: N801 — used as a function: ``with phase(name):``
+    """A slice of the engine's thread. Always on, never sampled: two
+    clock reads, one histogram ``observe`` and one ``TraceMe`` (while no
+    profiler session runs, its flag test alone). ``attrs`` become the
+    event's stats in the profiler's trace. Phases nest; a phase's self
+    time is its time less its children's, which the reducer of the
+    trace computes, not the program."""
+
+    __slots__ = ("_ann", "_hist", "_t0")
+
+    def __init__(self, name, **attrs):
+        self._ann = _TraceAnnotation(name, **attrs) \
+            if _TraceAnnotation.is_enabled() else None
+        self._hist = _PHASE_HIST[name]  # KeyError: not in PHASE_NAMES
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur_us = (time.perf_counter_ns() - self._t0) / 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._hist is not None:
+            self._hist.observe(dur_us)
+        return False
 
 
 @contextlib.contextmanager

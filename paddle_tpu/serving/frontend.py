@@ -410,8 +410,15 @@ class ServingEngine:
     def step(self):
         """Run one scheduling iteration (foreground mode, or extra
         nudges in background mode)."""
-        with self._lock:
+        # a client thread holds the lock inside submit(): the engine's
+        # thread waiting here is idle time that belongs to no phase of
+        # the step
+        with _tracing.phase("serving.engine.lock_wait"):
+            self._lock.acquire()
+        try:
             return self._sched.step()
+        finally:
+            self._lock.release()
 
     def run_until_idle(self):
         """Step until the scheduler is idle (foreground mode). Results
@@ -650,7 +657,9 @@ class ServingEngine:
                     while not self._sched.has_work:
                         if self._closed:
                             return
-                        self._cond.wait()
+                        # nothing to run: idle that is nobody's fault
+                        with _tracing.phase("serving.engine.no_work"):
+                            self._cond.wait()
                     if self._closed and not self._sched.has_work:
                         return
                 self.step()

@@ -174,8 +174,14 @@ class ServingRequest:
 
 
 # -- SLO instrumentation (always-on registry; see docs/SERVING.md) -------
-_US_BOUNDS = (500, 1000, 2500, 5000, 10000, 25000, 50000, 100000,
-              250000, 500000, 1000000, 5000000)
+# every bound the SLO histograms had (500 us .. 5 s on 1-2.5-5: the
+# exposition and the alert rules name those ``le``) and between them a
+# 1-2-5 series from 100 us to 10 s, so that a percentile read off
+# /metrics lands within one bucket of what a client measures
+_US_BOUNDS = tuple(sorted(
+    {500, 1000, 2500, 5000, 10000, 25000, 50000, 100000, 250000, 500000,
+     1000000, 5000000}
+    | {m * 10 ** e for e in range(2, 7) for m in (1, 2, 5)} | {10 ** 7}))
 _m_admitted = _metrics.counter("serving.admitted")
 _m_decoded = _metrics.counter("serving.decoded_tokens")
 _m_preempt = _metrics.counter("serving.preempt")
@@ -187,6 +193,9 @@ _m_shed = _metrics.counter("serving.shed")
 _m_errors = _metrics.counter("serving.errors")
 _m_cb_errors = _metrics.counter("serving.callback_errors")
 _m_steps = _metrics.counter("serving.steps")
+# context tokens (of K and of V) every layer's decode attention read:
+# sum of seq_len + 1 over the live slots of each decode step
+_m_ctx_tokens = _metrics.counter("serving.decode.context_tokens")
 _h_ttft = _metrics.histogram("serving.ttft_us", bounds=_US_BOUNDS)
 _h_itl = _metrics.histogram("serving.itl_us", bounds=_US_BOUNDS)
 _h_queue_wait = _metrics.histogram("serving.queue_wait_us",
@@ -223,6 +232,7 @@ _g_kv_quant_mult = _metrics.gauge(
 # concurrent engine's compile on another thread never leaks into this
 # scheduler's bills (profiler/accounting.py)
 _compile_s = _metrics.thread_compile_seconds
+_phase = _tracing.phase
 # same delta discipline for compile seconds the AOT cache SAVED
 # (serving/aot_cache.py): a dispatch that loaded a serialized
 # executable bills the avoided compile as aot_saved_us — informational
@@ -366,6 +376,7 @@ class Scheduler:
         self._next_admit_seq = 0
         self._last_tok = np.zeros((max_batch,), np.int64)
         self._remaining = np.zeros((max_batch,), np.int64)
+        self._step_no = 0  # ``serving.steps`` as the running step began
 
     # -- submission / cancellation ------------------------------------
 
@@ -532,26 +543,38 @@ class Scheduler:
     def step(self):
         """One iteration: sweep -> admit -> decode. Returns the list of
         (rid, token) emitted this step (prefill first tokens included)."""
-        t0 = time.monotonic()
-        self.accounting.step_begin()
-        self._sweep()
-        # overload control (serving/overload.py): pressure -> brownout
-        # ladder update -> shed lowest-priority/newest queued requests
-        # while over the watermarks — BEFORE admission, so a step never
-        # prefills work it is about to shed
-        self.overload.control(self)
-        out = self._admit()
-        out += self._decode()
-        _m_steps.inc()
-        step_us = (time.monotonic() - t0) * 1e6
-        _h_step.observe(step_us)
-        # apportion this step's wall time across the requests that did
-        # work in it (profiler/accounting.py) BEFORE the gauges so the
-        # capacity view and the attribution agree on the step boundary
-        self.accounting.step_end(step_us)
-        self._update_gauges()
-        if self.alerts is not None:
-            self.alerts.maybe_evaluate()
+        # the step's number joins a request's prefill / decode_step
+        # span to the phase spans of the step that ran it
+        self._step_no = _m_steps.value
+        with _phase("serving.step", step=self._step_no,
+                    running=len(self.running), queued=len(self.queue)):
+            t0 = time.monotonic()
+            self.accounting.step_begin()
+            with _phase("serving.sweep"):
+                self._sweep()
+            # overload control (serving/overload.py): pressure ->
+            # brownout ladder update -> shed lowest-priority/newest
+            # queued requests while over the watermarks — BEFORE
+            # admission, so a step never prefills work it is about to
+            # shed
+            with _phase("serving.overload"):
+                self.overload.control(self)
+            with _phase("serving.admit"):
+                out = self._admit()
+            with _phase("serving.decode"):
+                out += self._decode()
+            _m_steps.inc()
+            step_us = (time.monotonic() - t0) * 1e6
+            _h_step.observe(step_us)
+            with _phase("serving.step_end"):
+                # apportion this step's wall time across the requests
+                # that did work in it (profiler/accounting.py) BEFORE
+                # the gauges so the capacity view and the attribution
+                # agree on the step boundary
+                self.accounting.step_end(step_us)
+                self._update_gauges()
+                if self.alerts is not None:
+                    self.alerts.maybe_evaluate()
         return out
 
     def run_to_completion(self):
@@ -636,38 +659,39 @@ class Scheduler:
             if len(self.running) >= self.cache.max_batch:
                 break  # before planning: don't hash prompts every
                 #        decode step while the batch stays full
-            req = self.queue[0]
-            ids = self._prefill_ids(req)
-            ids_len = len(ids)
-            plan = self.cache.plan_prefix(ids) if self.prefix_cache \
-                else None
-            covered = plan.covered_tokens if plan is not None else 0
-            # full coverage still computes the final token for its
-            # logits; everything covered is free
-            uncovered = max(ids_len - covered, 1)
-            if used > 0 and budget and used + uncovered > budget:
-                break
-            slot = self.cache.alloc_slot_cached(plan) \
-                if plan is not None else self.cache.alloc_slot(ids_len)
-            if slot is None:
-                break
-            self.queue.pop(0)
-            used += uncovered
-            req.slot = slot
-            req.status = RequestStatus.RUNNING
-            req.admit_seq = self._next_admit_seq
-            self._next_admit_seq += 1
-            now = time.monotonic()
-            if req.admitted_at is None:
-                req.admitted_at = now
-                wait_us = (now - req.submitted_at) * 1e6
-                with _tracing.attach(req.span):  # exemplar -> trace_id
-                    _h_queue_wait.observe(wait_us)
-                _tracing.record_span("serving.queue_wait", req.span,
-                                     wait_us)
-                self.accounting.note_queue_wait(req, wait_us)
-            self.running[slot] = req
-            _m_admitted.inc()
+            with _phase("serving.admit.plan"):
+                req = self.queue[0]
+                ids = self._prefill_ids(req)
+                ids_len = len(ids)
+                plan = self.cache.plan_prefix(ids) if self.prefix_cache \
+                    else None
+                covered = plan.covered_tokens if plan is not None else 0
+                # full coverage still computes the final token for its
+                # logits; everything covered is free
+                uncovered = max(ids_len - covered, 1)
+                if used > 0 and budget and used + uncovered > budget:
+                    break
+                slot = self.cache.alloc_slot_cached(plan) \
+                    if plan is not None else self.cache.alloc_slot(ids_len)
+                if slot is None:
+                    break
+                self.queue.pop(0)
+                used += uncovered
+                req.slot = slot
+                req.status = RequestStatus.RUNNING
+                req.admit_seq = self._next_admit_seq
+                self._next_admit_seq += 1
+                now = time.monotonic()
+                if req.admitted_at is None:
+                    req.admitted_at = now
+                    wait_us = (now - req.submitted_at) * 1e6
+                    with _tracing.attach(req.span):  # exemplar -> trace
+                        _h_queue_wait.observe(wait_us)
+                    _tracing.record_span("serving.queue_wait", req.span,
+                                         wait_us)
+                    self.accounting.note_queue_wait(req, wait_us)
+                self.running[slot] = req
+                _m_admitted.inc()
             comp0 = _compile_s()  # compile billed to THIS request
             saved0 = _saved_s()   # ...and so are AOT-cache savings
             t_pf = time.perf_counter_ns()
@@ -680,7 +704,8 @@ class Scheduler:
                                    tokens=ids_len, pad_to=pad_to,
                                    reprefill=bool(req.generated),
                                    covered=covered,
-                                   hit_blocks=plan.hit_blocks):
+                                   hit_blocks=plan.hit_blocks,
+                                   step=self._step_no):
                     tok = int(self.model.paged_prefill_extend(
                         self.cache, slot, ids, tail_start,
                         plan.write_start,
@@ -691,38 +716,40 @@ class Scheduler:
                 with _tracing.span("serving.prefill", parent=req.span,
                                    tokens=ids_len, pad_to=pad_to,
                                    reprefill=bool(req.generated),
-                                   covered=0, hit_blocks=0):
+                                   covered=0, hit_blocks=0,
+                                   step=self._step_no):
                     tok = int(self.model.paged_prefill(
                         self.cache, slot, ids,
                         temperature=self.temperature, pad_to=pad_to))
             pf_us = (time.perf_counter_ns() - t_pf) / 1000.0
-            comp_us = (_compile_s() - comp0) * 1e6
-            if plan is not None:
-                _m_prefix_computed.inc(pad_to)
-                self.cache.commit_prefix(slot, plan)
-            # the prefill note carries only the COMPUTED (padded tail)
-            # tokens — covered prefix tokens are free in the
-            # apportionment, re-prefill bills to the preemption event
-            self.accounting.note_prefill(
-                req, pad_to, covered, comp_us,
-                reprefill=req.preempts > 0,
-                aot_saved_us=(_saved_s() - saved0) * 1e6)
-            # the admission model's EWMA sees the COMPILE-FREE cost per
-            # computed token — a cold bucket's compile must not poison
-            # the steady-state service-time estimate
-            self.overload.observe_prefill(pad_to,
-                                          max(pf_us - comp_us, 0.0))
-            self._last_tok[slot] = tok
-            self._remaining[slot] = \
-                req.max_new_tokens - len(req.generated) - 1
-            if req.prefill_only:
-                # disagg prefill stage: stop at the first token — the
-                # decode stage continues from the handed-off blocks on
-                # another replica (serving/disagg.py)
-                self._remaining[slot] = 0
-            self._emit(req, tok)
-            out.append((req.rid, tok))
-            self._maybe_finish(slot)
+            with _phase("serving.admit.finish"):
+                comp_us = (_compile_s() - comp0) * 1e6
+                if plan is not None:
+                    _m_prefix_computed.inc(pad_to)
+                    self.cache.commit_prefix(slot, plan)
+                # the prefill note carries only the COMPUTED (padded
+                # tail) tokens — covered prefix tokens are free in the
+                # apportionment, re-prefill bills to the preemption event
+                self.accounting.note_prefill(
+                    req, pad_to, covered, comp_us,
+                    reprefill=req.preempts > 0,
+                    aot_saved_us=(_saved_s() - saved0) * 1e6)
+                # the admission model's EWMA sees the COMPILE-FREE cost
+                # per computed token — a cold bucket's compile must not
+                # poison the steady-state service-time estimate
+                self.overload.observe_prefill(pad_to,
+                                              max(pf_us - comp_us, 0.0))
+                self._last_tok[slot] = tok
+                self._remaining[slot] = \
+                    req.max_new_tokens - len(req.generated) - 1
+                if req.prefill_only:
+                    # disagg prefill stage: stop at the first token —
+                    # the decode stage continues from the handed-off
+                    # blocks on another replica (serving/disagg.py)
+                    self._remaining[slot] = 0
+                self._emit(req, tok)
+                out.append((req.rid, tok))
+                self._maybe_finish(slot)
         return out
 
     def _choose_victim(self):
@@ -745,17 +772,25 @@ class Scheduler:
                 return s
         return cands[0]
 
-    def _timed_decode_dispatch(self, dispatch):
+    def _timed_decode_dispatch(self, dispatch, batch, ctx_tokens):
         """Run one batched decode program under the shared
-        instrumentation contract — compile + AOT-saved deltas billed
-        through the accountant, pure device time fed to overload
+        instrumentation contract — the dispatch and the read-back that
+        waits for its tokens each a phase, compile + AOT-saved deltas
+        billed through the accountant, pure device time fed to overload
         control — so the plain and speculative paths can never drift
-        apart in what they report. Returns (program output, wall us)."""
+        apart in what they report. ``dispatch`` returns the program's
+        tokens still on the device. Returns (tokens as numpy, wall us
+        of both)."""
         comp0 = _compile_s()
         saved0 = _saved_s()
         t_dec = time.perf_counter_ns()
-        out = dispatch()
+        with _phase("serving.decode.dispatch", batch=batch,
+                    context_tokens=ctx_tokens):
+            out = dispatch()
+        with _phase("serving.decode.readback"):  # waits for the device
+            out = np.asarray(out)
         dec_us = (time.perf_counter_ns() - t_dec) / 1000.0
+        _m_ctx_tokens.inc(ctx_tokens)
         dec_comp_us = (_compile_s() - comp0) * 1e6
         self.accounting.note_decode_compile(dec_comp_us)
         self.accounting.note_decode_aot_saved((_saved_s() - saved0) * 1e6)
@@ -772,70 +807,78 @@ class Scheduler:
             # nothing proposed (or speculative capacity unavailable):
             # this step runs the plain single-token path below —
             # bit-equivalent, just not multiplied
-        # make each slot's next position writable: grow tables (cold
-        # cached prefixes are LRU-evicted before anything else —
-        # eviction always runs before preemption), copy-on-write shared
-        # blocks; preempt a victim on true pool exhaustion (never
-        # truncate)
-        for slot in list(self.running):
-            if slot not in self.running:  # preempted as a victim below
-                continue
-            while True:
-                denied = self.cache.prepare_append(
-                    slot, int(self.cache.seq_lens[slot]) + 1)
-                if denied:
-                    break
-                if denied.reason == CapacityError.SEQ_LIMIT:
-                    # retrying can never help — only a caller bypassing
-                    # validate_request's worst-case bound can get here
-                    req = self.running[slot]
-                    raise RuntimeError(
-                        f"serving: request {req.rid} outgrew "
-                        f"max_blocks_per_seq: {denied.detail}")
-                if len(self.running) == 1:
-                    # unreachable since validate_request bounds each
-                    # request's worst-case demand to the pool; keep as
-                    # an invariant guard
-                    req = self.running[slot]
-                    need = math.ceil(
-                        (int(self.cache.seq_lens[slot]) + 1)
-                        / self.cache.block_size)
-                    raise RuntimeError(
-                        f"serving: KV pool exhausted — request "
-                        f"{req.rid} needs {need} blocks, pool has "
-                        f"{self.cache.num_blocks - 1} usable and no "
-                        "other running request to preempt; increase "
-                        "num_blocks or lower max_seq_len")
-                victim = self._choose_victim()
-                self._preempt(victim)
-                if victim == slot:
-                    break  # grower preempted itself; re-prefills later
-        if not self.running:
-            return []
-        active = np.zeros((self.cache.max_batch,), bool)
-        for slot in self.running:
-            active[slot] = True
+        with _phase("serving.decode.prepare"):
+            # make each slot's next position writable: grow tables (cold
+            # cached prefixes are LRU-evicted before anything else —
+            # eviction always runs before preemption), copy-on-write
+            # shared blocks; preempt a victim on true pool exhaustion
+            # (never truncate)
+            for slot in list(self.running):
+                if slot not in self.running:  # preempted as a victim
+                    continue
+                while True:
+                    denied = self.cache.prepare_append(
+                        slot, int(self.cache.seq_lens[slot]) + 1)
+                    if denied:
+                        break
+                    if denied.reason == CapacityError.SEQ_LIMIT:
+                        # retrying can never help — only a caller
+                        # bypassing validate_request's worst-case bound
+                        # can get here
+                        req = self.running[slot]
+                        raise RuntimeError(
+                            f"serving: request {req.rid} outgrew "
+                            f"max_blocks_per_seq: {denied.detail}")
+                    if len(self.running) == 1:
+                        # unreachable since validate_request bounds each
+                        # request's worst-case demand to the pool; keep
+                        # as an invariant guard
+                        req = self.running[slot]
+                        need = math.ceil(
+                            (int(self.cache.seq_lens[slot]) + 1)
+                            / self.cache.block_size)
+                        raise RuntimeError(
+                            f"serving: KV pool exhausted — request "
+                            f"{req.rid} needs {need} blocks, pool has "
+                            f"{self.cache.num_blocks - 1} usable and no "
+                            "other running request to preempt; increase "
+                            "num_blocks or lower max_seq_len")
+                    victim = self._choose_victim()
+                    self._preempt(victim)
+                    if victim == slot:
+                        break  # grower preempted itself; re-prefills later
+            if not self.running:
+                return []
+            active = np.zeros((self.cache.max_batch,), bool)
+            for slot in self.running:
+                active[slot] = True
+            batch = len(self.running)
+            ctx_tokens = int(self.cache.seq_lens[active].sum()) + batch
+
         # decode compiles split across the batch
         toks, dec_us = self._timed_decode_dispatch(
-            lambda: np.asarray(self.model.paged_decode_step(
+            lambda: self.model.paged_decode_step(
                 self.cache, np.asarray(self._last_tok), active,
                 temperature=self.temperature,
-                kernel_mode=self.kernel_mode)))
+                kernel_mode=self.kernel_mode),
+            batch, ctx_tokens)
         out = []
-        for slot, req in list(self.running.items()):
-            t = int(toks[slot])
-            self._last_tok[slot] = t
-            self._remaining[slot] -= 1
-            # the decode dispatch is one batched program: each live
-            # request's trace gets a slice of that step's wall time
-            _tracing.record_span("serving.decode_step", req.span,
-                                 dec_us, token=len(req.generated),
-                                 batch=len(self.running),
-                                 route=self.kernel_route)
-            self.accounting.note_decode(req)
-            self._emit(req, t)
-            out.append((req.rid, t))
-            self._maybe_finish(slot)
+        with _phase("serving.decode.emit"):
+            for slot, req in list(self.running.items()):
+                t = int(toks[slot])
+                self._last_tok[slot] = t
+                self._remaining[slot] -= 1
+                # the decode dispatch is one batched program: each live
+                # request's trace gets a slice of that step's wall time
+                _tracing.record_span("serving.decode_step", req.span,
+                                     dec_us, token=len(req.generated),
+                                     batch=len(self.running),
+                                     route=self.kernel_route,
+                                     step=self._step_no)
+                self.accounting.note_decode(req)
+                self._emit(req, t)
+                out.append((req.rid, t))
+                self._maybe_finish(slot)
         _m_decoded.inc(len(out))
         return out
 
@@ -859,96 +902,107 @@ class Scheduler:
         compose, test-pinned)."""
         k = self.spec_tokens
         bs = self.cache.block_size
-        drafts = {}
-        any_proposed = False
-        for slot, req in self.running.items():
-            cap = min(k, int(self._remaining[slot]) - 1)
-            d = _spec.propose_draft(self._prefill_ids(req), cap,
-                                    self.spec_ngram) \
-                if cap > 0 else np.empty((0,), np.int64)
-            drafts[slot] = d
-            any_proposed = any_proposed or d.size > 0
-        if not any_proposed:
-            return None
-        # capacity: every slot needs positions [len, len + 1 + drafts)
-        # writable (growth + COW of every touched shared block). Track
-        # pre-grow block counts so a mid-loop failure rolls EVERY slot
-        # back — the plain path must start from an untouched table.
-        grown = []
-        failed = None
-        for slot in list(self.running):
-            old = len(self.cache._slot_blocks[slot])
-            need = int(self.cache.seq_lens[slot]) + 1 + \
-                int(drafts[slot].size)
-            r = self.cache.prepare_append_range(slot, need)
-            if not r:
-                failed = r
-                break
-            grown.append((slot, old))
-        if failed is not None:
-            for slot, old in grown:
-                self.cache.truncate_blocks(slot, old)
-            return None
-        draft_mat = np.zeros((self.cache.max_batch, k), np.int64)
-        n_inputs = np.zeros((self.cache.max_batch,), np.int64)
-        active = np.zeros((self.cache.max_batch,), bool)
-        for slot, d in drafts.items():
-            active[slot] = True
-            n_inputs[slot] = 1 + d.size
-            draft_mat[slot, :d.size] = d
+        with _phase("serving.decode.prepare"):
+            drafts = {}
+            any_proposed = False
+            for slot, req in self.running.items():
+                cap = min(k, int(self._remaining[slot]) - 1)
+                d = _spec.propose_draft(self._prefill_ids(req), cap,
+                                        self.spec_ngram) \
+                    if cap > 0 else np.empty((0,), np.int64)
+                drafts[slot] = d
+                any_proposed = any_proposed or d.size > 0
+            if not any_proposed:
+                return None
+            # capacity: every slot needs positions [len, len + 1 +
+            # drafts) writable (growth + COW of every touched shared
+            # block). Track pre-grow block counts so a mid-loop failure
+            # rolls EVERY slot back — the plain path must start from an
+            # untouched table.
+            grown = []
+            failed = None
+            for slot in list(self.running):
+                old = len(self.cache._slot_blocks[slot])
+                need = int(self.cache.seq_lens[slot]) + 1 + \
+                    int(drafts[slot].size)
+                r = self.cache.prepare_append_range(slot, need)
+                if not r:
+                    failed = r
+                    break
+                grown.append((slot, old))
+            if failed is not None:
+                for slot, old in grown:
+                    self.cache.truncate_blocks(slot, old)
+                return None
+            draft_mat = np.zeros((self.cache.max_batch, k), np.int64)
+            n_inputs = np.zeros((self.cache.max_batch,), np.int64)
+            active = np.zeros((self.cache.max_batch,), bool)
+            for slot, d in drafts.items():
+                active[slot] = True
+                n_inputs[slot] = 1 + d.size
+                draft_mat[slot, :d.size] = d
+            # every candidate row is written, then attended with the
+            # slot's whole context
+            ctx_tokens = int(self.cache.seq_lens[active].sum()
+                             + n_inputs.sum())
+
         outs, dec_us = self._timed_decode_dispatch(
-            lambda: np.asarray(self.model.paged_spec_step(
+            lambda: self.model.paged_spec_step(
                 self.cache, np.asarray(self._last_tok), draft_mat,
-                n_inputs, active)))
+                n_inputs, active),
+            len(drafts), ctx_tokens)
         out = []
-        for slot, req in list(self.running.items()):
-            g = outs[slot]
-            proposed = int(drafts[slot].size)
-            # accept while each draft equals the model's own previous
-            # argmax — then the emitted run is g[0..m], exactly what m+1
-            # sequential steps would have produced
-            m = 0
-            while m < proposed and int(draft_mat[slot, m]) == int(g[m]):
-                m += 1
-            emitted = [int(g[i]) for i in range(m + 1)]
-            if self.eos_token_id is not None:
-                for j, t in enumerate(emitted):
-                    if t == self.eos_token_id:
-                        # sequential decode stops here: later accepted
-                        # rows must not survive
-                        emitted = emitted[:j + 1]
-                        m = j
-                        break
-            # inputs consumed = len(emitted) (last_tok + m drafts):
-            # their KV rows are exactly the ones sequential decode
-            # would have written; roll the rest back
-            new_seq = int(self.cache.seq_lens[slot]) + len(emitted)
-            self.cache.seq_lens[slot] = new_seq
-            self.cache.truncate_blocks(
-                slot, max(math.ceil(new_seq / bs), 1))
-            self._last_tok[slot] = emitted[-1]
-            self._remaining[slot] -= len(emitted)
-            _m_spec_proposed.inc(proposed)
-            _m_spec_accepted.inc(m)
-            _m_spec_rejected.inc(proposed - m)
-            if proposed:
-                with _tracing.attach(req.span):  # exemplar -> trace_id
-                    _h_spec_accept.observe(m / proposed)
-            _tracing.record_span("serving.decode_step", req.span,
-                                 dec_us, token=len(req.generated),
-                                 batch=len(self.running),
-                                 route=self.kernel_route,
-                                 spec_proposed=proposed,
-                                 spec_accepted=m)
-            if proposed:
-                self.accounting.note_spec(req, emitted=len(emitted),
-                                          proposed=proposed, accepted=m)
-            else:
-                self.accounting.note_decode(req)
-            for t in emitted:
-                self._emit(req, t)
-                out.append((req.rid, t))
-            self._maybe_finish(slot)
+        with _phase("serving.decode.emit"):
+            for slot, req in list(self.running.items()):
+                g = outs[slot]
+                proposed = int(drafts[slot].size)
+                # accept while each draft equals the model's own previous
+                # argmax — then the emitted run is g[0..m], exactly what
+                # m+1 sequential steps would have produced
+                m = 0
+                while m < proposed and \
+                        int(draft_mat[slot, m]) == int(g[m]):
+                    m += 1
+                emitted = [int(g[i]) for i in range(m + 1)]
+                if self.eos_token_id is not None:
+                    for j, t in enumerate(emitted):
+                        if t == self.eos_token_id:
+                            # sequential decode stops here: later
+                            # accepted rows must not survive
+                            emitted = emitted[:j + 1]
+                            m = j
+                            break
+                # inputs consumed = len(emitted) (last_tok + m drafts):
+                # their KV rows are exactly the ones sequential decode
+                # would have written; roll the rest back
+                new_seq = int(self.cache.seq_lens[slot]) + len(emitted)
+                self.cache.seq_lens[slot] = new_seq
+                self.cache.truncate_blocks(
+                    slot, max(math.ceil(new_seq / bs), 1))
+                self._last_tok[slot] = emitted[-1]
+                self._remaining[slot] -= len(emitted)
+                _m_spec_proposed.inc(proposed)
+                _m_spec_accepted.inc(m)
+                _m_spec_rejected.inc(proposed - m)
+                if proposed:
+                    with _tracing.attach(req.span):  # exemplar -> trace
+                        _h_spec_accept.observe(m / proposed)
+                _tracing.record_span("serving.decode_step", req.span,
+                                     dec_us, token=len(req.generated),
+                                     batch=len(self.running),
+                                     route=self.kernel_route,
+                                     spec_proposed=proposed,
+                                     spec_accepted=m, step=self._step_no)
+                if proposed:
+                    self.accounting.note_spec(req, emitted=len(emitted),
+                                              proposed=proposed,
+                                              accepted=m)
+                else:
+                    self.accounting.note_decode(req)
+                for t in emitted:
+                    self._emit(req, t)
+                    out.append((req.rid, t))
+                self._maybe_finish(slot)
         _m_spec_steps.inc()
         _m_decoded.inc(len(out))
         return out

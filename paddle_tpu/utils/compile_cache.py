@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point that compiles on the chip (chip_smoke.py,
-bench.py, tools/capture_headline_trace.py, tools/pipeline_tick_ab.py):
+bench.py, benchmarks/run.py, tools/pipeline_tick_ab.py):
 the machine decides, not the program. If ``JAX_COMPILATION_CACHE_DIR``
 is set, jax reads it itself and nothing is set in code; otherwise the
 cache is ``<checkout>/.jax_compile_cache``. The path is part of the

@@ -7,7 +7,10 @@ gate pins what the observability PR promised, in order of importance:
      near-free global read under ``TRACE_GATE_BUDGET_US``; at the
      default sample rate a full record-into-ring span stays under
      ``TRACE_GATE_SPAN_BUDGET_US`` (generous: catches a lock convoy or
-     an allocation storm, not scheduler jitter);
+     an allocation storm, not scheduler jitter); an engine-thread
+     ``tracing.phase`` with no profiler session (two clock reads, one
+     histogram observe, one TraceMe) stays under 5 us, and a served
+     request feeds every phase histogram of the step;
   2. completeness — one served request produces a complete exportable
      trace: submit root, queue-wait, prefill, one decode slice per
      decoded token, terminal event, all parent-linked;
@@ -33,6 +36,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 BUDGET_US = float(os.environ.get("TRACE_GATE_BUDGET_US", "5"))
 SPAN_BUDGET_US = float(os.environ.get("TRACE_GATE_SPAN_BUDGET_US", "75"))
+# a phase is always on: ~17 a scheduler step, 3 more a prefill
+PHASE_BUDGET_US = 5.0
 
 
 def _med_us(fn, n, trials=5):
@@ -69,6 +74,30 @@ def check_overhead():
     print(f"[trace-gate] overhead: disarmed={off_us:.3f}us "
           f"(budget {BUDGET_US}us) sampled span={on_us:.2f}us "
           f"(budget {SPAN_BUDGET_US}us) {'PASS' if ok else 'FAIL'}")
+    return ok
+
+
+def check_phases(before):
+    """The always-on phase span: its cost with no profiler session, and
+    that the request ``_serve_one`` served fed every phase histogram of
+    a foreground engine's step (no driver thread, so no ``no_work``)."""
+    from paddle_tpu.profiler import metrics, tracing
+
+    def one_phase():
+        with tracing.phase("serving.decode.dispatch", batch=4,
+                           context_tokens=99):
+            pass
+
+    after = metrics.snapshot("serving.phase.")
+    us = _med_us(one_phase, 20_000)
+    silent = [n for n in tracing.PHASE_NAMES
+              if n not in ("serving.step", "serving.engine.no_work")
+              and after[tracing.phase_histogram_name(n)]["count"]
+              <= before[tracing.phase_histogram_name(n)]["count"]]
+    ok = us < PHASE_BUDGET_US and not silent
+    print(f"[trace-gate] phases: no-session phase={us:.3f}us "
+          f"(budget {PHASE_BUDGET_US}us) silent histograms={silent} "
+          f"{'PASS' if ok else 'FAIL'}")
     return ok
 
 
@@ -152,15 +181,19 @@ def check_scrape(eng):
 
 
 def main():
+    from paddle_tpu.profiler import metrics
+
     ok1 = check_overhead()
+    phases_before = metrics.snapshot("serving.phase.")
     eng, handle = _serve_one()
     try:
         ok2 = check_complete_trace(handle)
         ok3 = check_exemplars()
         ok4 = check_scrape(eng)
+        ok5 = check_phases(phases_before)
     finally:
         eng.close()
-    if ok1 and ok2 and ok3 and ok4:
+    if ok1 and ok2 and ok3 and ok4 and ok5:
         print("[trace-gate] PASS")
         return 0
     print("[trace-gate] FAIL")
