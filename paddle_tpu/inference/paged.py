@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import hashlib
 import math
+import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
 
@@ -62,6 +63,14 @@ _PREFIX_EVICT = _metrics.counter("serving.prefix.evictions")
 _KERN_PALLAS = _metrics.counter("serving.kernel.pallas")
 _KERN_DENSE = _metrics.counter("serving.kernel.dense")
 _KERN_INTERPRET = _metrics.counter("serving.kernel.interpret")
+# whether the pools are updated in place (docs/OBSERVABILITY.md): every
+# call of a program that writes the pools takes them donated;
+# ``PagedKVCache.rebind_pools`` looks at the first pool it handed in —
+# deleted means the program consumed the buffer and wrote into it, alive
+# means the backend or a sharding refused the donation and the program
+# wrote a copy of every pool
+_KV_DONATED = _metrics.counter("serving.kv.donated_calls")
+_KV_COPIED = _metrics.counter("serving.kv.copied_calls")
 
 __all__ = ["PagedKVCache", "paged_prefill_write",
            "paged_prefill_write_masked", "paged_decode_attention",
@@ -247,10 +256,16 @@ class PrefixPlan:
 class PagedKVCache:
     """Per-layer block pools + block tables + sequence lengths.
 
-    Device state (jit-carried): k_pools/v_pools (list per layer),
+    Device state: k_pools/v_pools (list per layer; int8 pools carry
+    k_scales/v_scales beside them). The pools are BUFFERS, updated in
+    place: every program that writes them takes them donated and
+    :meth:`rebind_pools` takes what it returns, under ``pool_lock`` from
+    dispatch to rebind — the arrays handed in are deleted by the call,
+    so a reader on another thread (``kv_transfer.export_prefix``) holds
+    the lock too and nobody keeps a pool across a call. Host state:
     block_tables [max_batch, max_blocks_per_seq] int32, seq_lens
-    [max_batch] int32. Host state: free-list of block ids, per-block
-    refcounts, and the content-addressed prefix index.
+    [max_batch] int32, the free-list of block ids, per-block refcounts,
+    and the content-addressed prefix index.
 
     **Prefix sharing** (vLLM shared-block / SGLang RadixAttention
     style): a block registered in the prefix index is immutable in its
@@ -330,6 +345,9 @@ class PagedKVCache:
                              for _ in range(num_layers)]
         else:
             self.k_scales = self.v_scales = None
+        # held from the dispatch of a pool-writing program until its
+        # pools are rebound, and by any reader off the engine's thread
+        self.pool_lock = threading.RLock()
         # block 0 is reserved as the null block so fresh table entries are
         # valid indices; the length mask hides its contents
         self._free = list(range(num_blocks - 1, 0, -1))
@@ -531,21 +549,51 @@ class PagedKVCache:
             self._refcount[b] = 0
             self._release_block(b)
 
+    def pool_arrays(self):
+        """Every array a pool-writing program takes donated: the K and V
+        pools, then an int8 cache's scale arrays."""
+        out = self.k_pools + self.v_pools
+        return out + self.k_scales + self.v_scales if self.quantized \
+            else out
+
+    def rebind_pools(self, k_pools, v_pools, k_scales=None,
+                     v_scales=None):
+        """Take the pools a pool-writing program returned (the caller
+        holds ``pool_lock`` since before the dispatch). The pools handed
+        in are still bound here, so one look at the first says whether
+        the program consumed them (``serving.kv.donated_calls``) or
+        wrote copies (``serving.kv.copied_calls``)."""
+        (_KV_DONATED if self.k_pools[0].is_deleted()
+         else _KV_COPIED).inc()
+        self.k_pools = list(k_pools)
+        self.v_pools = list(v_pools)
+        if self.quantized:
+            self.k_scales = list(k_scales)
+            self.v_scales = list(v_scales)
+
+    def write_blocks(self, dst, src):
+        """Overwrite pool blocks ``dst`` in every layer's pools (and
+        scale arrays) through the one donated block-copy program:
+        ``src`` is either block ids of the same pools (copy-on-write)
+        or ``(k, v[, k_scales, v_scales])`` lists of one ``[len(dst),
+        ...]`` array a layer (a transfer landing)."""
+        dst = jnp.asarray(dst, jnp.int32)
+        if isinstance(src, tuple):
+            src = [r for rows in src for r in rows]
+        else:
+            src = jnp.asarray(src, jnp.int32)
+        with self.pool_lock:
+            out = _kv_block_copy(self.pool_arrays(), dst, src)
+            n = self.num_layers
+            self.rebind_pools(out[:n], out[n:2 * n], out[2 * n:3 * n],
+                              out[3 * n:])
+
     def _copy_block_rows(self, src, dst):
         """Copy-on-write body: duplicate one pool block across every
         layer (the K and V rows move together; quantized pools copy
         the scale rows with them — an int8 copy is bit-exact, so
         shared-vs-private content stays identical)."""
-        for i in range(self.num_layers):
-            self.k_pools[i] = self.k_pools[i].at[dst].set(
-                self.k_pools[i][src])
-            self.v_pools[i] = self.v_pools[i].at[dst].set(
-                self.v_pools[i][src])
-            if self.quantized:
-                self.k_scales[i] = self.k_scales[i].at[dst].set(
-                    self.k_scales[i][src])
-                self.v_scales[i] = self.v_scales[i].at[dst].set(
-                    self.v_scales[i][src])
+        self.write_blocks([dst], [src])
 
     def _choose_slot(self):
         """Admission slot choice: the first free slot (legacy FCFS
@@ -821,10 +869,24 @@ class PagedKVCache:
 # device-side functional ops (static shapes, jit-safe)
 # ---------------------------------------------------------------------------
 
+def _block_copy(pools, dst, src):
+    rows = src if isinstance(src, list) else [p[src] for p in pools]
+    return [p.at[dst].set(r) for p, r in zip(pools, rows)]
+
+
+_block_copy.__name__ = _block_copy.__qualname__ = "kv_block_copy"
+# the program behind ``PagedKVCache.write_blocks``: ``src`` block ids
+# gather their rows from the pools themselves, a list holds the rows
+_kv_block_copy = jax.jit(_block_copy, donate_argnums=(0,))
+
+
 def paged_prefill_write(k_pool, v_pool, block_row, k_new, v_new):
     """Write a prompt's KV [S, Hk, D] into the pool blocks listed in
     `block_row` [max_blocks_per_seq]. S is padded to a block multiple by
-    the caller; returns updated pools."""
+    the caller. Functional: returns the pools with the rows set — a
+    serving program that was handed the pools donated writes them in
+    place; called eagerly (the tests' reference) each result is a new
+    pool."""
     s = k_new.shape[0]
     bs = k_pool.shape[1]
     nb = s // bs
@@ -1143,7 +1205,8 @@ def paged_spec_write(k_pool, v_pool, block_tables, start_lens, k_new,
     positions of an active slot are real — the rest (draft padding,
     inactive slots) are masked to the reserved null block 0, the
     bucketing convention. Quantized pools (scales passed) quantize
-    per row on the way in. Returns the updated pools (+ scales)."""
+    per row on the way in. Returns the pools (+ scales) with the rows
+    set, in place inside a program that took them donated."""
     b, s = k_new.shape[:2]
     bs = k_pool.shape[1]
     pos = start_lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]

@@ -38,14 +38,18 @@ def _aot_wrap(jitted, tag):
     return wrap(jitted, tag)
 
 
-def _named_jit(fn, name):
+def _named_jit(fn, name, donate_argnums=()):
     """``jax.jit(fn)`` as the program ``jit_<name>``: the name a profiler
     trace shows on the device's ``XLA Modules`` line and the host's
     ``PjitFunction(<name>)`` events, so busy time splits by program
     (``benchmarks/span_reduce.py``). Every serving closure is called
-    ``fn`` where it is written."""
+    ``fn`` where it is written. ``donate_argnums`` names the KV pools
+    (and int8 scale arrays) of a program that writes them: it takes
+    their buffers and writes in place, and the caller rebinds what comes
+    back (``PagedKVCache.rebind_pools``) before anything reads the
+    cache."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=donate_argnums)
 
 
 @dataclasses.dataclass
@@ -341,7 +345,8 @@ class Llama(nn.Layer):
     # every jitted serving entry point this model caches; cleared when
     # the serving mesh changes so programs re-lower against the new
     # shardings (and re-fingerprint in the AOT cache under the new tag)
-    _PAGED_JIT_ATTRS = ("_paged_prefill_jit", "_paged_extend_jit",
+    _PAGED_JIT_ATTRS = ("_paged_prefill_jit", "_paged_prefill_q8_jit",
+                       "_paged_extend_jit",
                        "_paged_extend_q8_jit", "_paged_decode_jit",
                        "_paged_decode_q8_jit", "_paged_spec_jit",
                        "_paged_spec_q8_jit")
@@ -410,7 +415,8 @@ class Llama(nn.Layer):
                       pad_to=None):
         """Run the prompt through the dense forward (causal), write its
         post-rope KV into the slot's pool blocks, set seq_len, and return
-        the first sampled token.
+        the first sampled token: ONE program, which takes the pools
+        donated and writes them in place (``_build_prefill``).
 
         ``pad_to`` (serving/bucketing.py): pad the prompt to a bucketed
         length instead of the next block multiple, so warm serving traces
@@ -419,61 +425,60 @@ class Llama(nn.Layer):
         reserved null block, and everything past ``true_len`` is masked.
         """
         from ..core.random import next_key
-        from ..inference.paged import paged_prefill_write
 
-        with _phase("serving.prefill.forward"):
-            prompt = np.asarray(prompt_ids).reshape(-1)
-            s = prompt.shape[0]
-            bs = cache.block_size
-            spad = -(-s // bs) * bs
-            if pad_to is not None:
-                cap = cache.max_blocks_per_seq * bs
-                want = min(max(int(pad_to), spad), cap)
-                spad = -(-want // bs) * bs
-            ids = np.zeros((1, spad), np.int64)
-            ids[:, :s] = prompt
+        # nobody reads the cache between the dispatch, which deletes the
+        # pools it is handed, and the rebind of those it returns
+        with self._paged_lock(), cache.pool_lock:
+            with _phase("serving.prefill.forward"):
+                prompt = np.asarray(prompt_ids).reshape(-1)
+                s = prompt.shape[0]
+                bs = cache.block_size
+                spad = -(-s // bs) * bs
+                if pad_to is not None:
+                    cap = cache.max_blocks_per_seq * bs
+                    want = min(max(int(pad_to), spad), cap)
+                    spad = -(-want // bs) * bs
+                ids = np.zeros((1, spad), np.int64)
+                ids[:, :s] = prompt
 
-            if not hasattr(self, "_paged_prefill_jit"):
-                self._paged_prefill_jit = self._build_prefill()
-
-            with self._paged_lock():
+                attr = "_paged_prefill_q8_jit" if cache.quantized \
+                    else "_paged_prefill_jit"
+                if getattr(self, attr, None) is None:
+                    setattr(self, attr,
+                            self._build_prefill(cache.quantized))
                 arrs = self._param_arrays()
-                tok, ks, vs = self._paged_prefill_jit(
+                tok, *pools = getattr(self, attr)(
                     arrs, jnp.asarray(ids), jnp.int32(s),
+                    jnp.asarray(cache.block_tables[slot]),
+                    cache.k_pools, cache.v_pools,
+                    cache.k_scales if cache.quantized else [],
+                    cache.v_scales if cache.quantized else [],
                     next_key(), jnp.float32(temperature))
                 # tracing left tracers bound into the module params;
                 # restore
                 self._param_rebind()(arrs)
-        # one eager scatter a pool, each returning a new pool
-        with _phase("serving.prefill.pool_write", layers=cache.num_layers,
-                    tokens=spad):
-            row = cache.block_tables[slot]
-            for i in range(cache.num_layers):
-                if cache.quantized:
-                    from ..inference.paged import paged_prefill_write_q
-                    (cache.k_pools[i], cache.v_pools[i],
-                     cache.k_scales[i], cache.v_scales[i]) = \
-                        paged_prefill_write_q(
-                            cache.k_pools[i], cache.v_pools[i],
-                            cache.k_scales[i], cache.v_scales[i],
-                            row, ks[i], vs[i])
-                else:
-                    cache.k_pools[i], cache.v_pools[i] = \
-                        paged_prefill_write(
-                            cache.k_pools[i], cache.v_pools[i], row,
-                            ks[i], vs[i])
-            cache.seq_lens[slot] = s
+            # what is left of the write on the host: the program wrote
+            # into the pools it was handed; take them back
+            with _phase("serving.prefill.pool_write",
+                        layers=cache.num_layers, tokens=spad):
+                cache.rebind_pools(*pools)
+                cache.seq_lens[slot] = s
         with _phase("serving.prefill.readback"):  # waits for the device
             return int(tok)
 
-    def _build_prefill(self):
+    def _build_prefill(self, quantized):
         """The dense causal prefill program: the prompt's logits at its
         last true position sampled, and every layer's post-rope K and V
-        handed back for the pool write."""
+        written into the blocks of the slot's table row ``row`` — the
+        pools (and, ``quantized``, their scale arrays) come in donated
+        and go back out, written in place."""
         rebind = self._param_rebind()
 
-        def fn(param_arrays, ids_arr, true_len, key, temp):
+        def fn(param_arrays, ids_arr, true_len, row, k_pools, v_pools,
+               k_scales, v_scales, key, temp):
             from ..core.autograd import no_grad
+            from ..inference.paged import (paged_prefill_write,
+                                           paged_prefill_write_q)
             from .generation import sample_token
             rebind(param_arrays)
             sink = []
@@ -487,11 +492,27 @@ class Llama(nn.Layer):
                 lambda: sample_token(last / jnp.maximum(temp, 1e-6),
                                      temperature=1.0, key=key),
                 lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            ks = [k._data[0] for k, _ in sink]
-            vs = [v._data[0] for _, v in sink]
-            return tok[0], ks, vs
-        return _aot_wrap(_named_jit(fn, "llama_paged_prefill"),
-                         self._aot_tag("llama.paged_prefill"))
+            new_k, new_v, new_ks, new_vs = [], [], [], []
+            for i, (k, v) in enumerate(sink):
+                if quantized:
+                    kp, vp, ksc, vsc = paged_prefill_write_q(
+                        k_pools[i], v_pools[i], k_scales[i], v_scales[i],
+                        row, k._data[0], v._data[0])
+                    new_ks.append(ksc)
+                    new_vs.append(vsc)
+                else:
+                    kp, vp = paged_prefill_write(
+                        k_pools[i], v_pools[i], row, k._data[0],
+                        v._data[0])
+                new_k.append(kp)
+                new_v.append(vp)
+            return tok[0], new_k, new_v, new_ks, new_vs
+        tag = "llama.paged_prefill.q8" if quantized \
+            else "llama.paged_prefill"
+        return _aot_wrap(
+            _named_jit(fn, tag.replace(".", "_"),
+                       donate_argnums=(4, 5, 6, 7)),
+            self._aot_tag(tag))
 
     def paged_prefill_extend(self, cache, slot, ids, tail_start,
                              write_start, temperature=0.0, pad_to=None):
@@ -526,46 +547,35 @@ class Llama(nn.Layer):
             tail = np.zeros((1, spad), np.int64)
             tail[0, :s_tail] = ids[tail_start:]
 
-            if cache.quantized:
-                # int8 pools thread their scale arrays through the
-                # program and dequantize at the gathers; its own jit +
-                # AOT tag so a model can serve quantized and
-                # full-precision caches side by side
-                if getattr(self, "_paged_extend_q8_jit", None) is None:
-                    self._paged_extend_q8_jit = self._build_extend_q8()
-                with self._paged_lock():
-                    arrs = self._param_arrays()
-                    tok, ks, vs, kss, vss = self._paged_extend_q8_jit(
-                        arrs, jnp.asarray(tail), jnp.int32(tail_start),
-                        jnp.int32(write_start), jnp.int32(total),
-                        jnp.asarray(cache.block_tables[slot]),
-                        cache.k_pools, cache.v_pools,
-                        cache.k_scales, cache.v_scales, next_key(),
-                        jnp.float32(temperature))
-                    self._param_rebind()(arrs)
-                cache.k_scales = list(kss)
-                cache.v_scales = list(vss)
-            else:
-                if not hasattr(self, "_paged_extend_jit"):
-                    self._paged_extend_jit = self._build_extend()
-                with self._paged_lock():
-                    arrs = self._param_arrays()
-                    tok, ks, vs = self._paged_extend_jit(
-                        arrs, jnp.asarray(tail), jnp.int32(tail_start),
-                        jnp.int32(write_start), jnp.int32(total),
-                        jnp.asarray(cache.block_tables[slot]),
-                        cache.k_pools, cache.v_pools, next_key(),
-                        jnp.float32(temperature))
-                    self._param_rebind()(arrs)
-            cache.k_pools = list(ks)
-            cache.v_pools = list(vs)
+            # int8 pools thread their scale arrays through the program
+            # and dequantize at the gathers; its own jit + AOT tag so a
+            # model can serve quantized and full-precision caches side
+            # by side
+            attr = "_paged_extend_q8_jit" if cache.quantized \
+                else "_paged_extend_jit"
+            if getattr(self, attr, None) is None:
+                setattr(self, attr, self._build_extend_q8()
+                        if cache.quantized else self._build_extend())
+            scales = (cache.k_scales, cache.v_scales) \
+                if cache.quantized else ()
+            with self._paged_lock(), cache.pool_lock:
+                arrs = self._param_arrays()
+                tok, *pools = getattr(self, attr)(
+                    arrs, jnp.asarray(tail), jnp.int32(tail_start),
+                    jnp.int32(write_start), jnp.int32(total),
+                    jnp.asarray(cache.block_tables[slot]),
+                    cache.k_pools, cache.v_pools, *scales, next_key(),
+                    jnp.float32(temperature))
+                self._param_rebind()(arrs)
+                cache.rebind_pools(*pools)
             cache.seq_lens[slot] = total
         with _phase("serving.prefill.readback"):  # waits for the device
             return int(tok)
 
     def _build_extend(self):
         """The tail-extend program of ``paged_prefill_extend``: the pool
-        write is inside it (``paged_prefill_write_masked``)."""
+        write is inside it (``paged_prefill_write_masked``), in place in
+        the donated pools."""
         rebind = self._param_rebind()
         cfg = self.config
         hq = cfg.num_heads
@@ -618,8 +628,9 @@ class Llama(nn.Layer):
                                      temperature=1.0, key=key),
                 lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
             return tok[0], new_k, new_v
-        return _aot_wrap(_named_jit(fn, "llama_paged_extend"),
-                         self._aot_tag("llama.paged_extend"))
+        return _aot_wrap(
+            _named_jit(fn, "llama_paged_extend", donate_argnums=(6, 7)),
+            self._aot_tag("llama.paged_extend"))
 
     def _build_extend_q8(self):
         """Quantized twin of the `_paged_extend_jit` program
@@ -682,8 +693,10 @@ class Llama(nn.Layer):
                                      temperature=1.0, key=key),
                 lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
             return tok[0], new_k, new_v, new_ks, new_vs
-        return _aot_wrap(_named_jit(fn, "llama_paged_extend_q8"),
-                         self._aot_tag("llama.paged_extend.q8"))
+        return _aot_wrap(
+            _named_jit(fn, "llama_paged_extend_q8",
+                       donate_argnums=(6, 7, 8, 9)),
+            self._aot_tag("llama.paged_extend.q8"))
 
     def paged_decode_step(self, cache, last_tokens, active,
                           temperature=0.0, kernel_mode=None):
@@ -701,117 +714,102 @@ class Llama(nn.Layer):
         from ..inference.paged import resolve_paged_kernel
 
         mode = resolve_paged_kernel(kernel_mode)
-
-        if cache.quantized:
-            jits = self.__dict__.setdefault("_paged_decode_q8_jit", {})
-            if jits.get(mode) is None:
-                jits[mode] = self._build_decode_q8(mode)
-            step = jits[mode]
-            with self._paged_lock():
-                arrs = self._param_arrays()
-                toks, nk, nv, nks, nvs = step(
-                    arrs, jnp.asarray(last_tokens, jnp.int32),
-                    cache.k_pools, cache.v_pools, cache.k_scales,
-                    cache.v_scales, cache.block_tables,
-                    jnp.asarray(cache.seq_lens), jnp.asarray(active),
-                    next_key(), jnp.float32(temperature))
-                self._param_rebind()(arrs)
-            cache.k_pools = list(nk)
-            cache.v_pools = list(nv)
-            cache.k_scales = list(nks)
-            cache.v_scales = list(nvs)
-            act = np.asarray(active)
-            cache.seq_lens = np.where(act, cache.seq_lens + 1,
-                                      cache.seq_lens).astype(np.int32)
-            return toks
-
-        jits = self.__dict__.setdefault("_paged_decode_jit", {})
+        attr = "_paged_decode_q8_jit" if cache.quantized \
+            else "_paged_decode_jit"
+        jits = self.__dict__.setdefault(attr, {})
         if jits.get(mode) is None:
-            rebind = self._param_rebind()
-            cfg = self.config
-            hq = cfg.num_heads
-            hk = cfg.num_kv_heads
-            hd = cfg.hidden_size // hq
-            # mesh-sharded serving: captured at build time — the jit is
-            # rebuilt (apply_serving_mesh clears it) when the mesh
-            # changes. A model-sharded mesh runs the attention
-            # explicitly sharded per kv-head under shard_map.
-            mesh = self.__dict__.get("_serving_mesh")
-            use_tp = mesh is not None and mesh.shard_map_armed
-
-            def fn(param_arrays, toks, k_pools, v_pools, tables, lens,
-                   active, key, temp):
-                from ..inference.paged import (paged_decode_attention,
-                                               paged_decode_attention_tp,
-                                               paged_decode_write)
-                from .generation import sample_token
-                from ..core.autograd import no_grad
-                rebind(param_arrays)
-                b = toks.shape[0]
-                with no_grad():
-                    x = self.embed_tokens(Tensor(toks[:, None]))
-                    new_k, new_v = [], []
-                    for i, blk in enumerate(self.layers):
-                        attn = blk.self_attn
-                        h = blk.input_layernorm(x)
-                        q = attn.q_proj(h).reshape([b, 1, hq, hd])
-                        k = attn.k_proj(h).reshape([b, 1, hk, hd])
-                        v = attn.v_proj(h).reshape([b, 1, hk, hd])
-                        q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                          position_offset=lens)
-                        kp, vp = paged_decode_write(
-                            k_pools[i], v_pools[i], tables, lens,
-                            k._data[:, 0], v._data[:, 0], active)
-                        if use_tp:
-                            out = paged_decode_attention_tp(
-                                q._data[:, 0], kp, vp, tables,
-                                jnp.where(active, lens + 1, lens), mesh,
-                                kernel_mode=mode)
-                        else:
-                            out = paged_decode_attention(
-                                q._data[:, 0], kp, vp, tables,
-                                jnp.where(active, lens + 1, lens),
-                                kernel_mode=mode)
-                        x = x + attn.o_proj(
-                            Tensor(out.reshape(b, 1, hq * hd)))
-                        x = x + blk.mlp(blk.post_attention_layernorm(x))
-                        new_k.append(kp)
-                        new_v.append(vp)
-                    x = self.norm(x)
-                    if self.lm_head is not None:
-                        logits = self.lm_head(x)
-                    else:
-                        from .. import ops
-                        logits = ops.matmul(x, self.embed_tokens.weight,
-                                            transpose_y=True)
-                last = logits._data[:, 0]
-                nxt = jax.lax.cond(
-                    temp > 0,
-                    lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                         temperature=1.0, key=key),
-                    lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-                return nxt, new_k, new_v
-            tag = "llama.paged_decode" + (
-                "" if mode == "auto" else f".k-{mode}")
-            jits[mode] = _aot_wrap(_named_jit(fn, "llama_paged_decode"),
-                                   self._aot_tag(tag))
-        step = jits[mode]
-
-        with self._paged_lock():
+            jits[mode] = (self._build_decode_q8 if cache.quantized
+                          else self._build_decode)(mode)
+        scales = (cache.k_scales, cache.v_scales) \
+            if cache.quantized else ()
+        with self._paged_lock(), cache.pool_lock:
             arrs = self._param_arrays()
-            toks, new_k, new_v = step(
+            toks, *pools = jits[mode](
                 arrs, jnp.asarray(last_tokens, jnp.int32),
-                cache.k_pools, cache.v_pools, cache.block_tables,
-                jnp.asarray(cache.seq_lens), jnp.asarray(active),
-                next_key(),
+                cache.k_pools, cache.v_pools, *scales,
+                cache.block_tables, jnp.asarray(cache.seq_lens),
+                jnp.asarray(active), next_key(),
                 jnp.float32(temperature))
             self._param_rebind()(arrs)
-        cache.k_pools = list(new_k)
-        cache.v_pools = list(new_v)
+            cache.rebind_pools(*pools)
         act = np.asarray(active)
         cache.seq_lens = np.where(act, cache.seq_lens + 1,
                                   cache.seq_lens).astype(np.int32)
         return toks
+
+    def _build_decode(self, mode="auto"):
+        """The batched decode program: each live slot's incoming token
+        written into the donated pools (``paged_decode_write``), then
+        attended against them by the route ``mode`` picks."""
+        rebind = self._param_rebind()
+        cfg = self.config
+        hq = cfg.num_heads
+        hk = cfg.num_kv_heads
+        hd = cfg.hidden_size // hq
+        # mesh-sharded serving: captured at build time — the jit is
+        # rebuilt (apply_serving_mesh clears it) when the mesh
+        # changes. A model-sharded mesh runs the attention
+        # explicitly sharded per kv-head under shard_map.
+        mesh = self.__dict__.get("_serving_mesh")
+        use_tp = mesh is not None and mesh.shard_map_armed
+
+        def fn(param_arrays, toks, k_pools, v_pools, tables, lens,
+               active, key, temp):
+            from ..inference.paged import (paged_decode_attention,
+                                           paged_decode_attention_tp,
+                                           paged_decode_write)
+            from .generation import sample_token
+            from ..core.autograd import no_grad
+            rebind(param_arrays)
+            b = toks.shape[0]
+            with no_grad():
+                x = self.embed_tokens(Tensor(toks[:, None]))
+                new_k, new_v = [], []
+                for i, blk in enumerate(self.layers):
+                    attn = blk.self_attn
+                    h = blk.input_layernorm(x)
+                    q = attn.q_proj(h).reshape([b, 1, hq, hd])
+                    k = attn.k_proj(h).reshape([b, 1, hk, hd])
+                    v = attn.v_proj(h).reshape([b, 1, hk, hd])
+                    q, k = apply_rope(q, k, theta=attn.rope_theta,
+                                      position_offset=lens)
+                    kp, vp = paged_decode_write(
+                        k_pools[i], v_pools[i], tables, lens,
+                        k._data[:, 0], v._data[:, 0], active)
+                    if use_tp:
+                        out = paged_decode_attention_tp(
+                            q._data[:, 0], kp, vp, tables,
+                            jnp.where(active, lens + 1, lens), mesh,
+                            kernel_mode=mode)
+                    else:
+                        out = paged_decode_attention(
+                            q._data[:, 0], kp, vp, tables,
+                            jnp.where(active, lens + 1, lens),
+                            kernel_mode=mode)
+                    x = x + attn.o_proj(
+                        Tensor(out.reshape(b, 1, hq * hd)))
+                    x = x + blk.mlp(blk.post_attention_layernorm(x))
+                    new_k.append(kp)
+                    new_v.append(vp)
+                x = self.norm(x)
+                if self.lm_head is not None:
+                    logits = self.lm_head(x)
+                else:
+                    from .. import ops
+                    logits = ops.matmul(x, self.embed_tokens.weight,
+                                        transpose_y=True)
+            last = logits._data[:, 0]
+            nxt = jax.lax.cond(
+                temp > 0,
+                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
+                                     temperature=1.0, key=key),
+                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
+            return nxt, new_k, new_v
+        tag = "llama.paged_decode" + (
+            "" if mode == "auto" else f".k-{mode}")
+        return _aot_wrap(
+            _named_jit(fn, "llama_paged_decode", donate_argnums=(2, 3)),
+            self._aot_tag(tag))
 
     def _build_decode_q8(self, kernel_mode="auto"):
         """Quantized twin of the `_paged_decode_jit` program: the
@@ -887,8 +885,10 @@ class Llama(nn.Layer):
             return nxt, new_k, new_v, new_ks, new_vs
         tag = "llama.paged_decode.q8" + (
             "" if kernel_mode == "auto" else f".k-{kernel_mode}")
-        return _aot_wrap(_named_jit(fn, "llama_paged_decode_q8"),
-                         self._aot_tag(tag))
+        return _aot_wrap(
+            _named_jit(fn, "llama_paged_decode_q8",
+                       donate_argnums=(2, 3, 4, 5)),
+            self._aot_tag(tag))
 
     # -- self-speculative decode (docs/SERVING.md "Decode speed tiers") --
 
@@ -958,8 +958,10 @@ class Llama(nn.Layer):
             nxt = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
             return nxt, new_k, new_v, new_ks, new_vs
         tag = "llama.paged_spec.q8" if quantized else "llama.paged_spec"
-        return _aot_wrap(_named_jit(fn, tag.replace(".", "_")),
-                         self._aot_tag(tag))
+        return _aot_wrap(
+            _named_jit(fn, tag.replace(".", "_"),
+                       donate_argnums=(6, 7, 8, 9)),
+            self._aot_tag(tag))
 
     def paged_spec_step(self, cache, last_tokens, draft_tokens, n_inputs,
                         active):
@@ -980,9 +982,9 @@ class Llama(nn.Layer):
         toks = np.concatenate(
             [np.asarray(last_tokens).reshape(-1, 1),
              np.asarray(draft_tokens)], axis=1)
-        with self._paged_lock():
+        with self._paged_lock(), cache.pool_lock:
             arrs = self._param_arrays()
-            nxt, nk, nv, nks, nvs = getattr(self, attr)(
+            nxt, *pools = getattr(self, attr)(
                 arrs, jnp.asarray(toks, jnp.int32),
                 jnp.asarray(cache.seq_lens),
                 jnp.asarray(n_inputs, jnp.int32),
@@ -991,11 +993,7 @@ class Llama(nn.Layer):
                 cache.k_scales if cache.quantized else [],
                 cache.v_scales if cache.quantized else [])
             self._param_rebind()(arrs)
-        cache.k_pools = list(nk)
-        cache.v_pools = list(nv)
-        if cache.quantized:
-            cache.k_scales = list(nks)
-            cache.v_scales = list(nvs)
+            cache.rebind_pools(*pools)
         return nxt
 
     def forward_hidden(self, input_ids, kv_sink=None):

@@ -247,7 +247,8 @@ def _load(fp):
             raise _Corrupt("metadata disagrees with filename")
         from jax.experimental import serialize_executable as _se
         compiled = _se.deserialize_and_load(
-            meta["exe"], meta["in_tree"], meta["out_tree"])
+            meta["exe"], meta["in_tree"], meta["out_tree"],
+            execution_devices=_devices_by_id(meta.get("device_ids")))
     except Exception as e:  # noqa: BLE001 — ANY load failure quarantines:
         # the entry claimed this fingerprint and could not deliver it
         _quarantine(path, f"{type(e).__name__}: {e}")
@@ -255,6 +256,17 @@ def _load(fp):
     _h_load_us.observe((time.perf_counter_ns() - t0) / 1000.0)
     _c_bytes.inc(len(raw))
     return compiled, meta
+
+
+def _devices_by_id(ids):
+    """The devices an entry was compiled for (None for an entry from
+    before they were recorded: the loader then takes every device of the
+    backend, which is right only where the program spans them all)."""
+    if not ids:
+        return None
+    import jax
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in ids]  # KeyError quarantines the entry
 
 
 def _store(fp, compiled, compile_s, tag):
@@ -272,7 +284,10 @@ def _store(fp, compiled, compile_s, tag):
             {"format": FORMAT, "fingerprint": fp, "tag": str(tag),
              "compile_s": float(compile_s), "ts": time.time(),
              "backend": _backend_sig(), "exe": exe,
-             "in_tree": in_tree, "out_tree": out_tree})
+             "in_tree": in_tree, "out_tree": out_tree,
+             # a program for one device of eight must load onto one
+             "device_ids": [d.id for d in compiled.runtime_executable()
+                            .local_devices()]})
         os.makedirs(cache_dir(), exist_ok=True)
         blob = (MAGIC
                 + _HEADER.pack(zlib.crc32(payload).to_bytes(4, "big"),

@@ -250,18 +250,20 @@ def export_prefix(cache, token_ids):
                        parent, ids[plan.num_tokens - plan.partial_len:])}
         blocks.append(plan.partial_block)
     idx = np.asarray(blocks, np.int32)
-    k_rows = [np.asarray(cache.k_pools[i][idx])
-              for i in range(cache.num_layers)]
-    v_rows = [np.asarray(cache.v_pools[i][idx])
-              for i in range(cache.num_layers)]
-    obj = {"version": _VERSION, "geom": _geometry(cache), "ids": ids,
-           "digests": list(plan.digests), "partial": partial,
-           "k": k_rows, "v": v_rows, "k_scales": None, "v_scales": None}
-    if cache.quantized:
-        obj["k_scales"] = [np.asarray(cache.k_scales[i][idx])
-                           for i in range(cache.num_layers)]
-        obj["v_scales"] = [np.asarray(cache.v_scales[i][idx])
-                           for i in range(cache.num_layers)]
+
+    def rows(pools):
+        return [np.asarray(p[idx]) for p in pools]
+
+    # a step on the engine's thread deletes the pools it is handed
+    # (they are donated): read between two steps, never across one
+    with cache.pool_lock:
+        obj = {"version": _VERSION, "geom": _geometry(cache), "ids": ids,
+               "digests": list(plan.digests), "partial": partial,
+               "k": rows(cache.k_pools), "v": rows(cache.v_pools),
+               "k_scales": None, "v_scales": None}
+        if cache.quantized:
+            obj["k_scales"] = rows(cache.k_scales)
+            obj["v_scales"] = rows(cache.v_scales)
     frame = pack_frame(pickle.dumps(obj, protocol=4))
     return frame, ExportedPrefix(plan.num_tokens, plan.matched_full,
                                  plan.partial_len, len(frame))
@@ -358,17 +360,10 @@ def import_prefix(cache, frame):
         taken.append(b)
     if taken:
         src = np.asarray([i for i, _, _ in land], np.int64)
-        dst = np.asarray(taken, np.int64)
-        for i in range(cache.num_layers):
-            cache.k_pools[i] = cache.k_pools[i].at[dst].set(
-                np.asarray(obj["k"][i])[src])
-            cache.v_pools[i] = cache.v_pools[i].at[dst].set(
-                np.asarray(obj["v"][i])[src])
-            if cache.quantized:
-                cache.k_scales[i] = cache.k_scales[i].at[dst].set(
-                    np.asarray(obj["k_scales"][i])[src])
-                cache.v_scales[i] = cache.v_scales[i].at[dst].set(
-                    np.asarray(obj["v_scales"][i])[src])
+        names = ("k", "v") + (("k_scales", "v_scales")
+                              if cache.quantized else ())
+        cache.write_blocks(taken, tuple(
+            [np.asarray(r)[src] for r in obj[name]] for name in names))
     # register, then park refcount-0 in the reclaimable LRU — byte-for-
     # byte the state commit_prefix + free_slot leaves local blocks in
     for (_, kind, key), b in zip(land, taken):

@@ -112,6 +112,72 @@ def test_roundtrip_fp32_bit_exact(tiny_llama):
         np.testing.assert_array_equal(sv, dv)
 
 
+# -- the pools are donated: nobody keeps one across a step (ISSUE 27) -------
+
+@pytest.mark.filterwarnings("error::UserWarning")
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["plain", "int8"])
+def test_a_landing_consumes_the_pools_and_counts_as_donated(tiny_llama,
+                                                            kv_dtype):
+    src, _ = _prefill_engine(tiny_llama, kv_cache_dtype=kv_dtype)
+    dst = tiny_engine(_same_weights_model(), prefix_cache=True,
+                      role="decode", kv_cache_dtype=kv_dtype)
+    frame, _ = kv_transfer.export_prefix(src.cache, PROMPT)
+    handed_in = dst.cache.pool_arrays()
+    c0 = metrics.snapshot("serving.kv.")
+    kv_transfer.import_prefix(dst.cache, frame)
+    c1 = metrics.snapshot("serving.kv.")
+    assert all(a.is_deleted() for a in handed_in)
+    # one block-copy program for every pool, not one scatter a pool
+    assert c1["serving.kv.donated_calls"] \
+        == c0["serving.kv.donated_calls"] + 1
+    assert c1["serving.kv.copied_calls"] == c0["serving.kv.copied_calls"]
+
+
+def test_export_beside_a_stepping_engine_never_reads_a_deleted_pool(
+        tiny_llama):
+    """``export_prefix`` runs on the pipeline's thread while the
+    prefill replica's own thread steps other requests. Each step
+    deletes the pools it is handed; the export holds ``pool_lock`` and
+    so reads between two steps — without it this raises "Array has
+    been deleted" within a few tries."""
+    import threading
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import ServingEngine
+
+    eng = ServingEngine(tiny_llama, max_batch=4, block_size=8,
+                        max_seq_len=64, bucket_cap=32, temperature=0.0,
+                        dtype=jnp.float32, prefix_cache=True,
+                        background=True)
+    eng.submit(PROMPT, max_new_tokens=1).result(timeout=60)
+    want, _ = kv_transfer.export_prefix(eng.cache, PROMPT)
+    errors, done = [], threading.Event()
+
+    def exporter():
+        try:
+            while not done.is_set():
+                frame, _ = kv_transfer.export_prefix(eng.cache, PROMPT)
+                assert frame == want
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    t = threading.Thread(target=exporter)
+    t.start()
+    try:
+        rng = np.random.default_rng(3)
+        for _ in range(3):
+            hs = [eng.submit(rng.integers(20, 250, size=9),
+                             max_new_tokens=12) for _ in range(3)]
+            for h in hs:
+                assert len(h.result(timeout=120)) == 12
+    finally:
+        done.set()
+        t.join(timeout=60)
+        eng.close()
+    assert not errors, errors
+
+
 def test_roundtrip_int8_data_and_scales_move_together(tiny_llama):
     src, _ = _prefill_engine(_same_weights_model(),
                              kv_cache_dtype="int8")

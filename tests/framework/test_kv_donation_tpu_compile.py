@@ -1,0 +1,107 @@
+"""The donated serving programs, compiled for a described TPU v5e with no
+chip attached (ISSUE 27): at the benchmark's attention geometry (32 query
+/ 8 KV heads of 128, a pool of 4097 blocks of 16 tokens, 32 slots of 128
+pages) the chip's compiler pairs every pool with an output and leaves no
+whole-pool ``copy`` in front of the scatter or the Pallas call — the
+copy that was the largest device operation of both serving cells
+(PERF.md, PR 26). Nothing runs; a time comes only from the chip.
+
+The only file that describes a topology: the process that does holds the
+TPU library until it exits, so these stay together and the call stays in
+a fixture (``on-chip-measurement`` guide, section 2).
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu as paddle
+
+LAYERS, SLOTS, PAGES, BLOCK, BLOCKS = 1, 32, 128, 16, 4097
+POOL = (BLOCKS, BLOCK, 8, 128)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def model():
+    """Mistral-7B's attention geometry; MLP and vocabulary cut down (they
+    touch no pool) so the parameters are 60M, not 7B."""
+    from paddle_tpu.models import Llama, LlamaConfig
+
+    paddle.seed(0)
+    m = Llama(LlamaConfig(vocab_size=1024, hidden_size=4096,
+                          intermediate_size=1024, num_layers=LAYERS,
+                          num_heads=32, num_kv_heads=8,
+                          max_position_embeddings=4096, rope_theta=1e6))
+    m.eval()
+    return m
+
+
+def _lower(model, program, one_chip, quantized):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = [s(POOL, jnp.int8 if quantized else jnp.bfloat16)] * LAYERS
+    scales = [s(POOL[:3], jnp.float32)] * LAYERS if quantized else []
+    arrs = model._param_arrays()
+    params = tuple(s(a.shape, jnp.bfloat16) for a in arrs)
+    key = jax.random.key(0)
+    key, temp = s(key.shape, key.dtype), s((), jnp.float32)
+    try:
+        if program == "decode":
+            build = model._build_decode_q8 if quantized \
+                else model._build_decode
+            return build("pallas")._jitted.lower(
+                params, s((SLOTS,), jnp.int32), pools, pools,
+                *([scales, scales] if quantized else []),
+                s((SLOTS, PAGES), jnp.int32), s((SLOTS,), jnp.int32),
+                s((SLOTS,), jnp.bool_), key, temp)
+        return model._build_prefill(quantized)._jitted.lower(
+            params, s((1, 512), jnp.int64), s((), jnp.int32),
+            s((PAGES,), jnp.int32), pools, pools, scales, scales, key,
+            temp)
+    finally:
+        model._param_rebind()(arrs)
+
+
+@pytest.mark.parametrize("program, quantized", [
+    ("decode", False), ("decode", True), ("prefill", False),
+    ("prefill", True)],
+    ids=["decode", "decode-int8", "prefill", "prefill-int8"])
+def test_the_v5e_compiler_aliases_every_pool_and_copies_none(
+        monkeypatch, one_chip, model, program, quantized):
+    monkeypatch.setenv("PADDLE_PALLAS_FORCE_COMPILE", "1")
+    compiled = _lower(model, program, one_chip, quantized).compile()
+    text = compiled.as_text()
+    header = text[:text.index("\n")]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                         header)
+    assert len(aliased) == (4 if quantized else 2) * LAYERS, header[:400]
+    pool = r"(?:bf16|s8)\[4097,16,8,128\]"
+    copies = [ln.strip()[:160] for ln in text.split("\n")
+              if re.search(rf"= {pool}\S* copy\(", ln)]
+    assert not copies, copies
+    if program == "decode":
+        assert "tpu_custom_call" in text  # the Pallas kernel is in it
+    # one pool of K and one of V a layer is all the program holds:
+    # arguments alias outputs, and temporaries stay far under one pool
+    mem = compiled.memory_analysis()
+    one_pool = 4097 * 16 * 8 * 128 * (1 if quantized else 2)
+    assert mem.alias_size_in_bytes >= 2 * LAYERS * one_pool
+    assert mem.temp_size_in_bytes < one_pool

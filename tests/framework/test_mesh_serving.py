@@ -352,3 +352,62 @@ def test_capacity_view_renders_slices():
     text = "\n".join(_capacity_view(snap))
     assert "kv.slice[0]" in text
     assert "kv.slice[1]" in text
+
+
+# -- sharded pools are written in place too (ISSUE 27) ---------------------
+
+@pytest.mark.filterwarnings("error::UserWarning")  # "Some donated
+# buffers were not usable": an output sharded otherwise than its input
+@pytest.mark.parametrize("spec, kv_dtype", [
+    ("1x2", None), ("2x4", None), ("1x2", "int8")],
+    ids=["mesh-1x2", "mesh-2x4", "mesh-1x2-int8"])
+def test_sharded_programs_take_the_pools_donated(mixed_base, spec,
+                                                 kv_dtype):
+    """Prefill and decode on the mesh: every program consumes the pools
+    it is handed, what comes back is sharded as what went in, every call
+    counts as donated and none as copied, and the greedy tokens are the
+    single-device ones."""
+    import jax.numpy as jnp
+
+    from conftest import assert_lowered_donates
+    from paddle_tpu.serving import ServingEngine
+
+    eng = ServingEngine(_model(), max_batch=4, block_size=8,
+                        max_seq_len=64, temperature=0.0, bucket_cap=32,
+                        background=False, dtype=jnp.float32, mesh=spec,
+                        kv_cache_dtype=kv_dtype)
+    cache, model = eng.cache, eng.scheduler.model
+    handed_in = cache.pool_arrays()
+    shardings = [a.sharding for a in handed_in]
+    assert len(shardings[0].device_set) == eng.scheduler.mesh.devices
+    c0 = metrics.snapshot("serving.kv.")
+    hs = [eng.submit(p, max_new_tokens=8) for p in _mixed()]
+    eng.run_until_idle()
+    c1 = metrics.snapshot("serving.kv.")
+    assert all(a.is_deleted() for a in handed_in)
+    for a, want in zip(cache.pool_arrays(), shardings):
+        assert a.sharding.is_equivalent_to(want, a.ndim)
+    assert c1["serving.kv.donated_calls"] \
+        - c0["serving.kv.donated_calls"] >= 3 + 7  # prefills + decodes
+    assert c1["serving.kv.copied_calls"] == c0["serving.kv.copied_calls"]
+    if kv_dtype is None:
+        assert [h.tokens() for h in hs] == mixed_base
+
+    # the sharded decode program, lowered: pools donated, nothing else
+    import jax
+
+    quantized = kv_dtype is not None
+    program = model.__dict__["_paged_decode_q8_jit" if quantized
+                             else "_paged_decode_jit"]["auto"]
+    scales = (cache.k_scales, cache.v_scales) if quantized else ()
+    arrs = model._param_arrays()
+    try:
+        lowered = program._jitted.lower(
+            arrs, jnp.zeros((4,), jnp.int32), cache.k_pools,
+            cache.v_pools, *scales, cache.block_tables,
+            jnp.asarray(cache.seq_lens), jnp.ones((4,), bool),
+            jax.random.key(0), jnp.float32(0.0))
+    finally:
+        model._param_rebind()(arrs)
+    assert_lowered_donates(lowered, (2, 3, 4, 5) if quantized else (2, 3))
+    eng.close()

@@ -177,3 +177,243 @@ def test_paged_gqa_ratio(model):
     rid = eng.add_request(prompt, max_new_tokens=8)
     out = eng.run_to_completion()
     assert out[rid] == ref
+
+
+# -- the pools are written in place (ISSUE 27) ----------------------------
+
+from conftest import (assert_lowered_donates, assert_pools_equal,  # noqa: E402
+                      dispatches, pools_numpy, undonated_twin)
+
+# "Some donated buffers were not usable" is a UserWarning
+_DONATION = pytest.mark.filterwarnings("error::UserWarning")
+_KV = pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                              ids=["plain", "int8"])
+
+
+def _cache(model, kv_dtype, max_batch=3):
+    import jax.numpy as jnp
+
+    cfg = model.config
+    return PagedKVCache(cfg.num_layers, cfg.num_kv_heads,
+                        cfg.hidden_size // cfg.num_heads, num_blocks=24,
+                        block_size=8, max_blocks_per_seq=4,
+                        max_batch=max_batch, dtype=jnp.float32,
+                        kv_dtype=kv_dtype)
+
+
+def _eager_prefill(model, cache, slot, prompt, spad):
+    """The eager reference of a prefill: the plain forward's post-rope K
+    and V of every layer, written by the eager ``paged_prefill_write``
+    (``_q``), one pool at a time as the serving path did before the
+    write moved into the program. Returns the greedy first token."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.inference.paged import (paged_prefill_write,
+                                            paged_prefill_write_q)
+
+    ids = np.zeros((1, spad), np.int64)
+    ids[0, :len(prompt)] = prompt
+    sink = []
+    with no_grad():
+        logits = model.forward(paddle.to_tensor(ids), kv_sink=sink)
+    row = jnp.asarray(cache.block_tables[slot])
+    for i, (k, v) in enumerate(sink):
+        if cache.quantized:
+            (cache.k_pools[i], cache.v_pools[i], cache.k_scales[i],
+             cache.v_scales[i]) = paged_prefill_write_q(
+                cache.k_pools[i], cache.v_pools[i], cache.k_scales[i],
+                cache.v_scales[i], row, k._data[0], v._data[0])
+        else:
+            cache.k_pools[i], cache.v_pools[i] = paged_prefill_write(
+                cache.k_pools[i], cache.v_pools[i], row, k._data[0],
+                v._data[0])
+    cache.seq_lens[slot] = len(prompt)
+    return int(np.argmax(logits.numpy()[0, len(prompt) - 1]))
+
+
+def _assert_decode_wrote_only_its_rows(old, new, cache, lens, active):
+    """``new`` pools against the eager ``paged_decode_write`` of the rows
+    the program wrote into the ``old`` ones: every other row of every
+    pool is what it was."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference.paged import (paged_decode_write,
+                                            paged_decode_write_q)
+
+    n = cache.num_layers
+    slots = np.arange(cache.max_batch)
+    blocks = cache.block_tables[slots, lens // cache.block_size]
+    offs = lens % cache.block_size
+    act = jnp.asarray(active)
+    for i in range(n):
+        if not cache.quantized:
+            want = paged_decode_write(
+                old[i], old[n + i], jnp.asarray(cache.block_tables),
+                jnp.asarray(lens), new[i][blocks, offs],
+                new[n + i][blocks, offs], act)
+            got = (new[i], new[n + i])
+        else:
+            from paddle_tpu.quantization import dequantize_rows
+
+            rows = [dequantize_rows(new[j][blocks, offs],
+                                    new[2 * n + j][blocks, offs],
+                                    jnp.float32)
+                    for j in (i, n + i)]
+            want = paged_decode_write_q(
+                old[i], old[n + i], old[2 * n + i], old[3 * n + i],
+                jnp.asarray(cache.block_tables), jnp.asarray(lens),
+                rows[0], rows[1], act)
+            got = (new[i], new[n + i], new[2 * n + i], new[3 * n + i])
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), i
+
+
+@_DONATION
+@_KV
+def test_prefill_writes_the_donated_pools_like_the_eager_reference(
+        model, kv_dtype):
+    cache, ref = _cache(model, kv_dtype), _cache(model, kv_dtype)
+    prompt = np.random.default_rng(5).integers(3, 250, (13,))
+    slot = cache.alloc_slot(len(prompt))
+    assert ref.alloc_slot(len(prompt)) == slot
+    handed_in = cache.pool_arrays()
+    tok = model.paged_prefill(cache, slot, prompt)
+    assert all(a.is_deleted() for a in handed_in)
+    assert not any(a.is_deleted() for a in cache.pool_arrays())
+    assert tok == _eager_prefill(model, ref, slot, prompt, 16)
+    assert_pools_equal(pools_numpy(cache), pools_numpy(ref), scale_ulp=1)
+    assert cache.seq_lens[slot] == ref.seq_lens[slot] == len(prompt)
+
+
+@_DONATION
+@_KV
+def test_decode_steps_write_the_donated_pools_and_nothing_else(
+        model, kv_dtype):
+    """Prefill two slots, then four decode steps: the donated program
+    against its undonated twin (same function, fresh buffers) bitwise in
+    tokens and pools, each step's input pools consumed, and each step's
+    output the eager ``paged_decode_write`` of its new rows into its
+    input."""
+    import jax
+    import jax.numpy as jnp
+
+    cache, ref = _cache(model, kv_dtype), _cache(model, kv_dtype)
+    rng = np.random.default_rng(6)
+    last = np.zeros((cache.max_batch,), np.int64)
+    for n in (13, 6):
+        prompt = rng.integers(3, 250, (n,))
+        slot = cache.alloc_slot(n + 4)
+        assert ref.alloc_slot(n + 4) == slot
+        last[slot] = model.paged_prefill(cache, slot, prompt)
+        assert model.paged_prefill(ref, slot, prompt) == last[slot]
+    active = np.array([True, True, False])
+    attr = "_paged_decode_q8_jit" if kv_dtype else "_paged_decode_jit"
+    ref_last = last.copy()
+    for _ in range(4):
+        handed_in = cache.pool_arrays()
+        toks = np.asarray(model.paged_decode_step(cache, last, active))
+        assert all(a.is_deleted() for a in handed_in)
+        # the twin leaves ``ref``'s pools alive: old and new side by side
+        twin = undonated_twin(model.__dict__[attr]["auto"])
+        arrs = model._param_arrays()
+        old = ref.pool_arrays()
+        scales = (ref.k_scales, ref.v_scales) if ref.quantized else ()
+        lens = ref.seq_lens.copy()
+        try:
+            ref_toks, *new = twin(
+                arrs, jnp.asarray(ref_last, jnp.int32), ref.k_pools,
+                ref.v_pools, *scales, ref.block_tables,
+                jnp.asarray(lens), jnp.asarray(active),
+                jax.random.key(0), jnp.float32(0.0))
+        finally:
+            model._param_rebind()(arrs)
+        assert not any(a.is_deleted() for a in old)
+        ref.rebind_pools(*new)
+        ref.seq_lens = np.where(active, lens + 1, lens).astype(np.int32)
+        assert np.array_equal(toks[active], np.asarray(ref_toks)[active])
+        assert_pools_equal(pools_numpy(cache), pools_numpy(ref))
+        _assert_decode_wrote_only_its_rows(old, ref.pool_arrays(), ref,
+                                           lens, active)
+        last[active] = ref_last[active] = toks[active]
+
+
+@_DONATION
+@pytest.mark.parametrize("program", ["prefill", "prefill-int8", "decode",
+                                     "decode-int8", "block-copy"])
+def test_lowered_programs_mark_every_pool_as_donated(model, program):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import paged
+
+    quantized = program.endswith("int8")
+    cache = _cache(model, "int8" if quantized else None)
+    arrs = model._param_arrays()
+    scales = [cache.k_scales, cache.v_scales] if quantized else [[], []]
+    key, temp = jax.random.key(0), jnp.float32(0.0)
+    try:
+        if program.startswith("prefill"):
+            lowered = model._build_prefill(quantized)._jitted.lower(
+                arrs, jnp.zeros((1, 16), jnp.int64), jnp.int32(9),
+                jnp.asarray(cache.block_tables[0]), cache.k_pools,
+                cache.v_pools, *scales, key, temp)
+            donated = (4, 5, 6, 7) if quantized else (4, 5)
+        elif program.startswith("decode"):
+            build = model._build_decode_q8 if quantized \
+                else model._build_decode
+            lowered = build("dense")._jitted.lower(
+                arrs, jnp.zeros((3,), jnp.int32), cache.k_pools,
+                cache.v_pools, *(scales if quantized else []),
+                cache.block_tables, jnp.asarray(cache.seq_lens),
+                jnp.ones((3,), bool), key, temp)
+            donated = (2, 3, 4, 5) if quantized else (2, 3)
+        else:
+            lowered = paged._kv_block_copy.lower(
+                cache.pool_arrays(), jnp.asarray([2], jnp.int32),
+                jnp.asarray([1], jnp.int32))
+            donated = (0,)
+    finally:
+        model._param_rebind()(arrs)
+    assert_lowered_donates(lowered, donated)
+
+
+@_DONATION
+@_KV
+def test_a_prefill_is_one_program_dispatch(model, kv_dtype):
+    cache = _cache(model, kv_dtype)
+    prompt = np.arange(3, 16)
+    model.paged_prefill(cache, cache.alloc_slot(13), prompt)  # compiles
+    slot = cache.alloc_slot(13)
+    _tok, names = dispatches(
+        lambda: model.paged_prefill(cache, slot, prompt))
+    name = "llama_paged_prefill_q8" if kv_dtype else "llama_paged_prefill"
+    assert names.count(name) == 1
+    # what else runs is the key split and scalar conversions, never a
+    # write (the 2 x num_layers eager scatters of old)
+    assert not [n for n in names if "scatter" in n]
+    assert len(names) <= 6, names
+
+
+@_DONATION
+@_KV
+def test_copy_on_write_goes_through_the_donated_block_copy(model, kv_dtype):
+    from paddle_tpu.profiler import metrics
+
+    cache = _cache(model, kv_dtype)
+    prompt = np.arange(3, 16)
+    model.paged_prefill(cache, cache.alloc_slot(13), prompt)
+    before = pools_numpy(cache)
+    handed_in = cache.pool_arrays()
+    c0 = metrics.snapshot("serving.kv.")
+    cache._copy_block_rows(int(cache.block_tables[0, 0]), 20)
+    c1 = metrics.snapshot("serving.kv.")
+    assert all(a.is_deleted() for a in handed_in)
+    src = int(cache.block_tables[0, 0])
+    for old, new in zip(before, pools_numpy(cache)):
+        want = old.copy()
+        want[20] = old[src]
+        assert np.array_equal(new, want)
+    assert c1["serving.kv.donated_calls"] \
+        == c0["serving.kv.donated_calls"] + 1
+    assert c1["serving.kv.copied_calls"] == c0["serving.kv.copied_calls"]

@@ -405,3 +405,104 @@ def test_flag_default_routes_scheduler(model):
         assert eng2.scheduler.prefix_cache is True
     finally:
         paddle.set_flags({flag: orig})
+
+
+# -- the tail extend writes the donated pools in place (ISSUE 27) ----------
+
+from conftest import (assert_lowered_donates, assert_pools_equal,  # noqa: E402
+                      pools_numpy, undonated_twin)
+
+
+def _shared_prefix_cache(model, kv_dtype, prompt):
+    """A cache whose slot 0 prefilled ``prompt`` and registered it."""
+    import jax.numpy as jnp
+
+    cfg = model.config
+    c = PagedKVCache(cfg.num_layers, cfg.num_kv_heads,
+                     cfg.hidden_size // cfg.num_heads, num_blocks=17,
+                     block_size=8, max_blocks_per_seq=8, max_batch=2,
+                     dtype=jnp.float32, kv_dtype=kv_dtype)
+    plan = c.plan_prefix(prompt)
+    s0 = c.alloc_slot_cached(plan)
+    model.paged_prefill(c, s0, prompt, temperature=0.0)
+    c.commit_prefix(s0, plan)
+    return c
+
+
+@pytest.mark.filterwarnings("error::UserWarning")
+@pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                         ids=["extend", "extend-int8"])
+def test_extend_writes_the_donated_pools_like_the_eager_reference(
+        model, kv_dtype):
+    """A prompt that shares 16 of its 21 tokens extends a registered
+    prefix: the program consumes the pools it is handed, agrees bitwise
+    with its undonated twin, and what it wrote is the eager
+    ``paged_prefill_write_masked`` of the tail's rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference.paged import (paged_prefill_write_masked,
+                                            paged_prefill_write_masked_q)
+    from paddle_tpu.quantization import dequantize_rows
+
+    rng = np.random.default_rng(27)
+    first = rng.integers(3, 250, (20,)).astype("int64")
+    second = np.concatenate([first[:16], rng.integers(3, 250, (5,))])
+    cache = _shared_prefix_cache(model, kv_dtype, first)
+    ref = _shared_prefix_cache(model, kv_dtype, first)
+    assert_pools_equal(pools_numpy(cache), pools_numpy(ref))
+    plan = cache.plan_prefix(second)
+    assert (plan.tail_start, plan.write_start) == (16, 16)
+    slot = cache.alloc_slot_cached(plan)
+    assert ref.alloc_slot_cached(ref.plan_prefix(second)) == slot
+
+    handed_in = cache.pool_arrays()
+    tok = model.paged_prefill_extend(cache, slot, second, 16, 16,
+                                     temperature=0.0)
+    assert all(a.is_deleted() for a in handed_in)
+
+    program = getattr(model, "_paged_extend_q8_jit" if kv_dtype
+                      else "_paged_extend_jit")
+    tail = np.zeros((1, 8), np.int64)
+    tail[0, :5] = second[16:]
+    row = jnp.asarray(ref.block_tables[slot])
+    scales = (ref.k_scales, ref.v_scales) if kv_dtype else ()
+    args = (jnp.asarray(tail), jnp.int32(16), jnp.int32(16),
+            jnp.int32(21), row, ref.k_pools, ref.v_pools, *scales,
+            jax.random.key(0), jnp.float32(0.0))
+    arrs = model._param_arrays()
+    old = ref.pool_arrays()
+    try:
+        assert_lowered_donates(
+            program._jitted.lower(arrs, *args),
+            (6, 7, 8, 9) if kv_dtype else (6, 7))
+        ref_tok, *new = undonated_twin(program)(arrs, *args)
+    finally:
+        model._param_rebind()(arrs)
+    assert not any(a.is_deleted() for a in old)
+    ref.rebind_pools(*new)
+    assert tok == int(ref_tok)
+    assert_pools_equal(pools_numpy(cache), pools_numpy(ref))
+
+    # only positions 16..20 of the slot changed, and to these rows
+    new = ref.pool_arrays()
+    n = ref.num_layers
+    pos = 16 + np.arange(8)
+    blocks = ref.block_tables[slot][pos // 8]
+    for i in range(n):
+        if kv_dtype:
+            k, v = (dequantize_rows(new[j][blocks, pos % 8],
+                                    new[2 * n + j][blocks, pos % 8],
+                                    jnp.float32) for j in (i, n + i))
+            want = paged_prefill_write_masked_q(
+                old[i], old[n + i], old[2 * n + i], old[3 * n + i], row,
+                k, v, jnp.int32(16), jnp.int32(16), jnp.int32(21))
+            got = (new[i], new[n + i], new[2 * n + i], new[3 * n + i])
+        else:
+            want = paged_prefill_write_masked(
+                old[i], old[n + i], row, new[i][blocks, pos % 8],
+                new[n + i][blocks, pos % 8], jnp.int32(16),
+                jnp.int32(16), jnp.int32(21))
+            got = (new[i], new[n + i])
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), i

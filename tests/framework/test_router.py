@@ -134,6 +134,70 @@ def test_aot_roundtrip_store_then_hit_bitwise(aot_dir):
     assert out1.tobytes() == out3.tobytes()
 
 
+@pytest.mark.filterwarnings("error::UserWarning")
+def test_aot_donated_program_roundtrips_and_still_consumes_its_input(
+        aot_dir):
+    """A program with donated arguments stores and loads like any other,
+    and the LOADED executable still takes its input's buffer: the array
+    handed in is deleted, the result is bit-identical to the compiled
+    one's, and what was not donated stays alive."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(pools, dst, rows):
+        return [p.at[dst].set(r) for p, r in zip(pools, rows)]
+
+    def args():
+        return ([jnp.zeros((6, 4, 8)), jnp.ones((6, 4, 8))],
+                jnp.asarray([4, 1], jnp.int32),
+                [jnp.full((2, 4, 8), 7.0), jnp.full((2, 4, 8), 9.0)])
+
+    h0, s0 = _aot("hits"), _aot("stores")
+    w1 = aot_cache.wrap(jax.jit(f, donate_argnums=(0,)), tag="test.donate")
+    pools, dst, rows = args()
+    out1 = [np.array(o) for o in w1(pools, dst, rows)]
+    assert _aot("stores") == s0 + 1
+    assert all(p.is_deleted() for p in pools)
+    w2 = aot_cache.wrap(jax.jit(f, donate_argnums=(0,)), tag="test.donate")
+    c0 = _compiles()
+    for _ in range(2):  # the load, then the warm table
+        pools, dst, rows = args()
+        out2 = w2(pools, dst, rows)
+        assert all(p.is_deleted() for p in pools)
+        assert not dst.is_deleted() and not rows[0].is_deleted()
+        for a, b in zip(out1, out2):
+            assert a.tobytes() == np.asarray(b).tobytes()
+    assert _compiles() == c0 and _aot("hits") == h0 + 1
+    assert out1[0][4, 0, 0] == 7.0 and out1[1][1, 0, 0] == 9.0
+
+
+@pytest.mark.filterwarnings("error::UserWarning")
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["plain", "int8"])
+def test_aot_loaded_serving_programs_write_the_pools_in_place(
+        aot_dir, kv_dtype):
+    """A second model's programs come from the store: each still
+    consumes the pools it is handed (every call counted as donated,
+    none as copied) and the tokens are the first model's."""
+    def serve():
+        eng = _engine(_fresh_model(), kv_cache_dtype=kv_dtype)
+        handed_in = eng.cache.pool_arrays()
+        k0 = metrics.snapshot("serving.kv.")
+        hs = [eng.submit(p, max_new_tokens=5) for p in _prompts(27, [6, 11])]
+        eng.run_until_idle()
+        k1 = metrics.snapshot("serving.kv.")
+        assert all(a.is_deleted() for a in handed_in)
+        assert k1["serving.kv.copied_calls"] == k0["serving.kv.copied_calls"]
+        assert k1["serving.kv.donated_calls"] \
+            >= k0["serving.kv.donated_calls"] + 2 + 4
+        eng.close()
+        return [h.tokens() for h in hs]
+
+    first = serve()
+    h0 = _aot("hits")
+    assert serve() == first
+    assert _aot("hits") >= h0 + 2  # a prefill bucket and the decode step
+
+
 @pytest.mark.parametrize("damage", ["truncate", "bitflip", "garbage"])
 def test_aot_corruption_quarantines_and_recompiles(aot_dir, damage):
     """Truncated / bit-flipped / garbage entries quarantine to
@@ -389,12 +453,16 @@ def test_router_failover_matrix_exactly_once(model):
     ref_eng.close()
 
     r, e1, e2 = _two_replicas(model, background=True)
-    hs = [r.submit(p, max_new_tokens=5) for p in prompts]
-    victims = [h for h in hs if h.replica_id == "r1"]
-    assert victims, "placement must have used r1"
-    # kill r1 the way a crashed device manifests: its driver dies
-    e1._sched.step = lambda: (_ for _ in ()).throw(
-        RuntimeError("injected replica death"))
+    # r1's driver waits for its engine's lock (re-entrant for this
+    # thread's submits) until the death is in place: however fast a
+    # step is, r1 finishes none of its requests first
+    with e1._lock:
+        hs = [r.submit(p, max_new_tokens=5) for p in prompts]
+        victims = [h for h in hs if h.replica_id == "r1"]
+        assert victims, "placement must have used r1"
+        # kill r1 the way a crashed device manifests: its driver dies
+        e1._sched.step = lambda: (_ for _ in ()).throw(
+            RuntimeError("injected replica death"))
     f0 = metrics.snapshot("router.")["router.failover"]
     outs = [h.result(timeout=120) for h in hs]
     assert all(h.status == "DONE" for h in hs)

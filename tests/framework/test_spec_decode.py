@@ -20,6 +20,7 @@ The contract under test, in order of importance:
 import math
 
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.serving.spec import propose_draft, repetitive_prompts
@@ -258,3 +259,100 @@ def test_spec_warmup_includes_verify_program(tiny_llama):
     assert metrics.snapshot()["xla.compile.count"] == c0
     assert h.status == "DONE"
     eng.close()
+
+
+# -- the verify sweep writes the donated pools in place (ISSUE 27) ---------
+
+from conftest import (assert_lowered_donates, assert_pools_equal,  # noqa: E402
+                      pools_numpy, undonated_twin)
+
+
+def _two_prefilled_slots(model, kv_dtype):
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference.paged import PagedKVCache
+
+    cfg = model.config
+    c = PagedKVCache(cfg.num_layers, cfg.num_kv_heads,
+                     cfg.hidden_size // cfg.num_heads, num_blocks=20,
+                     block_size=8, max_blocks_per_seq=4, max_batch=3,
+                     dtype=jnp.float32, kv_dtype=kv_dtype)
+    last = np.zeros((3,), np.int64)
+    for prompt in _prompts(27, [11, 6]):
+        slot = c.alloc_slot(len(prompt) + 4)
+        last[slot] = model.paged_prefill(c, slot, prompt)
+    return c, last
+
+
+@pytest.mark.filterwarnings("error::UserWarning")
+@pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                         ids=["speculative", "speculative-int8"])
+def test_verify_sweep_writes_the_donated_pools_like_the_eager_reference(
+        tiny_llama, kv_dtype):
+    """1 + 3 candidate positions a slot (slot 1 has two real drafts,
+    slot 2 is idle): the sweep consumes its pools, agrees bitwise with
+    its undonated twin, and its output is the eager ``paged_spec_write``
+    of the rows it wrote — padding and the idle slot touch nothing but
+    the null block."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference.paged import paged_spec_write
+    from paddle_tpu.quantization import dequantize_rows
+
+    model = tiny_llama
+    cache, last = _two_prefilled_slots(model, kv_dtype)
+    ref, _ = _two_prefilled_slots(model, kv_dtype)
+    drafts = np.array([[7, 8, 9], [4, 5, 0], [0, 0, 0]], np.int64)
+    n_inputs = np.array([4, 3, 4], np.int64)
+    active = np.array([True, True, False])
+
+    handed_in = cache.pool_arrays()
+    out = np.asarray(model.paged_spec_step(cache, last, drafts, n_inputs,
+                                           active))
+    assert all(a.is_deleted() for a in handed_in)
+
+    program = getattr(model, "_paged_spec_q8_jit" if kv_dtype
+                      else "_paged_spec_jit")
+    toks = np.concatenate([last.reshape(-1, 1), drafts], axis=1)
+    lens = jnp.asarray(ref.seq_lens)
+    args = (jnp.asarray(toks, jnp.int32), lens,
+            jnp.asarray(n_inputs, jnp.int32), jnp.asarray(active),
+            ref.block_tables, ref.k_pools, ref.v_pools,
+            ref.k_scales if kv_dtype else [],
+            ref.v_scales if kv_dtype else [])
+    arrs = model._param_arrays()
+    old = ref.pool_arrays()
+    try:
+        assert_lowered_donates(program._jitted.lower(arrs, *args),
+                               (6, 7, 8, 9))
+        ref_out, *new = undonated_twin(program)(arrs, *args)
+    finally:
+        model._param_rebind()(arrs)
+    assert not any(a.is_deleted() for a in old)
+    ref.rebind_pools(*new)
+    assert np.array_equal(out[active], np.asarray(ref_out)[active])
+    assert_pools_equal(pools_numpy(cache), pools_numpy(ref))
+
+    new = ref.pool_arrays()
+    n = ref.num_layers
+    pos = ref.seq_lens[:, None] + np.arange(4)[None, :]
+    blocks = ref.block_tables[np.arange(3)[:, None], pos // 8]
+    for i in range(n):
+        if kv_dtype:
+            k, v = (dequantize_rows(new[j][blocks, pos % 8],
+                                    new[2 * n + j][blocks, pos % 8],
+                                    jnp.float32) for j in (i, n + i))
+            want = paged_spec_write(
+                old[i], old[n + i], jnp.asarray(ref.block_tables), lens,
+                k, v, jnp.asarray(n_inputs, jnp.int32),
+                jnp.asarray(active), k_scale=old[2 * n + i],
+                v_scale=old[3 * n + i])
+            got = (new[i], new[n + i], new[2 * n + i], new[3 * n + i])
+        else:
+            want = paged_spec_write(
+                old[i], old[n + i], jnp.asarray(ref.block_tables), lens,
+                new[i][blocks, pos % 8], new[n + i][blocks, pos % 8],
+                jnp.asarray(n_inputs, jnp.int32), jnp.asarray(active))
+            got = (new[i], new[n + i])
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), i
