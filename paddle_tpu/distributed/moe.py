@@ -11,6 +11,13 @@ dispatch/combine einsums over an expert axis; expert weights are stacked
 vmapped expert compute and inserts the all-to-alls the reference issues
 manually via global_scatter/global_gather. Capacity pruning is the
 position-in-expert cumsum mask — same semantics as limit_by_capacity.
+
+Serving takes the other path (``DroplessMoE`` / ``dropless_moe``): no
+capacity and no ``[T, E, C]`` tensor. Every (token, expert) assignment
+becomes a row, the rows are sorted by expert and go through a grouped
+matmul over the experts held (``kernels/pallas/moe_gmm.py``), so no
+token is ever dropped and the cost follows the rows, not ``T x E``.
+Training keeps the capacity path above.
 """
 
 from __future__ import annotations
@@ -25,9 +32,15 @@ from ..core.dispatch import apply
 from ..core.tensor import Parameter, Tensor
 from .api import shard_tensor
 from .mesh import get_mesh
+from ..profiler import metrics as _metrics
 from .placement import Replicate, Shard
 
-__all__ = ["MoELayer", "TopKGate"]
+# route the grouped expert matmul took, counted where the layer is
+# traced (one movement a compiled layer, as ``serving.kernel.pallas``)
+_GMM_PALLAS = _metrics.counter("serving.kernel.moe_gmm.pallas")
+_GMM_PLAIN = _metrics.counter("serving.kernel.moe_gmm.plain")
+
+__all__ = ["MoELayer", "TopKGate", "DroplessMoE", "dropless_moe"]
 
 
 def _one_hot(idx, n):
@@ -171,4 +184,162 @@ class MoELayer(nn.Layer):
         out, aux = apply(pure, x, self.gate.weight, *list(self._stacked),
                          name="moe")
         self.aux_loss = aux
+        return out
+
+
+# ---------------------------------------------------------------------------
+# dropless routing for serving: sort by expert + grouped matmul
+# ---------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def gmm_route(kernel_mode=None):
+    """Where the grouped matmul of a resolved ``FLAGS_paged_kernel`` mode
+    runs on this backend: ``pallas`` (TPU), ``interpret`` (the same
+    kernel interpreted, ``pallas`` forced on the CPU) or ``plain`` (the
+    sorted ``ragged_dot``: the CPU's default and ``dense``)."""
+    from ..inference.paged import kernel_route
+    route = kernel_route(kernel_mode)
+    return "plain" if route == "dense" else route
+
+
+def route_topk(x, router_w, top_k, norm_topk_prob=True):
+    """The router: softmax over ALL experts in float32 (the product at
+    ``highest`` precision: the TPU's default would round the float32
+    operands to bfloat16), the ``top_k`` largest, renormalised over the
+    chosen ones when ``norm_topk_prob``. x [T, d] -> (weights [T, k]
+    float32, expert ids [T, k] int32)."""
+    logits = jnp.matmul(x.astype(jnp.float32),
+                        router_w.astype(jnp.float32), precision=_HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, idx.astype(jnp.int32)
+
+
+def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k,
+                 norm_topk_prob=True, expert_lo=0, route="plain",
+                 valid=None, kernel_tag=""):
+    """The part of a SwiGLU expert layer's output that the experts
+    ``[expert_lo, expert_lo + E_held)`` give, with nothing dropped.
+
+    x [T, d]; ``router_w`` [d, E] routes over all E experts; ``w_gate``,
+    ``w_up`` [E_held, d, f] and ``w_down`` [E_held, f, d] are the held
+    experts, stacked. An assignment to an expert held elsewhere adds
+    nothing here (its holder adds it: the parts of all holders sum to
+    the whole layer). ``valid`` [T] bool marks the rows that are real
+    (a batch's idle slots are not): the others are routed nowhere,
+    counted nowhere and come out zero. Returns (y [T, d] in x's dtype,
+    rows routed to each of the E experts [E] int32, the router's
+    (weights [T, k] float32, expert ids [T, k])). ``route``:
+    ``gmm_route``; ``kernel_tag`` ends the Pallas calls' names
+    (``moe_gmm_swiglu<tag>``, ``moe_gmm<tag>``), so that a trace tells
+    one program's calls from another's."""
+    from ..kernels.pallas import moe_gmm as K
+    t, d = x.shape
+    n_experts = router_w.shape[1]
+    held = w_gate.shape[0]
+    weights, idx = route_topk(x, router_w, top_k, norm_topk_prob)
+    flat = idx.reshape(-1)                              # [A], A = T * k
+    a = flat.shape[0]
+    real = jnp.ones((a,), bool) if valid is None \
+        else jnp.repeat(valid, top_k)
+    counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(
+        real.astype(jnp.int32))
+    local = flat - jnp.int32(expert_lo)
+    mine = (local >= 0) & (local < held) & real
+    # assignments held elsewhere sort past the last group
+    key = jnp.where(mine, local, held)
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    sizes = jax.lax.dynamic_slice(counts, (jnp.int32(expert_lo),),
+                                  (held,))
+    tm = K.tile_rows(a, held)
+    m, offsets, padded, tile_expert, num_tiles = K.tile_layout(
+        sizes, tm, a)
+    starts = jnp.cumsum(sizes) - sizes
+    safe = jnp.minimum(sorted_key, held - 1)
+    rank = jnp.arange(a, dtype=jnp.int32) - starts[safe]
+    # out of range for the rows held elsewhere: the scatter drops them
+    dest_sorted = jnp.where(sorted_key < held, offsets[safe] + rank, m)
+    rows = jnp.zeros((m, d), x.dtype).at[dest_sorted].set(
+        x[order // top_k], mode="drop")
+    if route == "plain":
+        h = K.moe_gmm_swiglu_plain(rows, w_gate, w_up, padded)
+        y = K.moe_gmm_plain(h, w_down, padded)
+    else:
+        interpret = route == "interpret"
+        h = K.moe_gmm_swiglu(rows, w_gate, w_up, tile_expert, num_tiles,
+                             tm=tm, interpret=interpret, tag=kernel_tag)
+        y = K.moe_gmm(h, w_down, tile_expert, num_tiles, tm=tm,
+                      interpret=interpret, tag=kernel_tag)
+    dest = jnp.zeros((a,), jnp.int32).at[order].set(
+        jnp.minimum(dest_sorted, m - 1))
+    picked = jnp.where(mine[:, None], y[dest].astype(jnp.float32), 0.0)
+    out = jnp.sum(picked.reshape(t, top_k, d) * weights[..., None], axis=1)
+    return out.astype(x.dtype), counts, (weights, idx)
+
+
+class DroplessMoE(nn.Layer):
+    """SwiGLU expert layer with dropless top-k routing (no capacity, no
+    shared expert, no auxiliary loss): what a served sparse decoder
+    runs. The experts' matrices are created stacked, ``[E_held, ...]``,
+    one parameter a projection. ``expert_range=(lo, hi)`` is the range
+    of experts this holder computes (all by default): the router still
+    scores all ``num_experts``, which is what expert parallelism needs,
+    and a forward gives this range's part of the layer's output.
+
+    ``forward`` returns the Tensor; a ``counts_sink`` list is appended
+    the rows routed to each expert ([E] int32), as ``kv_sink`` is the
+    keys and values, and a ``route_sink`` list the router's (weights
+    [T, k] float32, expert ids [T, k]) as this forward computed them."""
+
+    def __init__(self, d_model, d_expert, num_experts, top_k,
+                 norm_topk_prob=True, expert_range=None, weight_attr=None):
+        super().__init__()
+        lo, hi = expert_range or (0, num_experts)
+        if not 0 <= lo < hi <= num_experts:
+            raise ValueError(f"DroplessMoE: expert_range {(lo, hi)} is "
+                             f"not within the {num_experts} experts")
+        self.num_experts = num_experts
+        self.top_k = top_k
+        self.norm_topk_prob = norm_topk_prob
+        self.expert_lo = lo
+        held = hi - lo
+        self.router = self.create_parameter(
+            shape=[d_model, num_experts], attr=weight_attr)
+        self.gate_proj = self.create_parameter(
+            shape=[held, d_model, d_expert], attr=weight_attr)
+        self.up_proj = self.create_parameter(
+            shape=[held, d_model, d_expert], attr=weight_attr)
+        self.down_proj = self.create_parameter(
+            shape=[held, d_expert, d_model], attr=weight_attr)
+
+    def forward(self, x, kernel_mode=None, counts_sink=None, valid=None,
+                kernel_tag="", route_sink=None):
+        route = gmm_route(kernel_mode)
+        (_GMM_PLAIN if route == "plain" else _GMM_PALLAS).inc()
+
+        # plain values in the closure: they are part of the dispatch
+        # cache's key
+        top_k, norm, lo = self.top_k, self.norm_topk_prob, self.expert_lo
+
+        def pure(xa, router, wg, wu, wd, *rows):
+            y, counts, (weights, idx) = dropless_moe(
+                xa.reshape(-1, xa.shape[-1]), router, wg, wu, wd,
+                top_k=top_k, norm_topk_prob=norm, expert_lo=lo,
+                route=route, valid=rows[0] if rows else None,
+                kernel_tag=kernel_tag)
+            return y.reshape(xa.shape), counts, weights, idx
+
+        out, counts, weights, idx = apply(
+            pure, x, self.router, self.gate_proj, self.up_proj,
+            self.down_proj, *([] if valid is None else [valid]),
+            name="dropless_moe")
+        if counts_sink is not None:
+            counts_sink.append(counts)
+        if route_sink is not None:
+            route_sink.append((weights, idx))
         return out

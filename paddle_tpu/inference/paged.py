@@ -75,6 +75,7 @@ _KV_COPIED = _metrics.counter("serving.kv.copied_calls")
 __all__ = ["PagedKVCache", "paged_prefill_write",
            "paged_prefill_write_masked", "paged_decode_attention",
            "paged_decode_attention_dense", "paged_decode_attention_tp",
+           "paged_block_attention",
            "paged_prefix_attention_dense",
            "paged_spec_write", "paged_spec_attention_dense",
            "ContinuousBatchingEngine", "validate_request",
@@ -985,14 +986,16 @@ def _gather_kv(pool, index, scale, dtype):
 
 def paged_prefix_attention_dense(q, k_pool, v_pool, block_row, q_start,
                                  total_len, scale=None, k_scale=None,
-                                 v_scale=None):
+                                 v_scale=None, block_len=1):
     """Chunked-prefill attention for the prefix-cache tail: queries
     [S, Hq, D] sit at absolute positions ``q_start .. q_start+S-1`` and
     attend the slot's whole paged context (cached prefix blocks + the
     tail KV just written), causal by absolute position and masked to
     ``total_len``. Same gather + group-folded GQA formulation as
     `paged_decode_attention_dense`, generalized to S queries; padded
-    query rows produce junk that the caller never reads."""
+    query rows produce junk that the caller never reads. ``block_len``
+    L > 1 makes the mask block-causal: key j is visible to query i iff
+    ``j // L <= i // L`` (blocks aligned to position 0)."""
     s, hq, d = q.shape
     _, bs, hk, _ = k_pool.shape
     g = hq // hk
@@ -1010,8 +1013,10 @@ def paged_prefix_attention_dense(q, k_pool, v_pool, block_row, q_start,
                         preferred_element_type=jnp.float32) * sm_scale
     pos_q = q_start + jnp.arange(s, dtype=jnp.int32)
     pos_k = jnp.arange(s_max, dtype=jnp.int32)
+    if block_len > 1:
+        pos_q, pos_k = pos_q // block_len, pos_k // block_len
     mask = (pos_k[None, :] <= pos_q[:, None]) & \
-        (pos_k[None, :] < total_len)
+        (jnp.arange(s_max, dtype=jnp.int32)[None, :] < total_len)
     logits = jnp.where(mask[:, None, None, :], logits, jnp.float32(-1e30))
     probs = jax.nn.softmax(logits, axis=-1)
     probs = jnp.where(mask[:, None, None, :], probs, 0.0)
@@ -1066,7 +1071,8 @@ def paged_decode_write_q(k_pool, v_pool, k_scale, v_scale, block_tables,
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
                            scale=None, use_kernel=None, k_scale=None,
-                           v_scale=None, kernel_mode=None):
+                           v_scale=None, kernel_mode=None,
+                           kernel_name="paged_decode"):
     """Masked decode attention over the paged cache — THE kernel
     routing point (docs/PERF.md "Pallas serving-kernel tier").
 
@@ -1081,7 +1087,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
     tier-1 testable); ``dense`` forces the reference byte-for-byte
     with serving.kernel.* counter silence. The pallas/dense/interpret
     route counters move at the routing decision
-    (tools/kernel_gate.py pins movement and silence).
+    (tools/kernel_gate.py pins movement and silence). ``kernel_name``
+    is the Pallas call's name in a device trace.
     """
     if kernel_mode is None and use_kernel is not None:
         kernel_mode = "pallas" if use_kernel else "dense"
@@ -1107,10 +1114,30 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
     if block_tables.shape[1] >= _CHUNK_MIN_PAGES:
         return paged_decode_attention_chunked(
             q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, name=kernel_name)
     return paged_decode_attention_kernel(
         q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
-        k_scale=k_scale, v_scale=v_scale)
+        k_scale=k_scale, v_scale=v_scale, name=kernel_name)
+
+
+def paged_block_attention(q, k_pool, v_pool, block_tables, seq_lens,
+                          scale=None, kernel_mode=None):
+    """Attention of a block of L query rows a slot over the paged cache,
+    with no mask inside the block (block-diffusion decoding): q
+    [B, L, Hq, D]; every row of slot ``b`` sees its first ``seq_lens[b]``
+    keys, the block's own L among them (the caller wrote them and passes
+    ``len + L``). The L rows join the GQA group (``fold_block_rows``),
+    so the route, the kernels and the dense reference are
+    :func:`paged_decode_attention`'s; the Pallas call is named
+    ``paged_block*``. L = 1 is that function, program for program."""
+    from ..kernels.pallas.paged_attention import (fold_block_rows,
+                                                   unfold_block_rows)
+    l, hk = q.shape[1], k_pool.shape[2]
+    out = paged_decode_attention(
+        fold_block_rows(q, hk), k_pool, v_pool, block_tables, seq_lens,
+        scale=scale, kernel_mode=kernel_mode,
+        kernel_name="paged_decode" if l == 1 else "paged_block")
+    return unfold_block_rows(out, l, hk)
 
 
 def paged_decode_attention_dense(q, k_pool, v_pool, block_tables, seq_lens,
@@ -1377,7 +1404,7 @@ class ContinuousBatchingEngine:
             from ..core import flags as _flags
             kv_cache_dtype = _flags.flag("FLAGS_kv_cache_dtype")
         kv_dtype = resolve_kv_dtype(kv_cache_dtype)
-        hd = cfg.hidden_size // cfg.num_heads
+        hd = cfg.head_dim
         num_blocks = sized_num_blocks(
             num_blocks, max_batch, mbps, kv_dtype, hd, dtype)
         self.cache = PagedKVCache(

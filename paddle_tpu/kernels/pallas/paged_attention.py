@@ -59,7 +59,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _interpret
 
 __all__ = ["paged_decode_attention_kernel",
-           "paged_decode_attention_chunked", "pick_chunk_pages"]
+           "paged_decode_attention_chunked", "pick_chunk_pages",
+           "fold_block_rows", "unfold_block_rows"]
 
 # f32/i32-typed literals: under jax_enable_x64 bare python numbers trace as
 # weak 64-bit constants that Mosaic cannot legalize (see flash_attention.py)
@@ -219,10 +220,11 @@ def _decode_kernel_chunked(tables_ref, lens_ref, q_ref, *refs, hk, g,
         _finalize_out(o_ref, acc, l_scr)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
 def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
                                   seq_lens, scale=None, interpret=None,
-                                  k_scale=None, v_scale=None):
+                                  k_scale=None, v_scale=None,
+                                  name="paged_decode"):
     """Decode attention over a paged KV cache, fused in one Pallas kernel.
 
     q [B, Hq, D] (one query token per slot); k_pool/v_pool
@@ -231,7 +233,8 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
     [NB, bs, Hk] f32 — the page gather then carries the scale rows and
     dequantizes in VMEM (dequant fusion). Returns [B, Hq, D]. Matches
     `paged_decode_attention_dense` (the dense reference path, same int8
-    pool) bitwise-closely; tested one-vs-other.
+    pool) bitwise-closely; tested one-vs-other. ``name`` is the kernel's
+    name in a device trace (``fold_block_rows``' callers pass their own).
     """
     b, hq, d = q.shape
     _, bs, hk, _ = k_pool.shape
@@ -277,9 +280,31 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name="paged_decode_q8" if quantized else "paged_decode",
+        name=name + "_q8" if quantized else name,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q,
       k_pool, v_pool, *scales)
+
+
+def fold_block_rows(q, hk):
+    """A block of L query rows a slot as the kernels' GQA group: q
+    [B, L, Hq, D] -> [B, Hk * (L * g), D], a KV head's L x g rows
+    contiguous, so ``_page_update`` folds them as it folds the g rows of
+    a single query. Every row then sees the same ``seq_lens[b]`` keys
+    (the caller counts the block's own L in): attention with no mask
+    inside the block. L = 1 is the identity."""
+    b, l, hq, d = q.shape
+    g = hq // hk
+    return q.reshape(b, l, hk, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, hk * l * g, d)
+
+
+def unfold_block_rows(out, l, hk):
+    """Inverse of :func:`fold_block_rows` on the kernels' output."""
+    b, rows, d = out.shape
+    hq = rows // l
+    g = hq // hk
+    return out.reshape(b, hk, l, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, l, hq, d)
 
 
 # chunk candidates and the per-core VMEM budget the K+V tile may take
@@ -307,11 +332,11 @@ def pick_chunk_pages(npages, bs, hk, d, itemsize=2,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "chunk_pages"))
+                                             "chunk_pages", "name"))
 def paged_decode_attention_chunked(q, k_pool, v_pool, block_tables,
                                    seq_lens, scale=None, interpret=None,
                                    k_scale=None, v_scale=None,
-                                   chunk_pages=None):
+                                   chunk_pages=None, name="paged_decode"):
     """Chunked flash-decode: :func:`paged_decode_attention_kernel`
     tiling the KV sequence axis ``chunk_pages`` pages per grid step
     (long contexts stop paying one grid step + scratch round-trip per
@@ -381,6 +406,5 @@ def paged_decode_attention_chunked(q, k_pool, v_pool, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name=("paged_decode_chunked_q8" if quantized
-              else "paged_decode_chunked"),
+        name=name + ("_chunked_q8" if quantized else "_chunked"),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *ins)

@@ -68,10 +68,15 @@ class LlamaConfig:
     use_fp8: bool = False  # fp8 block linears (amp.fp8 delayed scaling)
     # loss() uses the blockwise fused LM-head CE (see models/gpt.py)
     fused_head_ce: bool = True
+    # a head's size; None = hidden_size // num_heads. A configuration
+    # may publish another (q_proj is then hidden -> num_heads * head_dim)
+    head_dim: int = None
 
     def __post_init__(self):
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_heads
 
     @staticmethod
     def llama2_7b():
@@ -155,22 +160,24 @@ class LlamaAttention(nn.Layer):
         d = config.hidden_size
         self.num_heads = config.num_heads
         self.num_kv_heads = config.num_kv_heads
-        self.head_dim = d // config.num_heads
+        self.head_dim = config.head_dim
         self.rope_theta = config.rope_theta
         std = config.initializer_range
+        q_out = self.num_heads * self.head_dim
         kv_out = self.num_kv_heads * self.head_dim
-        self.q_proj = nn.Linear(d, d, weight_attr=_normal_attr(std),
+        self.q_proj = nn.Linear(d, q_out, weight_attr=_normal_attr(std),
                                 bias_attr=False)
         self.k_proj = nn.Linear(d, kv_out, weight_attr=_normal_attr(std),
                                 bias_attr=False)
         self.v_proj = nn.Linear(d, kv_out, weight_attr=_normal_attr(std),
                                 bias_attr=False)
-        self.o_proj = nn.Linear(d, d, weight_attr=_normal_attr(std),
+        self.o_proj = nn.Linear(q_out, d, weight_attr=_normal_attr(std),
                                 bias_attr=False)
 
     def forward(self, x, cache=None, position_offset=0, kv_sink=None):
         from .. import ops
-        b, s, d = x.shape
+        b, s, _ = x.shape
+        d = self.num_heads * self.head_dim
         q = ops.reshape(self.q_proj(x), [b, s, self.num_heads, self.head_dim])
         k = ops.reshape(self.k_proj(x),
                         [b, s, self.num_kv_heads, self.head_dim])
@@ -257,7 +264,69 @@ class LlamaBlock(nn.Layer):
         return x, new_cache
 
 
-class Llama(nn.Layer):
+class PagedServingModel(nn.Layer):
+    """What every model served through ``ServingEngine`` shares: its
+    parameters handed to the jitted ``paged_*`` programs as arguments and
+    rebound after the trace, the lock that serializes those calls, and
+    the AOT-cache tag that folds the serving mesh in."""
+
+    def _param_rebind(self):
+        if not hasattr(self, "_pb_names"):
+            self._pb_names = [n for n, _ in self.named_parameters()]
+        if hasattr(self, "_pb_rebind"):
+            return self._pb_rebind
+
+        def rebind(param_arrays):
+            for n, arr in zip(self._pb_names, param_arrays):
+                obj = self
+                *path, leaf = n.split(".")
+                for seg in path:
+                    obj = obj[int(seg)] if seg.isdigit() else \
+                        getattr(obj, seg)
+                getattr(obj, leaf)._data = arr
+        self._pb_rebind = rebind
+        return rebind
+
+    def _param_arrays(self):
+        return tuple(p._data for _, p in self.named_parameters())
+
+    def serving_mesh(self):
+        """The ServingMesh this model's serving params are laid out
+        on, or None (single-device serving)."""
+        return self.__dict__.get("_serving_mesh")
+
+    def _aot_tag(self, base):
+        """AOT-cache tag for a serving program: the mesh spec folds in
+        so fingerprints differ across mesh shapes even where the
+        lowered text happens to agree (tests/framework/
+        test_mesh_serving.py pins the distinction)."""
+        mesh = self.__dict__.get("_serving_mesh")
+        return base if mesh is None else f"{base}.mesh{mesh.spec}"
+
+    def _paged_lock(self):
+        """Per-model lock serializing the paged jit entry points. Their
+        trace path REBINDS the module's parameters to tracers and
+        restores them after the call — with several serving engines
+        sharing one model (in-process fleet replicas), an unsynchronized
+        cold-start races another thread's restore and leaks tracers into
+        the shared params. One uncontended acquire per warm call is
+        noise next to the dispatch itself. Created lazily in __dict__
+        (not through Layer attr tracking; models stay picklable until
+        first serve)."""
+        lock = self.__dict__.get("_paged_call_lock")
+        if lock is None:
+            with _PAGED_LOCK_INIT:
+                lock = self.__dict__.get("_paged_call_lock")
+                if lock is None:
+                    lock = threading.Lock()
+                    self.__dict__["_paged_call_lock"] = lock
+        return lock
+
+    def num_params(self):
+        return sum(p.size for p in self.parameters())
+
+
+class Llama(PagedServingModel):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
@@ -308,7 +377,7 @@ class Llama(nn.Layer):
         from .. import ops
         dt = dtype or (self.embed_tokens.weight.dtype)
         kvh = self.config.num_kv_heads
-        hd = self.config.hidden_size // self.config.num_heads
+        hd = self.config.head_dim
         return [(ops.zeros([batch_size, max_seq_len, kvh, hd], dt),
                  ops.zeros([batch_size, max_seq_len, kvh, hd], dt))
                 for _ in range(self.config.num_layers)]
@@ -322,26 +391,6 @@ class Llama(nn.Layer):
     # Reference: block_multi_head_attention_kernel.cu (paged cache) +
     # masked_multihead_attention_kernel.cu (decode). See inference/paged.py.
 
-    def _param_rebind(self):
-        if not hasattr(self, "_pb_names"):
-            self._pb_names = [n for n, _ in self.named_parameters()]
-        if hasattr(self, "_pb_rebind"):
-            return self._pb_rebind
-
-        def rebind(param_arrays):
-            for n, arr in zip(self._pb_names, param_arrays):
-                obj = self
-                *path, leaf = n.split(".")
-                for seg in path:
-                    obj = obj[int(seg)] if seg.isdigit() else \
-                        getattr(obj, seg)
-                getattr(obj, leaf)._data = arr
-        self._pb_rebind = rebind
-        return rebind
-
-    def _param_arrays(self):
-        return tuple(p._data for _, p in self.named_parameters())
-
     # every jitted serving entry point this model caches; cleared when
     # the serving mesh changes so programs re-lower against the new
     # shardings (and re-fingerprint in the AOT cache under the new tag)
@@ -350,11 +399,6 @@ class Llama(nn.Layer):
                        "_paged_extend_q8_jit", "_paged_decode_jit",
                        "_paged_decode_q8_jit", "_paged_spec_jit",
                        "_paged_spec_q8_jit")
-
-    def serving_mesh(self):
-        """The ServingMesh this model's serving params are laid out
-        on, or None (single-device serving)."""
-        return self.__dict__.get("_serving_mesh")
 
     def apply_serving_mesh(self, mesh):
         """Lay the model out for mesh-sharded serving
@@ -383,33 +427,6 @@ class Llama(nn.Layer):
             self.__dict__["_serving_mesh"] = mesh
             for attr in self._PAGED_JIT_ATTRS:
                 self.__dict__.pop(attr, None)
-
-    def _aot_tag(self, base):
-        """AOT-cache tag for a serving program: the mesh spec folds in
-        so fingerprints differ across mesh shapes even where the
-        lowered text happens to agree (tests/framework/
-        test_mesh_serving.py pins the distinction)."""
-        mesh = self.__dict__.get("_serving_mesh")
-        return base if mesh is None else f"{base}.mesh{mesh.spec}"
-
-    def _paged_lock(self):
-        """Per-model lock serializing the paged jit entry points. Their
-        trace path REBINDS the module's parameters to tracers and
-        restores them after the call — with several serving engines
-        sharing one model (in-process fleet replicas), an unsynchronized
-        cold-start races another thread's restore and leaks tracers into
-        the shared params. One uncontended acquire per warm call is
-        noise next to the dispatch itself. Created lazily in __dict__
-        (not through Layer attr tracking; models stay picklable until
-        first serve)."""
-        lock = self.__dict__.get("_paged_call_lock")
-        if lock is None:
-            with _PAGED_LOCK_INIT:
-                lock = self.__dict__.get("_paged_call_lock")
-                if lock is None:
-                    lock = threading.Lock()
-                    self.__dict__["_paged_call_lock"] = lock
-        return lock
 
     def paged_prefill(self, cache, slot, prompt_ids, temperature=0.0,
                       pad_to=None):
@@ -580,7 +597,7 @@ class Llama(nn.Layer):
         cfg = self.config
         hq = cfg.num_heads
         hk = cfg.num_kv_heads
-        hd = cfg.hidden_size // hq
+        hd = cfg.head_dim
 
         def fn(param_arrays, tail_ids, t_start, w_start, t_total,
                row, k_pools, v_pools, key, temp):
@@ -642,7 +659,7 @@ class Llama(nn.Layer):
         cfg = self.config
         hq = cfg.num_heads
         hk = cfg.num_kv_heads
-        hd = cfg.hidden_size // hq
+        hd = cfg.head_dim
 
         def fn(param_arrays, tail_ids, t_start, w_start, t_total, row,
                k_pools, v_pools, k_scales, v_scales, key, temp):
@@ -745,7 +762,7 @@ class Llama(nn.Layer):
         cfg = self.config
         hq = cfg.num_heads
         hk = cfg.num_kv_heads
-        hd = cfg.hidden_size // hq
+        hd = cfg.head_dim
         # mesh-sharded serving: captured at build time — the jit is
         # rebuilt (apply_serving_mesh clears it) when the mesh
         # changes. A model-sharded mesh runs the attention
@@ -822,7 +839,7 @@ class Llama(nn.Layer):
         cfg = self.config
         hq = cfg.num_heads
         hk = cfg.num_kv_heads
-        hd = cfg.hidden_size // hq
+        hd = cfg.head_dim
         mesh = self.__dict__.get("_serving_mesh")
         use_tp = mesh is not None and mesh.shard_map_armed
 
@@ -907,7 +924,7 @@ class Llama(nn.Layer):
         cfg = self.config
         hq = cfg.num_heads
         hk = cfg.num_kv_heads
-        hd = cfg.hidden_size // hq
+        hd = cfg.head_dim
 
         def fn(param_arrays, toks, lens, n_inputs, active, tables,
                k_pools, v_pools, k_scales, v_scales):
@@ -1016,9 +1033,6 @@ class Llama(nn.Layer):
                                                 transpose_weight=tied)
         logits = self(input_ids)
         return F.cross_entropy(logits[:, :-1, :], labels[:, 1:])
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
 
     def flops_per_token(self, seq_len):
         n = self.num_params()

@@ -1,0 +1,499 @@
+"""SDAR (``sdar_moe``): a sparse decoder that generates by diffusion over
+blocks of tokens.
+
+One layer, everything in the parameters' dtype except where said::
+
+    n = RMSNorm(x);  q = W_q n, k = W_k n, v = W_v n      (no biases)
+    q, k <- RMSNorm over each head's features, THEN rotary positions
+    h = x + W_o attention(q, k, v)     key j visible to query i iff
+                                       j // L <= i // L  (block-causal)
+    m = RMSNorm(h)
+    y = h + sum over the top-k experts e of  w_e  W_down,e (silu(W_gate,e m) * W_up,e m)
+        w = softmax(W_r m) in float32 over all experts, the k largest,
+        renormalised over those k (``norm_topk_prob``)
+
+then a final RMSNorm and an untied head. A head's size is the
+configuration's ``head_dim`` (``q_proj`` is hidden -> heads x head_dim),
+not hidden / heads. The expert layer is ``distributed.moe.DroplessMoE``:
+no capacity, no dropped token, no shared expert, no auxiliary loss.
+
+Generation is by blocks of ``L = block_length`` positions
+(``docs/SERVING.md`` "Block-diffusion decoding"): a block opens all
+masked, each denoising forward runs the block's L ids against the cache
+and each other and the most confident masked positions are unmasked;
+once none is masked one more forward (the commit) writes the block's
+keys and values for good and its L tokens are emitted together. The
+model declares ``tokens_per_block``; ``serving.Scheduler`` branches on
+that and on nothing else.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax.numpy as jnp
+import numpy as np
+
+from .. import nn
+from ..core.tensor import Tensor
+from ..distributed.moe import DroplessMoE
+from ..nn import functional as F
+from ..profiler.tracing import phase as _phase
+from .llama import (PagedServingModel, _aot_wrap, _named_jit, _normal_attr,
+                    apply_rope)
+
+__all__ = ["SDAR", "SDARConfig", "block_causal_mask"]
+
+
+@dataclasses.dataclass
+class SDARConfig:
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_layers: int = 48
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    norm_topk_prob: bool = True
+    max_position_embeddings: int = 32768
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    initializer_range: float = 0.02
+    # generation (the released generate.py's names)
+    block_length: int = 4
+    mask_token_id: int = 151669
+    # denoising forwards a fully masked block takes; None = block_length
+    # (one position a forward). ``low_confidence_static`` is the one
+    # schedule: M masked positions over S steps unmask M // S a step, one
+    # more in the first M mod S steps
+    denoise_steps: int = None
+    # experts held here, (lo, hi); None = all (expert parallelism gives
+    # each holder a range)
+    expert_range: tuple = None
+
+    def __post_init__(self):
+        if self.denoise_steps is None:
+            self.denoise_steps = self.block_length
+
+    @staticmethod
+    def sdar_30b_a3b():
+        return SDARConfig()
+
+    @staticmethod
+    def tiny():
+        """2 layers, 8 experts, 2 a token, heads of 32 on a hidden of
+        64 (so head_dim is not hidden / heads), blocks of 4."""
+        return SDARConfig(vocab_size=256, hidden_size=64, num_layers=2,
+                          num_heads=4, num_kv_heads=2, head_dim=32,
+                          num_experts=8, num_experts_per_tok=2,
+                          moe_intermediate_size=48,
+                          max_position_embeddings=256, mask_token_id=255,
+                          denoise_steps=2)
+
+
+def block_causal_mask(q_pos, k_pos, block_length):
+    """[len(q_pos), len(k_pos)] bool: key j is visible to query i iff
+    ``j // L <= i // L`` (blocks aligned to position 0)."""
+    return (k_pos[None, :] // block_length) <= (q_pos[:, None]
+                                                // block_length)
+
+
+class SDARAttention(nn.Layer):
+    def __init__(self, config: SDARConfig):
+        super().__init__()
+        d, hd = config.hidden_size, config.head_dim
+        self.num_heads = config.num_heads
+        self.num_kv_heads = config.num_kv_heads
+        self.head_dim = hd
+        self.rope_theta = config.rope_theta
+        attr = _normal_attr(config.initializer_range)
+        self.q_proj = nn.Linear(d, self.num_heads * hd, weight_attr=attr,
+                                bias_attr=False)
+        self.k_proj = nn.Linear(d, self.num_kv_heads * hd,
+                                weight_attr=attr, bias_attr=False)
+        self.v_proj = nn.Linear(d, self.num_kv_heads * hd,
+                                weight_attr=attr, bias_attr=False)
+        self.o_proj = nn.Linear(self.num_heads * hd, d, weight_attr=attr,
+                                bias_attr=False)
+        self.q_norm = nn.RMSNorm(hd, epsilon=config.rms_norm_eps)
+        self.k_norm = nn.RMSNorm(hd, epsilon=config.rms_norm_eps)
+
+    def qkv(self, h, position_offset=0):
+        """q [b, s, heads, hd], k, v [b, s, kv heads, hd] of the normed
+        hidden ``h``: per-head RMSNorm on q and k, then rotary positions
+        from ``position_offset`` (a scalar, or one offset a row of the
+        batch)."""
+        b, s, _ = h.shape
+        q = self.q_proj(h).reshape([b, s, self.num_heads, self.head_dim])
+        k = self.k_proj(h).reshape([b, s, self.num_kv_heads,
+                                    self.head_dim])
+        v = self.v_proj(h).reshape([b, s, self.num_kv_heads,
+                                    self.head_dim])
+        q, k = apply_rope(self.q_norm(q), self.k_norm(k),
+                          theta=self.rope_theta,
+                          position_offset=position_offset)
+        return q, k, v
+
+    def out(self, attn):
+        b, s = attn.shape[:2]
+        return self.o_proj(attn.reshape([b, s, -1]))
+
+
+class SDARBlock(nn.Layer):
+    def __init__(self, config: SDARConfig):
+        super().__init__()
+        self.input_layernorm = nn.RMSNorm(config.hidden_size,
+                                          epsilon=config.rms_norm_eps)
+        self.self_attn = SDARAttention(config)
+        self.post_attention_layernorm = nn.RMSNorm(
+            config.hidden_size, epsilon=config.rms_norm_eps)
+        self.mlp = DroplessMoE(
+            config.hidden_size, config.moe_intermediate_size,
+            config.num_experts, config.num_experts_per_tok,
+            norm_topk_prob=config.norm_topk_prob,
+            expert_range=config.expert_range,
+            weight_attr=_normal_attr(config.initializer_range))
+
+
+class SDAR(PagedServingModel):
+    def __init__(self, config: SDARConfig):
+        super().__init__()
+        self.config = config
+        attr = _normal_attr(config.initializer_range)
+        self.embed_tokens = nn.Embedding(config.vocab_size,
+                                         config.hidden_size,
+                                         weight_attr=attr)
+        self.layers = nn.LayerList([SDARBlock(config)
+                                    for _ in range(config.num_layers)])
+        self.norm = nn.RMSNorm(config.hidden_size,
+                               epsilon=config.rms_norm_eps)
+        self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
+                                 weight_attr=attr, bias_attr=False)
+
+    @property
+    def tokens_per_block(self):
+        """Positions a decode step of this model holds a slot: the
+        scheduler's one sign that a step is a block's forward and not
+        one token's."""
+        return self.config.block_length
+
+    # -- the normal path: a full forward under the block-causal mask -----
+
+    def _layer(self, blk, x, mask, kernel_mode=None, kv_sink=None,
+               counts_sink=None, attention=True):
+        """One layer on x [1, s, d] under ``mask`` [s, s]. With
+        ``attention`` False the layer stops at its keys and values (the
+        last layer of a prefill, whose output nobody reads)."""
+        q, k, v = blk.self_attn.qkv(blk.input_layernorm(x))
+        if kv_sink is not None:
+            kv_sink.append((k, v))
+        if not attention:
+            return x
+        h = x + blk.self_attn.out(F.scaled_dot_product_attention(
+            q, k, v, attn_mask=mask))
+        return h + blk.mlp(blk.post_attention_layernorm(h),
+                           kernel_mode=kernel_mode,
+                           counts_sink=counts_sink)
+
+    def forward(self, input_ids, kernel_mode=None, counts_sink=None):
+        """Logits [b, s, vocab] of ``input_ids`` [b, s] under the
+        block-causal mask, positions from 0."""
+        s = input_ids.shape[1]
+        pos = jnp.arange(s, dtype=jnp.int32)
+        mask = block_causal_mask(pos, pos, self.config.block_length)
+        x = self.embed_tokens(input_ids)
+        for blk in self.layers:
+            x = self._layer(blk, x, mask, kernel_mode=kernel_mode,
+                            counts_sink=counts_sink)
+        return self.lm_head(self.norm(x))
+
+    # -- served path: programs over the paged cache ----------------------
+
+    def _jit(self, name, build):
+        """The cached serving program ``name``, built on first use."""
+        jits = self.__dict__.setdefault("_paged_jits", {})
+        if name not in jits:
+            jits[name] = build()
+        return jits[name]
+
+    def _padded(self, cache, ids, pad_to):
+        ids = np.asarray(ids).reshape(-1)
+        bs = cache.block_size
+        spad = -(-ids.shape[0] // bs) * bs
+        if pad_to is not None:
+            cap = cache.max_blocks_per_seq * bs
+            spad = -(-min(max(int(pad_to), spad), cap) // bs) * bs
+        out = np.zeros((1, spad), np.int64)
+        out[0, :ids.shape[0]] = ids
+        return out
+
+    def _check_cache(self, cache):
+        if cache.quantized:
+            raise ValueError(
+                "SDAR serves a bfloat16/float32 KV pool only: int8 KV "
+                "(FLAGS_kv_cache_dtype=int8) has no block-step program.")
+        if cache.block_size % self.config.block_length:
+            raise ValueError(
+                f"SDAR: the KV block_size {cache.block_size} must be a "
+                f"multiple of block_length {self.config.block_length}, "
+                "so that a page's keys depend on nothing after it.")
+
+    @staticmethod
+    def _table_row(cache, slot):
+        """The slot's table row as the prefill programs take it: a copy.
+        Nobody waits for a prefill that samples nothing, and a backend
+        may read a host array it was handed after the call returned (the
+        CPU's does: a view of ``block_tables`` showed the program, 10
+        times of 20, what the host wrote into it afterwards). The
+        scheduler's next moves are on this row: it grows for the open
+        block, and is zeroed if the slot is preempted."""
+        return jnp.asarray(cache.block_tables[slot].copy())
+
+    def paged_prefill(self, cache, slot, prompt_ids, temperature=0.0,
+                      pad_to=None, kernel_mode=None):
+        """Run ``prompt_ids`` (whole blocks: a multiple of
+        ``block_length``) through the block-causal forward and write
+        every layer's keys and values into the slot's blocks; sets
+        ``seq_len``. Returns None: a block-diffusion prefill samples no
+        token (the first block opens masked)."""
+        from ..inference.paged import resolve_paged_kernel
+        self._check_cache(cache)
+        mode = resolve_paged_kernel(kernel_mode)
+        with self._paged_lock(), cache.pool_lock:
+            with _phase("serving.prefill.forward"):
+                n = int(np.asarray(prompt_ids).size)
+                ids = self._padded(cache, prompt_ids, pad_to)
+                arrs = self._param_arrays()
+                pools = self._jit(("prefill", mode),
+                                  lambda: self._build_prefill(mode))(
+                    arrs, jnp.asarray(ids), jnp.int32(n),
+                    self._table_row(cache, slot),
+                    cache.k_pools, cache.v_pools)
+                self._param_rebind()(arrs)
+            with _phase("serving.prefill.pool_write",
+                        layers=cache.num_layers, tokens=ids.shape[1]):
+                cache.rebind_pools(*pools)
+                cache.seq_lens[slot] = n
+
+    def _build_prefill(self, mode):
+        rebind = self._param_rebind()
+        block_length = self.config.block_length
+
+        def fn(param_arrays, ids_arr, n, row, k_pools, v_pools):
+            from ..core.autograd import no_grad
+            from ..inference.paged import paged_prefill_write_masked
+            rebind(param_arrays)
+            s = ids_arr.shape[1]
+            pos = jnp.arange(s, dtype=jnp.int32)
+            mask = block_causal_mask(pos, pos, block_length)
+            sink = []
+            with no_grad():
+                x = self.embed_tokens(Tensor(ids_arr))
+                last = len(self.layers) - 1
+                for i, blk in enumerate(self.layers):
+                    x = self._layer(blk, x, mask, kernel_mode=mode,
+                                    kv_sink=sink, attention=i < last)
+            new_k, new_v = [], []
+            # row by row, the padding to the null block: a scatter of
+            # whole [16, 4, 128] pages makes the v5e compiler re-lay the
+            # pool out and copy it twice a layer (PERF.md, PR 28)
+            zero = jnp.int32(0)
+            for i, (k, v) in enumerate(sink):
+                kp, vp = paged_prefill_write_masked(
+                    k_pools[i], v_pools[i], row, k._data[0], v._data[0],
+                    zero, zero, n)
+                new_k.append(kp)
+                new_v.append(vp)
+            return new_k, new_v
+        tag = "sdar.paged_prefill" + ("" if mode == "auto"
+                                      else f".k-{mode}")
+        return _aot_wrap(
+            _named_jit(fn, "sdar_paged_prefill", donate_argnums=(4, 5)),
+            self._aot_tag(tag))
+
+    def paged_prefill_extend(self, cache, slot, ids, tail_start,
+                             write_start, temperature=0.0, pad_to=None,
+                             kernel_mode=None):
+        """Prefix hit or re-prefill: the slot's table already maps the
+        keys and values of ``[0, tail_start)``; compute only the tail
+        (whole blocks, so ``tail_start`` is block-aligned), write it and
+        attend it block-causally over the whole paged context."""
+        from ..inference.paged import resolve_paged_kernel
+        self._check_cache(cache)
+        mode = resolve_paged_kernel(kernel_mode)
+        with _phase("serving.prefill.forward"):
+            ids = np.asarray(ids).reshape(-1)
+            total = ids.shape[0]
+            tail = self._padded(cache, ids[tail_start:], pad_to)
+            with self._paged_lock(), cache.pool_lock:
+                arrs = self._param_arrays()
+                pools = self._jit(("extend", mode),
+                                  lambda: self._build_extend(mode))(
+                    arrs, jnp.asarray(tail), jnp.int32(tail_start),
+                    jnp.int32(write_start), jnp.int32(total),
+                    self._table_row(cache, slot),
+                    cache.k_pools, cache.v_pools)
+                self._param_rebind()(arrs)
+                cache.rebind_pools(*pools)
+            cache.seq_lens[slot] = total
+
+    def _build_extend(self, mode):
+        rebind = self._param_rebind()
+        block_length = self.config.block_length
+
+        def fn(param_arrays, tail_ids, t_start, w_start, t_total, row,
+               k_pools, v_pools):
+            from ..core.autograd import no_grad
+            from ..inference.paged import (paged_prefill_write_masked,
+                                           paged_prefix_attention_dense)
+            rebind(param_arrays)
+            new_k, new_v = [], []
+            with no_grad():
+                x = self.embed_tokens(Tensor(tail_ids))
+                last = len(self.layers) - 1
+                for i, blk in enumerate(self.layers):
+                    attn = blk.self_attn
+                    q, k, v = attn.qkv(blk.input_layernorm(x),
+                                       position_offset=t_start)
+                    kp, vp = paged_prefill_write_masked(
+                        k_pools[i], v_pools[i], row, k._data[0],
+                        v._data[0], t_start, w_start, t_total)
+                    new_k.append(kp)
+                    new_v.append(vp)
+                    if i == last:
+                        break
+                    out = paged_prefix_attention_dense(
+                        q._data[0], kp, vp, row, t_start, t_total,
+                        block_len=block_length)
+                    h = x + attn.out(Tensor(out[None]))
+                    x = h + blk.mlp(blk.post_attention_layernorm(h),
+                                    kernel_mode=mode)
+            return new_k, new_v
+        tag = "sdar.paged_extend" + ("" if mode == "auto"
+                                     else f".k-{mode}")
+        return _aot_wrap(
+            _named_jit(fn, "sdar_paged_extend", donate_argnums=(6, 7)),
+            self._aot_tag(tag))
+
+    def paged_block_step(self, cache, block_ids, active, kernel_mode=None,
+                         moe_sink=None):
+        """One forward of every active slot's open block: ``block_ids``
+        [B, L] sit at positions ``[seq_len, seq_len + L)``, their keys
+        and values are written there (overwriting the last forward's)
+        and each row attends ``seq_len + L`` keys, the block's own among
+        them, with no mask inside the block. ``seq_lens`` do not move:
+        the caller advances a slot whose block it commits.
+
+        Returns ONE float32 device array, so that one read brings
+        everything back: ``unpack_block_step`` splits it into, per
+        position, the arg-max token, its logit and its softmax
+        probability, and the rows routed to each expert of each layer.
+        A ``moe_sink`` list is appended what each layer's expert
+        layer saw and gave in this very program over the ``B * L`` rows,
+        two device arrays (each output of the program costs the host
+        50 us a step, chip runs PR 28): (input, output) [2, layers,
+        rows, hidden] and the router's (weights, expert ids) [2,
+        layers, rows, k] float32. Nothing reads them back unless the
+        caller does."""
+        from ..inference.paged import resolve_paged_kernel
+        self._check_cache(cache)
+        mode = resolve_paged_kernel(kernel_mode)
+        with self._paged_lock(), cache.pool_lock:
+            arrs = self._param_arrays()
+            packed, moe, *pools = self._jit(
+                ("block_step", mode), lambda: self._build_block_step(mode))(
+                arrs, jnp.asarray(block_ids, jnp.int32), cache.k_pools,
+                cache.v_pools, cache.block_tables,
+                jnp.asarray(cache.seq_lens), jnp.asarray(active))
+            self._param_rebind()(arrs)
+            cache.rebind_pools(*pools)
+        # the read-back's transfer queues behind the program now, not
+        # when the host comes to ask for it
+        packed.copy_to_host_async()
+        if moe_sink is not None:
+            moe_sink.append(moe)
+        return packed
+
+    def unpack_block_step(self, packed, batch):
+        """(tokens [B, L] int64, logits [B, L], probabilities [B, L],
+        expert rows [layers, experts] int64) of ``paged_block_step``'s
+        array, read to the host."""
+        cfg = self.config
+        n = batch * cfg.block_length
+        packed = np.asarray(packed)
+        shape = (batch, cfg.block_length)
+        return (packed[:n].astype(np.int64).reshape(shape),
+                packed[n:2 * n].reshape(shape),
+                packed[2 * n:3 * n].reshape(shape),
+                packed[3 * n:].astype(np.int64).reshape(
+                    cfg.num_layers, cfg.num_experts))
+
+    def _build_block_step(self, mode):
+        rebind = self._param_rebind()
+        cfg = self.config
+        block_length = cfg.block_length
+
+        def fn(param_arrays, ids, k_pools, v_pools, tables, lens, active):
+            from ..core.autograd import no_grad
+            from ..inference.paged import (paged_block_attention,
+                                           paged_spec_write)
+            rebind(param_arrays)
+            b = ids.shape[0]
+            whole = jnp.full((b,), block_length, jnp.int32)
+            seen = jnp.where(active, lens + block_length, lens)
+            counts, routed, moe_in, moe_out = [], [], [], []
+            new_k, new_v = [], []
+            with no_grad():
+                x = self.embed_tokens(Tensor(ids))
+                for i, blk in enumerate(self.layers):
+                    attn = blk.self_attn
+                    q, k, v = attn.qkv(blk.input_layernorm(x),
+                                       position_offset=lens)
+                    kp, vp = paged_spec_write(
+                        k_pools[i], v_pools[i], tables, lens, k._data,
+                        v._data, whole, active)
+                    out = paged_block_attention(
+                        q._data, kp, vp, tables, seen, kernel_mode=mode)
+                    h = x + attn.out(Tensor(out))
+                    m = blk.post_attention_layernorm(h)
+                    y = blk.mlp(m, kernel_mode=mode, counts_sink=counts,
+                                route_sink=routed,
+                                valid=Tensor(jnp.repeat(
+                                    active, block_length)),
+                                kernel_tag="_step")
+                    moe_in.append(m._data.reshape(-1, m.shape[-1]))
+                    moe_out.append(y._data.reshape(-1, y.shape[-1]))
+                    x = h + y
+                    new_k.append(kp)
+                    new_v.append(vp)
+                x = self.norm(x)
+            # float32 logits straight off the MXU's accumulator: a
+            # bfloat16 round of them would move a probability by 2 %
+            logits = jnp.matmul(x._data, self.lm_head.weight._data,
+                                preferred_element_type=jnp.float32)
+            top = jnp.max(logits, axis=-1)
+            tok = jnp.argmax(logits, axis=-1)
+            prob = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+            f32 = jnp.float32
+            packed = jnp.concatenate(
+                [tok.astype(f32).reshape(-1), top.reshape(-1),
+                 prob.reshape(-1)]
+                + [c._data.astype(f32) for c in counts])
+            moe = (jnp.stack([jnp.stack(moe_in), jnp.stack(moe_out)]),
+                   jnp.stack([jnp.stack([w._data for w, _ in routed]),
+                              jnp.stack([e._data.astype(f32)
+                                         for _, e in routed])]))
+            return packed, moe, new_k, new_v
+        tag = "sdar.block_step" + ("" if mode == "auto" else f".k-{mode}")
+        return _aot_wrap(
+            _named_jit(fn, "sdar_block_step", donate_argnums=(2, 3)),
+            self._aot_tag(tag))
+
+    def apply_serving_mesh(self, mesh):
+        if mesh is not None:
+            raise ValueError(
+                "SDAR is served on one device: a serving mesh "
+                "(FLAGS_serving_mesh) has no sharding rules for its "
+                "stacked experts or its block-step program yet.")

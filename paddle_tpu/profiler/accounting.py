@@ -309,14 +309,15 @@ class Accountant:
             req.cost.queue_us = float(wait_us)
 
     def note_prefill(self, req, computed_tokens, covered, compile_us,
-                     reprefill, aot_saved_us=0.0):
+                     reprefill, aot_saved_us=0.0, emitted=1):
         """A prefill ran for ``req`` this step: ``computed_tokens`` is
         the padded tail it actually computed (covered prefix tokens are
         NOT in it — they are free), ``compile_us`` any XLA compile its
         dispatch triggered (billed direct to this request), and
         ``aot_saved_us`` any compile time an AOT-cache hit AVOIDED
         (credited to this request, kept outside the closure sum —
-        saved time never ran on the device)."""
+        saved time never ran on the device). ``emitted``: the token it
+        sampled (a block-diffusion prefill samples none)."""
         kind = "reprefill" if reprefill else "prefill"
         self._notes.append(_Note(req, kind, max(int(computed_tokens), 1),
                                  float(compile_us),
@@ -325,7 +326,7 @@ class Accountant:
         if c is not None:
             c.tokens_prefilled += int(computed_tokens)
             c.covered_tokens += int(covered)
-            c.tokens_emitted += 1
+            c.tokens_emitted += int(emitted)
 
     def note_decode(self, req):
         """``req`` received one token from this step's batched decode."""
@@ -334,6 +335,20 @@ class Accountant:
         if c is not None:
             c.tokens_decoded += 1
             c.tokens_emitted += 1
+
+    def note_block(self, req, positions, emitted):
+        """``req`` took part in this step's block forward
+        (block-diffusion decoding, scheduler ``_decode_block``): the
+        device computed ``positions`` for it, a denoising forward's as a
+        commit's (the apportionment weight), and ``emitted`` tokens
+        streamed to the caller: none unless the forward committed the
+        block."""
+        self._notes.append(_Note(req, "decode", int(positions),
+                                 emitted=int(emitted)))
+        c = req.cost
+        if c is not None:
+            c.tokens_decoded += int(emitted)
+            c.tokens_emitted += int(emitted)
 
     def note_spec(self, req, emitted, proposed, accepted):
         """``req`` participated in this step's speculative verify sweep
@@ -619,10 +634,13 @@ class _NullAccountant(Accountant):
         pass
 
     def note_prefill(self, req, computed_tokens, covered, compile_us,
-                     reprefill, aot_saved_us=0.0):
+                     reprefill, aot_saved_us=0.0, emitted=1):
         pass
 
     def note_decode(self, req):
+        pass
+
+    def note_block(self, req, positions, emitted):
         pass
 
     def note_spec(self, req, emitted, proposed, accepted):

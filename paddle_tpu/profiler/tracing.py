@@ -70,7 +70,8 @@ from ..core import flags as flags_mod
 from . import metrics as _metrics
 
 __all__ = ["Span", "start_trace", "span", "record_span", "phase",
-           "PHASE_NAMES", "phase_histogram_name", "attach",
+           "PHASE_NAMES", "BLOCK_PHASE_NAMES", "phase_histogram_name",
+           "attach",
            "current_context", "current_trace_id", "get_trace",
            "trace_ids", "export_trace", "export_ring", "records",
            "enabled", "reset"]
@@ -324,6 +325,9 @@ PHASE_NAMES = (
     "serving.prefill.readback",
     "serving.decode", "serving.decode.prepare", "serving.decode.dispatch",
     "serving.decode.readback", "serving.decode.emit", "serving.step_end")
+# inside serving.decode.emit of a block-diffusion model's step only
+# (``Scheduler._decode_block``): no other model feeds them
+BLOCK_PHASE_NAMES = ("serving.block.unmask", "serving.block.commit")
 
 
 def phase_histogram_name(name):
@@ -341,7 +345,8 @@ _PHASE_BOUNDS = tuple(m * 10 ** e for e in range(1, 7) for m in (1, 2, 5)) \
 # always did
 _PHASE_HIST = {n: _metrics.histogram(phase_histogram_name(n),
                                      bounds=_PHASE_BOUNDS)
-               for n in PHASE_NAMES if n != "serving.step"}
+               for n in PHASE_NAMES + BLOCK_PHASE_NAMES
+               if n != "serving.step"}
 _PHASE_HIST["serving.step"] = None
 
 
@@ -358,7 +363,7 @@ class phase:  # noqa: N801 — used as a function: ``with phase(name):``
     def __init__(self, name, **attrs):
         self._ann = _TraceAnnotation(name, **attrs) \
             if _TraceAnnotation.is_enabled() else None
-        self._hist = _PHASE_HIST[name]  # KeyError: not in PHASE_NAMES
+        self._hist = _PHASE_HIST[name]  # KeyError: not a catalogued name
 
     def __enter__(self):
         if self._ann is not None:

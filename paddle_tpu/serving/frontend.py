@@ -215,6 +215,12 @@ class ServingEngine:
             raise ValueError(
                 f"ServingEngine: unknown role {role!r} "
                 f"(expected one of {ROLES})")
+        if self.role != "mixed" and \
+                getattr(model, "tokens_per_block", 1) > 1:
+            raise ValueError(
+                f"ServingEngine: role {self.role!r} is disaggregated "
+                "serving, which a block-diffusion model is not served "
+                "with: its prefill samples no first token to hand off.")
         self._sched = Scheduler(
             model, max_batch=max_batch, block_size=block_size,
             max_seq_len=max_seq_len, num_blocks=num_blocks,
@@ -465,6 +471,23 @@ class ServingEngine:
                                      sched.max_seq_len)
             t0 = time.perf_counter_ns()
             n = 0
+            kernel_mode = getattr(sched, "kernel_mode", None)
+            width = getattr(sched.model, "tokens_per_block", 1)
+
+            def decode_once(active):
+                """The batched decode program: a token a slot, or a
+                block-diffusion model's block step."""
+                if width > 1:
+                    sched.model.paged_block_step(
+                        cache, np.zeros((cache.max_batch, width),
+                                        np.int64), active,
+                        kernel_mode=kernel_mode)
+                else:
+                    sched.model.paged_decode_step(
+                        cache, np.zeros((cache.max_batch,), np.int64),
+                        active, temperature=sched.temperature,
+                        kernel_mode=kernel_mode)
+
             # role-specialized warm sets (disaggregated serving):
             # prefill replicas run ONLY the bucket ladder (they never
             # decode), decode replicas warm ONLY the decode/spec
@@ -477,12 +500,7 @@ class ServingEngine:
                     try:
                         active = np.zeros((cache.max_batch,), bool)
                         active[slot] = True
-                        sched.model.paged_decode_step(
-                            cache, np.zeros((cache.max_batch,),
-                                            np.int64), active,
-                            temperature=sched.temperature,
-                            kernel_mode=getattr(sched, "kernel_mode",
-                                                None))
+                        decode_once(active)
                         n += 1
                         if sched.spec:
                             sk = sched.spec_tokens
@@ -503,9 +521,14 @@ class ServingEngine:
                         continue  # pool smaller than the ladder tail
                     try:
                         ids = np.zeros((b,), np.int64)
-                        sched.model.paged_prefill(
-                            cache, slot, ids,
-                            temperature=sched.temperature, pad_to=b)
+                        if width > 1:
+                            sched.model.paged_prefill(
+                                cache, slot, ids, pad_to=b,
+                                kernel_mode=kernel_mode)
+                        else:
+                            sched.model.paged_prefill(
+                                cache, slot, ids,
+                                temperature=sched.temperature, pad_to=b)
                         n += 1
                         if not decoded:
                             # one decode step warms the (single) decode
@@ -514,13 +537,7 @@ class ServingEngine:
                             # the bucketing convention
                             active = np.zeros((cache.max_batch,), bool)
                             active[slot] = True
-                            sched.model.paged_decode_step(
-                                cache, np.zeros((cache.max_batch,),
-                                                np.int64), active,
-                                temperature=sched.temperature,
-                                kernel_mode=getattr(sched,
-                                                    "kernel_mode",
-                                                    None))
+                            decode_once(active)
                             decoded = True
                             n += 1
                             if sched.spec:

@@ -123,7 +123,20 @@ class ServiceTimeModel:
     steady-state estimate. Predictions are deliberately simple and
     documented — a drain-time estimate, not a simulation — and the
     admission path divides by ``FLAGS_admission_optimism`` worth of
-    slack before trusting them."""
+    slack before trusting them.
+
+    One stalled dispatch is not a service time: once primed, a sample
+    counts for at most ``OUTLIER`` times the estimate it updates. A
+    single step that a paused host stretched to seconds would otherwise
+    raise the decode estimate by a fifth of the stall and, times a queue
+    of 64, predict a wait past ``FLAGS_shed_wait_s``: half the queue shed
+    for one hiccup (the driver's check of PR 28). A slowdown that lasts
+    still moves the estimate by ``1 + alpha * (OUTLIER - 1)`` a sample
+    (1.6 at the defaults) until the samples are within ``OUTLIER`` of it:
+    a step ten times slower for good reads ninefold after 11 samples
+    instead of 10, so sustained overload sheds as before."""
+
+    OUTLIER = 4.0
 
     __slots__ = ("alpha", "min_samples", "prefill_us_per_token",
                  "decode_step_us", "n_prefill", "n_decode")
@@ -142,21 +155,25 @@ class ServiceTimeModel:
         served regardless (the histogram wants them); refusals wait."""
         return self.n_prefill >= self.min_samples
 
-    def _ewma(self, old, sample):
-        return sample if old is None else \
-            old + self.alpha * (sample - old)
+    def _ewma(self, old, sample, seen):
+        if old is None:
+            return sample
+        if seen >= self.min_samples and old > 0.0:
+            sample = min(sample, self.OUTLIER * old)
+        return old + self.alpha * (sample - old)
 
     def observe_prefill(self, tokens, us):
         """One prefill dispatch computed ``tokens`` (padded) in ``us``
         of compile-free wall time."""
         rate = float(us) / max(int(tokens), 1)
         self.prefill_us_per_token = \
-            self._ewma(self.prefill_us_per_token, rate)
+            self._ewma(self.prefill_us_per_token, rate, self.n_prefill)
         self.n_prefill += 1
 
     def observe_decode(self, us):
         """One batched decode step took ``us`` compile-free."""
-        self.decode_step_us = self._ewma(self.decode_step_us, float(us))
+        self.decode_step_us = self._ewma(self.decode_step_us, float(us),
+                                         self.n_decode)
         self.n_decode += 1
 
     def predict(self, queued_tokens, queued_requests, own_tokens):

@@ -27,7 +27,11 @@ around every entry point). Each ``step()`` is one scheduling iteration:
    instead runs ONE batched multi-position verify sweep over
    prompt-lookup drafts (``_decode_spec``; docs/SERVING.md "Decode
    speed tiers") — several tokens per request per step, still
-   bit-identical, rejected rows rolled back.
+   bit-identical, rejected rows rolled back. A model that declares
+   ``tokens_per_block`` > 1 (block-diffusion decoding, ``models/sdar.py``)
+   runs ``_decode_block`` instead: the step is one forward of every
+   slot's open block of L positions, which either unmasks some of them
+   (nothing emitted) or commits the block (L tokens emitted at once).
 
 Every request terminates in exactly one of ``DONE`` / ``CANCELLED`` /
 ``TIMEOUT`` / ``SHED`` (or ``ERROR`` if the engine itself died). SLO
@@ -226,6 +230,18 @@ _h_spec_accept = _metrics.histogram(
 _g_kv_quant_bits = _metrics.gauge("serving.kv.quant.bits")
 _g_kv_quant_mult = _metrics.gauge(
     "serving.kv.quant.capacity_multiplier")
+# block-diffusion decoding (docs/SERVING.md "Block-diffusion decoding"):
+# slot-forwards that denoised and that committed, blocks committed,
+# positions unmasked; and what the expert layers of those forwards
+# routed (summed over layers from the counts the block step returns with
+# its tokens): rows, experts that got any, the fullest expert's rows
+_m_blk_denoise = _metrics.counter("serving.blockdiff.denoise_forwards")
+_m_blk_commit = _metrics.counter("serving.blockdiff.commit_forwards")
+_m_blk_blocks = _metrics.counter("serving.blockdiff.blocks_committed")
+_m_blk_unmasked = _metrics.counter("serving.blockdiff.tokens_unmasked")
+_m_moe_rows = _metrics.counter("serving.moe.rows")
+_m_moe_hit = _metrics.counter("serving.moe.experts_hit")
+_m_moe_max = _metrics.counter("serving.moe.max_rows")
 # per-THREAD cumulative backend-compile seconds (profiler.metrics'
 # jax.monitoring listener): deltas around a prefill/decode dispatch
 # attribute compile cost to the request that triggered it — a
@@ -263,6 +279,17 @@ class Scheduler:
 
         cfg = model.config
         self.model = model
+        # positions a decode step holds a slot: 1, or a block-diffusion
+        # model's block length. What the model declares picks the decode
+        # path; nothing else does.
+        self._block_len = int(getattr(model, "tokens_per_block", 1))
+        if self._block_len > 1 and (block_size % self._block_len
+                                    or max_seq_len % self._block_len):
+            raise ValueError(
+                f"serving: block_size {block_size} and max_seq_len "
+                f"{max_seq_len} must be multiples of the model's block "
+                f"length {self._block_len}: a KV page may not end "
+                "inside a block.")
         self.temperature = temperature
         self.eos_token_id = eos_token_id
         self.max_seq_len = max_seq_len
@@ -284,6 +311,11 @@ class Scheduler:
         kv_dtype = resolve_kv_dtype(
             flags_mod.flag("FLAGS_kv_cache_dtype")
             if kv_cache_dtype is None else kv_cache_dtype)
+        if self._block_len > 1 and kv_dtype == "int8":
+            raise ValueError(
+                "serving: int8 KV (FLAGS_kv_cache_dtype) is not served "
+                "with a block-diffusion model: its block step has no "
+                "quantized program.")
         # paged-attention kernel routing (FLAGS_paged_kernel, read ONCE
         # at construction like kv_cache_dtype): the resolved mode rides
         # into every decode dispatch so the traced programs bake the
@@ -291,7 +323,7 @@ class Scheduler:
         # / dense) for spans and gates
         self.kernel_mode = resolve_paged_kernel(paged_kernel)
         self.kernel_route = kernel_route(self.kernel_mode)
-        hd = cfg.hidden_size // cfg.num_heads
+        hd = cfg.head_dim
         compute_dt = dtype if dtype is not None else jnp.bfloat16
         num_blocks = sized_num_blocks(
             num_blocks, max_batch, mbps, kv_dtype, hd, compute_dt)
@@ -329,6 +361,16 @@ class Scheduler:
             if spec_tokens is None else spec_tokens), 1)
         self.spec_ngram = max(
             int(flags_mod.flag("FLAGS_serving_spec_ngram")), 1)
+        if self._block_len > 1 and armed_spec:
+            raise ValueError(
+                "serving: speculation (FLAGS_serving_spec) is not served "
+                "with a block-diffusion model: a step already yields a "
+                "block of tokens.")
+        if self._block_len > 1 and temperature != 0.0:
+            raise ValueError(
+                "serving: a block-diffusion model is served greedy "
+                "(temperature 0): the unmasking rule ranks arg-max "
+                "confidences.")
         self.spec = armed_spec and temperature == 0.0
         self.prefill_token_budget = (
             flags_mod.flag("FLAGS_serving_prefill_budget")
@@ -377,6 +419,18 @@ class Scheduler:
         self._last_tok = np.zeros((max_batch,), np.int64)
         self._remaining = np.zeros((max_batch,), np.int64)
         self._step_no = 0  # ``serving.steps`` as the running step began
+        # block-diffusion decoding, per slot: the open block's ids, which
+        # of its positions are still masked (state, never read off the
+        # ids: a prompt may hold the mask id), how many leading positions
+        # the prompt gave, and how many denoising forwards it has had
+        n_blk = self._block_len if self._block_len > 1 else 0
+        self._blk_ids = np.zeros((max_batch, n_blk), np.int64)
+        self._blk_masked = np.zeros((max_batch, n_blk), bool)
+        self._blk_given = np.zeros((max_batch,), np.int64)
+        self._blk_denoised = np.zeros((max_batch,), np.int64)
+        # called with a dict for every slot-forward of a block step when
+        # set (the benchmark's reference check records through it)
+        self.block_observer = None
 
     # -- submission / cancellation ------------------------------------
 
@@ -403,6 +457,18 @@ class Scheduler:
         prompt = validate_request(prompt_ids, max_new_tokens,
                                   self.max_seq_len, self.cache,
                                   who="serving.submit")
+        if self._block_len > 1:
+            if prefill_only:
+                raise ValueError(
+                    "serving.submit: a block-diffusion model is not "
+                    "served disaggregated: its prefill samples no first "
+                    "token to hand off.")
+            # the last block is written whole, past max_new_tokens
+            whole = -(-(prompt.size + int(max_new_tokens))
+                      // self._block_len) * self._block_len
+            validate_request(prompt, whole - prompt.size,
+                             self.max_seq_len, self.cache,
+                             who="serving.submit")
         if prefill_only and not self.prefix_cache:
             raise ValueError(
                 "serving.submit: prefill_only requires the prefix "
@@ -465,6 +531,10 @@ class Scheduler:
         the fabric hop to this request's CostReport; ``handoff_id``
         (remote handoffs) rides the ``serving.handoff_admit`` span so
         the trace joins the lease/relay records."""
+        if self._block_len > 1:
+            raise HandoffError(
+                "serving.admit_handoff: a block-diffusion model is not "
+                "served disaggregated")
         prompt = validate_request(prompt_ids, max_new_tokens,
                                   self.max_seq_len, self.cache,
                                   who="serving.admit_handoff")
@@ -662,9 +732,15 @@ class Scheduler:
             with _phase("serving.admit.plan"):
                 req = self.queue[0]
                 ids = self._prefill_ids(req)
+                given = ids[:0]
+                if self._block_len > 1:
+                    # whole blocks are prefilled; what is left over of
+                    # the prompt opens the first block unmasked
+                    whole = len(ids) // self._block_len * self._block_len
+                    ids, given = ids[:whole], ids[whole:]
                 ids_len = len(ids)
-                plan = self.cache.plan_prefix(ids) if self.prefix_cache \
-                    else None
+                plan = self.cache.plan_prefix(ids) \
+                    if self.prefix_cache and ids_len else None
                 covered = plan.covered_tokens if plan is not None else 0
                 # full coverage still computes the final token for its
                 # logits; everything covered is free
@@ -695,7 +771,10 @@ class Scheduler:
             comp0 = _compile_s()  # compile billed to THIS request
             saved0 = _saved_s()   # ...and so are AOT-cache savings
             t_pf = time.perf_counter_ns()
-            if covered:
+            if self._block_len > 1:
+                tok = None  # a block-diffusion prefill samples nothing
+                pad_to = self._prefill_blocks(req, slot, ids, plan)
+            elif covered:
                 tail_start = plan.tail_start
                 pad_to = bucket_length(ids_len - tail_start, bs,
                                        self.bucket_cap,
@@ -727,6 +806,19 @@ class Scheduler:
                 if plan is not None:
                     _m_prefix_computed.inc(pad_to)
                     self.cache.commit_prefix(slot, plan)
+                if tok is None:
+                    self.accounting.note_prefill(
+                        req, pad_to, covered, comp_us,
+                        reprefill=req.preempts > 0,
+                        aot_saved_us=(_saved_s() - saved0) * 1e6,
+                        emitted=0)
+                    if pad_to:
+                        self.overload.observe_prefill(
+                            pad_to, max(pf_us - comp_us, 0.0))
+                    self._remaining[slot] = \
+                        req.max_new_tokens - len(req.generated)
+                    self._open_block(slot, given)
+                    continue
                 # the prefill note carries only the COMPUTED (padded
                 # tail) tokens — covered prefix tokens are free in the
                 # apportionment, re-prefill bills to the preemption event
@@ -772,20 +864,64 @@ class Scheduler:
                 return s
         return cands[0]
 
-    def _timed_decode_dispatch(self, dispatch, batch, ctx_tokens):
+    def _make_writable(self, grow):
+        """Make each running slot's next ``grow`` positions writable
+        (1: a token; a block's length: an open block, every step): grow
+        tables (cold cached prefixes are LRU-evicted before anything
+        else — eviction always runs before preemption), copy-on-write
+        shared blocks; preempt a victim on true pool exhaustion (never
+        truncate)."""
+        for slot in list(self.running):
+            if slot not in self.running:  # preempted as a victim
+                continue
+            while True:
+                new_len = int(self.cache.seq_lens[slot]) + grow
+                denied = self.cache.prepare_append(slot, new_len) \
+                    if grow == 1 else \
+                    self.cache.prepare_append_range(slot, new_len)
+                if denied:
+                    break
+                if denied.reason == CapacityError.SEQ_LIMIT:
+                    # retrying can never help — only a caller
+                    # bypassing validate_request's worst-case bound
+                    # can get here
+                    req = self.running[slot]
+                    raise RuntimeError(
+                        f"serving: request {req.rid} outgrew "
+                        f"max_blocks_per_seq: {denied.detail}")
+                if len(self.running) == 1:
+                    # unreachable since validate_request bounds each
+                    # request's worst-case demand to the pool; keep
+                    # as an invariant guard
+                    req = self.running[slot]
+                    need = math.ceil(new_len / self.cache.block_size)
+                    raise RuntimeError(
+                        f"serving: KV pool exhausted — request "
+                        f"{req.rid} needs {need} blocks, pool has "
+                        f"{self.cache.num_blocks - 1} usable and no "
+                        "other running request to preempt; increase "
+                        "num_blocks or lower max_seq_len")
+                victim = self._choose_victim()
+                self._preempt(victim)
+                if victim == slot:
+                    break  # grower preempted itself; re-prefills later
+
+    def _timed_decode_dispatch(self, dispatch, batch, ctx_tokens,
+                               **stats):
         """Run one batched decode program under the shared
         instrumentation contract — the dispatch and the read-back that
         waits for its tokens each a phase, compile + AOT-saved deltas
         billed through the accountant, pure device time fed to overload
         control — so the plain and speculative paths can never drift
         apart in what they report. ``dispatch`` returns the program's
-        tokens still on the device. Returns (tokens as numpy, wall us
-        of both)."""
+        tokens still on the device; ``stats`` join ``batch`` and
+        ``context_tokens`` on the dispatch span. Returns (tokens as
+        numpy, wall us of both)."""
         comp0 = _compile_s()
         saved0 = _saved_s()
         t_dec = time.perf_counter_ns()
         with _phase("serving.decode.dispatch", batch=batch,
-                    context_tokens=ctx_tokens):
+                    context_tokens=ctx_tokens, **stats):
             out = dispatch()
         with _phase("serving.decode.readback"):  # waits for the device
             out = np.asarray(out)
@@ -800,6 +936,8 @@ class Scheduler:
     def _decode(self):
         if not self.running:
             return []
+        if self._block_len > 1:
+            return self._decode_block()
         if self.spec:
             out = self._decode_spec()
             if out is not None:
@@ -808,45 +946,7 @@ class Scheduler:
             # this step runs the plain single-token path below —
             # bit-equivalent, just not multiplied
         with _phase("serving.decode.prepare"):
-            # make each slot's next position writable: grow tables (cold
-            # cached prefixes are LRU-evicted before anything else —
-            # eviction always runs before preemption), copy-on-write
-            # shared blocks; preempt a victim on true pool exhaustion
-            # (never truncate)
-            for slot in list(self.running):
-                if slot not in self.running:  # preempted as a victim
-                    continue
-                while True:
-                    denied = self.cache.prepare_append(
-                        slot, int(self.cache.seq_lens[slot]) + 1)
-                    if denied:
-                        break
-                    if denied.reason == CapacityError.SEQ_LIMIT:
-                        # retrying can never help — only a caller
-                        # bypassing validate_request's worst-case bound
-                        # can get here
-                        req = self.running[slot]
-                        raise RuntimeError(
-                            f"serving: request {req.rid} outgrew "
-                            f"max_blocks_per_seq: {denied.detail}")
-                    if len(self.running) == 1:
-                        # unreachable since validate_request bounds each
-                        # request's worst-case demand to the pool; keep
-                        # as an invariant guard
-                        req = self.running[slot]
-                        need = math.ceil(
-                            (int(self.cache.seq_lens[slot]) + 1)
-                            / self.cache.block_size)
-                        raise RuntimeError(
-                            f"serving: KV pool exhausted — request "
-                            f"{req.rid} needs {need} blocks, pool has "
-                            f"{self.cache.num_blocks - 1} usable and no "
-                            "other running request to preempt; increase "
-                            "num_blocks or lower max_seq_len")
-                    victim = self._choose_victim()
-                    self._preempt(victim)
-                    if victim == slot:
-                        break  # grower preempted itself; re-prefills later
+            self._make_writable(1)
             if not self.running:
                 return []
             active = np.zeros((self.cache.max_batch,), bool)
@@ -879,6 +979,166 @@ class Scheduler:
                 self._emit(req, t)
                 out.append((req.rid, t))
                 self._maybe_finish(slot)
+        _m_decoded.inc(len(out))
+        return out
+
+    # -- block-diffusion decoding (docs/SERVING.md) ---------------------
+
+    def _prefill_blocks(self, req, slot, ids, plan):
+        """Prefill ``ids`` (whole blocks) of a block-diffusion request
+        into ``slot``: the plain program, the tail-extend program after
+        a prefix hit, or nothing where the cache covers all of it (no
+        logits are wanted of a prefill). Returns the padded tokens
+        computed."""
+        n = len(ids)
+        covered = plan.covered_tokens if plan is not None else 0
+        if covered == n:
+            self.cache.seq_lens[slot] = n
+            return 0
+        pad_to = bucket_length(n - covered, self.cache.block_size,
+                               self.bucket_cap, max_len=self.max_seq_len)
+        with _tracing.span("serving.prefill", parent=req.span, tokens=n,
+                           pad_to=pad_to, reprefill=bool(req.generated),
+                           covered=covered,
+                           hit_blocks=plan.hit_blocks if covered else 0,
+                           step=self._step_no):
+            if covered:
+                self.model.paged_prefill_extend(
+                    self.cache, slot, ids, covered, covered,
+                    pad_to=pad_to, kernel_mode=self.kernel_mode)
+            else:
+                self.model.paged_prefill(
+                    self.cache, slot, ids, pad_to=pad_to,
+                    kernel_mode=self.kernel_mode)
+        return pad_to
+
+    def _open_block(self, slot, given):
+        """Open the slot's next block at ``seq_len``: ``given`` ids (what
+        a prompt leaves over past its last whole block) unmasked, the
+        rest masked."""
+        g = len(given)
+        self._blk_ids[slot, :g] = given
+        self._blk_ids[slot, g:] = self.model.config.mask_token_id
+        self._blk_masked[slot] = np.arange(self._block_len) >= g
+        self._blk_given[slot] = g
+        self._blk_denoised[slot] = 0
+
+    def _unmask(self, denoise, toks, probs):
+        """``low_confidence_static`` on every denoising slot at once: of
+        a block that opened with M masked positions, denoising forward t
+        of S unmasks the ``M // S`` (one more while ``t < M % S``) masked
+        positions whose arg-max token is most probable (ties: the
+        earlier position) and puts that token there. Returns the
+        positions unmasked, [B, L] bool."""
+        steps = int(self.model.config.denoise_steps)
+        opened = self._block_len - self._blk_given
+        n = np.where(denoise, opened // steps
+                     + (self._blk_denoised < opened % steps), 0)
+        conf = np.where(self._blk_masked, probs, -np.inf)
+        order = np.argsort(-conf, axis=1, kind="stable")
+        rank = np.argsort(order, axis=1, kind="stable")
+        pick = (rank < n[:, None]) & self._blk_masked
+        self._blk_ids[pick] = toks[pick]
+        self._blk_masked &= ~pick
+        self._blk_denoised += denoise
+        return pick
+
+    def _decode_block(self):
+        """One block step (``models/sdar.py``): ONE forward over every
+        running slot's open block, whichever phase it is in. A slot with
+        masked positions is denoised: the rule unmasks some, nothing is
+        emitted, nothing committed. A slot with none is committed: this
+        forward wrote the block's final keys and values, so ``seq_len``
+        moves past it, its tokens are emitted together (those the
+        prompt gave, and those past ``max_new_tokens``, are not) and the
+        next block opens masked. A preempted request forgets its open
+        block and re-prefills prompt + committed tokens."""
+        width = self._block_len
+        nb = self.cache.max_batch
+        with _phase("serving.decode.prepare"):
+            # the open block's rows are rewritten every step
+            self._make_writable(width)
+            if not self.running:
+                return []
+            active = np.zeros((nb,), bool)
+            active[list(self.running)] = True
+            denoise = active & self._blk_masked.any(axis=1)
+            batch = len(self.running)
+            n_denoise = int(denoise.sum())
+            ctx_tokens = int(self.cache.seq_lens[active].sum()) \
+                + batch * width
+
+        # read once: another thread may take the observer away mid-step.
+        # It is also shown what each expert layer saw and gave
+        observer = self.block_observer
+        moe = [] if observer is not None else None
+        packed, dec_us = self._timed_decode_dispatch(
+            lambda: self.model.paged_block_step(
+                self.cache, self._blk_ids, active,
+                kernel_mode=self.kernel_mode, moe_sink=moe),
+            batch, ctx_tokens, rows=batch * width,
+            denoise_slots=n_denoise, commit_slots=batch - n_denoise)
+        out = []
+        with _phase("serving.decode.emit"):
+            toks, logits, probs, expert_rows = \
+                self.model.unpack_block_step(packed, nb)
+            _m_moe_rows.inc(int(expert_rows.sum()))
+            _m_moe_hit.inc(int((expert_rows > 0).sum()))
+            _m_moe_max.inc(int(expert_rows.max(axis=1).sum()))
+            _m_blk_denoise.inc(n_denoise)
+            _m_blk_commit.inc(batch - n_denoise)
+            before = (self._blk_ids.copy(), self._blk_masked.copy()) \
+                if observer is not None else None
+            with _phase("serving.block.unmask"):
+                picked = self._unmask(denoise, toks, probs)
+                _m_blk_unmasked.inc(int(picked.sum()))
+            if before is not None:
+                for slot, req in self.running.items():
+                    observer({
+                        "rid": req.rid, "step": self._step_no,
+                        "seq_len": int(self.cache.seq_lens[slot]),
+                        "ids": before[0][slot], "masked": before[1][slot],
+                        "commit": not denoise[slot],
+                        "tokens": toks[slot], "logits": logits[slot],
+                        "probs": probs[slot], "unmasked": picked[slot],
+                        # of the whole step: the slots live in it, the
+                        # rows each expert of each layer got, and the
+                        # expert layers' (input, output) and (router's
+                        # weights, expert ids), two device arrays [2,
+                        # layers, rows, .] whose rows ``moe_rows`` are
+                        # this slot's
+                        "batch": batch, "expert_rows": expert_rows,
+                        "moe": moe[0] if moe else None,
+                        "moe_rows": slice(slot * width,
+                                             (slot + 1) * width)})
+            with _phase("serving.block.commit"):
+                # plain lists: this loop runs for every slot every step
+                commits = (~denoise).tolist()
+                given = self._blk_given.tolist()
+                remaining = self._remaining.tolist()
+                for slot, req in list(self.running.items()):
+                    _tracing.record_span(
+                        "serving.decode_step", req.span, dec_us,
+                        token=len(req.generated), batch=batch,
+                        route=self.kernel_route, step=self._step_no,
+                        commit=commits[slot])
+                    if not commits[slot]:
+                        self.accounting.note_block(req, width, 0)
+                        continue
+                    self.cache.seq_lens[slot] += width
+                    new = self._blk_ids[slot, given[slot]:].tolist()
+                    emitted = 0
+                    for t in new[:remaining[slot]]:
+                        emitted += 1
+                        self._emit(req, t)
+                        out.append((req.rid, t))
+                        if t == self.eos_token_id:
+                            break
+                    self._remaining[slot] -= emitted
+                    self.accounting.note_block(req, width, emitted)
+                    self._open_block(slot, ())
+                    self._maybe_finish(slot)
+                _m_blk_blocks.inc(batch - n_denoise)
         _m_decoded.inc(len(out))
         return out
 

@@ -105,3 +105,78 @@ def test_the_v5e_compiler_aliases_every_pool_and_copies_none(
     one_pool = 4097 * 16 * 8 * 128 * (1 if quantized else 2)
     assert mem.alias_size_in_bytes >= 2 * LAYERS * one_pool
     assert mem.temp_size_in_bytes < one_pool
+
+
+# -- the block-diffusion programs (ISSUE 28) ----------------------------------
+
+SDAR_LAYERS, SDAR_SLOTS, SDAR_BLOCKS = 2, 64, 8193
+SDAR_POOL = (SDAR_BLOCKS, BLOCK, 4, 128)
+
+
+@pytest.fixture(scope="module")
+def sdar():
+    """SDAR-30B-A3B's attention geometry (32 query / 4 KV heads of 128 on
+    a hidden of 2048) and expert widths (2048 x 768, 8 a token); 16
+    experts, not 128, and a small vocabulary: they touch no pool."""
+    from paddle_tpu.models import SDAR, SDARConfig
+
+    paddle.seed(0)
+    m = SDAR(SDARConfig(vocab_size=1024, num_layers=SDAR_LAYERS, num_experts=16,
+                        denoise_steps=2))
+    m.eval()
+    return m
+
+
+def _lower_sdar(model, program, one_chip):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = [s(SDAR_POOL, jnp.bfloat16)] * SDAR_LAYERS
+    arrs = model._param_arrays()
+    params = tuple(s(a.shape, jnp.bfloat16) for a in arrs)
+    row, scalar = s((PAGES,), jnp.int32), s((), jnp.int32)
+    try:
+        if program == "block_step":
+            return model._build_block_step("pallas")._jitted.lower(
+                params, s((SDAR_SLOTS, 4), jnp.int32), pools, pools,
+                s((SDAR_SLOTS, PAGES), jnp.int32),
+                s((SDAR_SLOTS,), jnp.int32), s((SDAR_SLOTS,), jnp.bool_))
+        if program == "prefill":
+            return model._build_prefill("pallas")._jitted.lower(
+                params, s((1, 512), jnp.int64), scalar, row, pools, pools)
+        return model._build_extend("pallas")._jitted.lower(
+            params, s((1, 512), jnp.int64), scalar, scalar, scalar, row,
+            pools, pools)
+    finally:
+        model._param_rebind()(arrs)
+
+
+@pytest.mark.parametrize("program, kernels", [
+    ("block_step", 3 * SDAR_LAYERS), ("prefill", 2 * (SDAR_LAYERS - 1)),
+    ("extend", 2 * (SDAR_LAYERS - 1))])
+def test_the_block_diffusion_programs_alias_every_pool_and_copy_none(
+        monkeypatch, one_chip, sdar, program, kernels):
+    """At 4 KV heads a scatter of whole [16, 4, 128] pages made the v5e
+    compiler re-lay the pool out and copy it twice a layer, the fourth
+    device operation of the cell's first traced run (PERF.md, PR 28):
+    the prefill writes row by row, as the extend and the step do."""
+    monkeypatch.setenv("PADDLE_PALLAS_FORCE_COMPILE", "1")
+    compiled = _lower_sdar(sdar, program, one_chip).compile()
+    text = compiled.as_text()
+    header = text[:text.index("\n")]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                         header)
+    assert len(aliased) == 2 * SDAR_LAYERS, header[:400]
+    copies = [ln.strip()[:160] for ln in text.split("\n")
+              if re.search(r"= bf16\[8193,16,4,128\]\S* copy\(", ln)]
+    assert not copies, copies
+    # the block attention (the step only) and the two grouped matmuls a
+    # layer; a prefill's last layer stops at its keys and values
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    one_pool = 8193 * 16 * 4 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * SDAR_LAYERS * one_pool
+    # the extend's dense attention over the whole paged context holds
+    # two [512, 32, 2048] float32 arrays (134 MB each); no pool beside
+    assert mem.temp_size_in_bytes < (3 if program == "extend" else 1) \
+        * one_pool

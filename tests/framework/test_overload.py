@@ -118,6 +118,62 @@ def test_service_time_model_unit():
     assert ttft == wait + 10 * 150.0 + 50.0
 
 
+@pytest.mark.parametrize("observe, steady", [
+    (lambda m, us: m.observe_decode(us), 36e3),
+    # a prefill the scheduler never waits for reads its dispatch alone
+    (lambda m, us: m.observe_prefill(128, us), 128.0),
+])
+def test_one_stalled_dispatch_is_not_a_service_time(observe, steady):
+    """A primed estimate takes a sample for at most ``OUTLIER`` times
+    itself: one dispatch that a paused host stretched to seconds moves it
+    by 1 + alpha * (OUTLIER - 1) and no further; a slowdown that lasts
+    still gets there, geometrically."""
+    m = overload.ServiceTimeModel()
+    read = (lambda: m.decode_step_us) if steady == 36e3 \
+        else (lambda: m.prefill_us_per_token * 128)
+    observe(m, 10 * steady)              # the first sample is taken whole
+    assert read() == pytest.approx(10 * steady)
+    for _ in range(40):
+        observe(m, steady)
+    assert read() == pytest.approx(steady, rel=1e-2)
+    observe(m, 4.5e6)                    # one stall of 4.5 s
+    assert read() <= steady * 1.01 * (1 + m.alpha * (m.OUTLIER - 1))
+    for _ in range(40):
+        observe(m, steady)
+    lasting = 0
+    while read() < 9 * steady:           # ten times slower, and it lasts
+        observe(m, 10 * steady)
+        lasting += 1
+    assert lasting <= 11              # 10 without the clamp
+
+
+def test_one_stalled_step_sheds_nothing_a_lasting_slowdown_does(
+        model, flags_guard):
+    """The predicted-wait watermark at a deep queue (64 queued behind a
+    36 ms step is 2.3 s of 30): one decode step of 4.5 s used to read as
+    0.94 s a step, 60 s of wait, and shed half the queue."""
+    eng = _engine(model, max_queue=256)
+    _prime(eng)
+    ov = eng.scheduler.overload
+    for _ in range(10):
+        ov.observe_decode(36e3)
+    # the block-diffusion cell's: a 36 ms step, prefills never waited for
+    ov.model.decode_step_us, ov.model.prefill_us_per_token = 36e3, 1.0
+    hs = [eng.submit(p, max_new_tokens=2)
+          for p in _prompts(21, [5] * 64)]
+    ov.observe_decode(4.5e6)
+    # a third is the depth of the queue itself, 64 of 0.75 x 256
+    assert ov.pressure(eng.scheduler) == pytest.approx(1 / 3)
+    ov.control(eng.scheduler)
+    assert all(h.status == RequestStatus.QUEUED for h in hs)
+    for _ in range(12):                  # every step that slow: overload
+        ov.observe_decode(4.5e6)
+    assert ov.pressure(eng.scheduler) >= 1.0
+    ov.control(eng.scheduler)
+    assert any(h.status == RequestStatus.SHED for h in hs)
+    eng.close()
+
+
 # -- deadline-aware admission --------------------------------------------
 
 

@@ -184,3 +184,54 @@ def test_fused_linear_ce_lowers_for_tpu():
     export.export(jax.jit(train_ragged), platforms=["tpu"])(
         _aval((256, D), jnp.bfloat16), _aval((D, 50257), jnp.bfloat16),
         _aval((256,), jnp.int32))
+
+
+# the widths the block-diffusion cell serves (SDAR-30B-A3B: 128 experts of
+# 2048 x 768, 32 / 4 heads of 128): the block step's 2048 assignments in
+# tiles of 16 rows, and a 2048-token prefill's 16384 in tiles of 64
+@pytest.mark.parametrize("rows", [2048, 16384], ids=["step", "prefill"])
+def test_moe_gmm_lowers_at_the_served_widths(rows):
+    from paddle_tpu.kernels.pallas import moe_gmm as K
+
+    e, d, f = 128, 2048, 768
+    tm = K.tile_rows(rows, e)
+    m = (-(-rows // tm) + e) * tm
+    avals = [_aval((m, d), jnp.bfloat16), _aval((e, d, f), jnp.bfloat16),
+             _aval((e, d, f), jnp.bfloat16), _aval((e, f, d), jnp.bfloat16),
+             _aval((m // tm,), jnp.int32), _aval((1,), jnp.int32)]
+
+    def fn(x, wg, wu, wd, tile_expert, num_tiles):
+        h = K.moe_gmm_swiglu(x, wg, wu, tile_expert, num_tiles, tm=tm,
+                             interpret=False, tag="_step")
+        return K.moe_gmm(h, wd, tile_expert, num_tiles, tm=tm,
+                         interpret=False, tag="_step")
+
+    assert _lower(fn, *avals) == 2
+
+
+def test_dropless_moe_lowers_with_its_sort_and_scatter():
+    from paddle_tpu.distributed.moe import dropless_moe
+
+    e, d, f = 128, 2048, 768
+    avals = [_aval((256, d), jnp.bfloat16), _aval((d, e), jnp.bfloat16),
+             _aval((e, d, f), jnp.bfloat16), _aval((e, d, f), jnp.bfloat16),
+             _aval((e, f, d), jnp.bfloat16), _aval((256,), jnp.bool_)]
+
+    def fn(x, router, wg, wu, wd, valid):
+        return dropless_moe(x, router, wg, wu, wd, top_k=8, route="pallas",
+                            valid=valid)[:2]
+
+    assert _lower(fn, *avals) == 2
+
+
+@pytest.mark.parametrize("pages", [128, 8], ids=["chunked", "per-page"])
+def test_paged_block_attention_lowers_at_the_served_widths(pages):
+    from paddle_tpu.inference.paged import paged_block_attention
+
+    b, width, hq, hk, d, bs = 64, 4, 32, 4, 128, 16
+    pool = _aval((1 + b * pages, bs, hk, d), jnp.bfloat16)
+    avals = [_aval((b, width, hq, d), jnp.bfloat16), pool, pool,
+             _aval((b, pages), jnp.int32), _aval((b,), jnp.int32)]
+    n = _lower(lambda q, k, v, t, l: paged_block_attention(
+        q, k, v, t, l, kernel_mode="pallas"), *avals)
+    assert n == 1
