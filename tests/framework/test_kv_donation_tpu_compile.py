@@ -93,7 +93,9 @@ def test_the_v5e_compiler_aliases_every_pool_and_copies_none(
     aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
                          header)
     assert len(aliased) == (4 if quantized else 2) * LAYERS, header[:400]
-    pool = r"(?:bf16|s8)\[4097,16,8,128\]"
+    # as the cache holds it, or as the paged kernel is handed it (a
+    # page's 16 x 8 rows dense: the same bytes, so no copy either)
+    pool = r"(?:bf16|s8)\[4097,(?:16,8|128),128\]"
     copies = [ln.strip()[:160] for ln in text.split("\n")
               if re.search(rf"= {pool}\S* copy\(", ln)]
     assert not copies, copies
@@ -168,7 +170,7 @@ def test_the_block_diffusion_programs_alias_every_pool_and_copy_none(
                          header)
     assert len(aliased) == 2 * SDAR_LAYERS, header[:400]
     copies = [ln.strip()[:160] for ln in text.split("\n")
-              if re.search(r"= bf16\[8193,16,4,128\]\S* copy\(", ln)]
+              if re.search(r"= bf16\[8193,(?:16,4|64),128\]\S* copy\(", ln)]
     assert not copies, copies
     # the block attention (the step only) and the two grouped matmuls a
     # layer; a prefill's last layer stops at its keys and values

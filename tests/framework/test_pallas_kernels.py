@@ -106,6 +106,79 @@ def test_pick_chunk_pages_budget():
     assert pick_chunk_pages(64, 512, 32, 256) == 1
     # and the pick never exceeds the table length
     assert pick_chunk_pages(3, 8, 4, 32) <= 3
+    # the benchmark's geometries: dense 32 KiB / 16 KiB pages and the
+    # [rows, keys] score tile leave room for the longest candidate
+    assert pick_chunk_pages(128, 16, 8, 128, 2, rows=32) == 32
+    assert pick_chunk_pages(128, 16, 4, 128, 2, rows=128) == 32
+    # the score tile counts: more query rows a slot, fewer pages a step
+    assert pick_chunk_pages(128, 16, 8, 128, 2, rows=1024) \
+        < pick_chunk_pages(128, 16, 8, 128, 2, rows=32)
+    # so do the pools' bytes: MHA at 32 heads holds 128 KiB pages
+    assert pick_chunk_pages(128, 16, 32, 128, 2, rows=32) == 8
+
+
+# the two serving cells' attention geometries (16-token pages, 128-page
+# tables): Mistral-7B decode (32 query / 8 KV heads of 128) and the SDAR
+# block step (4 x 8 folded rows a KV head, 4 KV heads)
+_GEOMETRIES = {"mistral": (32, 8), "sdar_block": (128, 4)}
+# slot lengths around a page's and a chunk's edges (the default pick is
+# 32 pages = 512 tokens a grid step), an idle slot, a full table; every
+# batch's slots differ
+_LENS = {"page_edges": [0, 1, 15, 16, 17],
+         "chunk_edges": [511, 512, 513, 2048]}
+
+
+@pytest.mark.parametrize("lens", sorted(_LENS))
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+def test_benchmark_geometry_matches_dense(geometry, pool, lens):
+    """The chunked kernel as the cells call it (chunk length picked from
+    the shapes) against the dense reference: bf16 pools with bf16
+    queries, int8 pools with their scale rows."""
+    from paddle_tpu.inference.paged import paged_decode_attention_dense
+    from paddle_tpu.kernels.pallas.paged_attention import (
+        paged_decode_attention_chunked)
+
+    rows, hk = _GEOMETRIES[geometry]
+    q, k, v, tables, lens_j = _case(len(_LENS[lens]), rows, hk, 128, 16,
+                                    128, _LENS[lens])
+    scales, tol = {}, dict(atol=5e-5, rtol=1e-4)
+    if pool == "int8":
+        from paddle_tpu.quantization import quantize_rows
+        k, ks = quantize_rows(k)
+        v, vs = quantize_rows(v)
+        scales = dict(k_scale=ks, v_scale=vs)
+    else:
+        q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+        tol = dict(atol=3e-2, rtol=3e-2)
+    ref = paged_decode_attention_dense(q, k, v, tables, lens_j, **scales)
+    got = paged_decode_attention_chunked(q, k, v, tables, lens_j,
+                                         interpret=True, **scales)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+    idle = [i for i, n in enumerate(_LENS[lens]) if n == 0]
+    assert not np.asarray(got, np.float32)[idle].any()
+
+
+@pytest.mark.parametrize("chunked", [False, True])
+@pytest.mark.parametrize("hq,hk", [(16, 16), (8, 2), (8, 1), (12, 3)],
+                         ids=["mha", "tp_shard", "mqa", "three_kv_heads"])
+def test_head_layouts_match_dense(hq, hk, chunked):
+    """One matmul for all KV heads under a head mask: every query row
+    sums over its own KV head's keys only, whatever the grouping — MHA
+    (a row a head), the 2 KV heads of a tensor-parallel shard, MQA, and
+    a head count that is no power of two."""
+    from paddle_tpu.inference.paged import paged_decode_attention_dense
+    from paddle_tpu.kernels.pallas.paged_attention import (
+        paged_decode_attention_chunked, paged_decode_attention_kernel)
+
+    q, k, v, tables, lens_j = _case(3, hq, hk, 128, 16, 20, [0, 17, 300])
+    ref = paged_decode_attention_dense(q, k, v, tables, lens_j)
+    kernel = paged_decode_attention_chunked if chunked \
+        else paged_decode_attention_kernel
+    got = kernel(q, k, v, tables, lens_j, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=5e-5, rtol=1e-4)
 
 
 def test_quant_matmul_matches_xla_dequant():

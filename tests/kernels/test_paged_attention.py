@@ -15,7 +15,8 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference.paged import paged_decode_attention_dense
 from paddle_tpu.kernels.pallas.paged_attention import (
-    paged_decode_attention_kernel)
+    fold_block_rows, paged_decode_attention_chunked,
+    paged_decode_attention_kernel, unfold_block_rows)
 
 
 def _case(B, HQ, HK, D, BS, MBPS, lens, dtype=jnp.float32, seed=0):
@@ -33,6 +34,17 @@ def _case(B, HQ, HK, D, BS, MBPS, lens, dtype=jnp.float32, seed=0):
         np.asarray(lens, np.int32))
 
 
+# a page a step, and chunks of 3 pages (no table below is a multiple of
+# it, so every slot ends in a chunk that is part dead)
+_KERNELS = {
+    "page": lambda *a, **kw: paged_decode_attention_kernel(
+        *a, interpret=True, **kw),
+    "chunked": lambda *a, **kw: paged_decode_attention_chunked(
+        *a, interpret=True, chunk_pages=3, **kw),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_KERNELS))
 @pytest.mark.parametrize(
     "B,HQ,HK,D,BS,MBPS,lens",
     [
@@ -41,26 +53,55 @@ def _case(B, HQ, HK, D, BS, MBPS, lens, dtype=jnp.float32, seed=0):
         (2, 4, 1, 64, 32, 4, [5, 0]),          # MQA + inactive slot
         (1, 16, 8, 128, 16, 16, [250]),        # long context
         (4, 8, 4, 64, 64, 4, [200, 64, 65, 17]),  # large pages
+        (5, 8, 2, 128, 16, 8, [0, 0, 47, 0, 128]),  # idle slots between
     ],
 )
-def test_kernel_matches_dense(B, HQ, HK, D, BS, MBPS, lens):
+def test_kernel_matches_dense(B, HQ, HK, D, BS, MBPS, lens, kernel):
     q, kp, vp, tbl, sl = _case(B, HQ, HK, D, BS, MBPS, lens)
     dense = paged_decode_attention_dense(q, kp, vp, tbl, sl)
-    kern = paged_decode_attention_kernel(q, kp, vp, tbl, sl,
-                                         interpret=True)
+    kern = _KERNELS[kernel](q, kp, vp, tbl, sl)
     np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
                                atol=5e-5, rtol=1e-4)
 
 
-def test_kernel_bf16():
+@pytest.mark.parametrize("kernel", sorted(_KERNELS))
+def test_kernel_bf16(kernel):
     q, kp, vp, tbl, sl = _case(2, 8, 4, 128, 16, 4, [17, 33],
                                dtype=jnp.bfloat16)
     dense = paged_decode_attention_dense(q, kp, vp, tbl, sl)
-    kern = paged_decode_attention_kernel(q, kp, vp, tbl, sl,
-                                         interpret=True)
+    kern = _KERNELS[kernel](q, kp, vp, tbl, sl)
     np.testing.assert_allclose(
         np.asarray(kern, np.float32), np.asarray(dense, np.float32),
         atol=3e-2, rtol=3e-2)
+
+
+def test_fold_block_rows_round_trip():
+    """A block of L = 4 query rows a slot joins the GQA group and comes
+    back: fold and unfold are inverses, a KV head's L x g rows are
+    contiguous, and the kernel on the folded rows is the dense reference
+    row by row."""
+    B, L, HQ, HK, D = 2, 4, 8, 2, 128
+    q, kp, vp, tbl, sl = _case(B, HQ, HK, D, 16, 8, [37, 128])
+    q4 = jnp.asarray(np.random.RandomState(1).randn(B, L, HQ, D),
+                     jnp.float32)
+    folded = fold_block_rows(q4, HK)
+    assert folded.shape == (B, L * HQ, D)
+    np.testing.assert_array_equal(
+        np.asarray(unfold_block_rows(folded, L, HK)), np.asarray(q4))
+    g = HQ // HK
+    # row (h, l, j) of the fold is query head h * g + j of block row l
+    np.testing.assert_array_equal(
+        np.asarray(folded).reshape(B, HK, L, g, D)[:, 1, 2, 0],
+        np.asarray(q4)[:, 2, g])
+    got = unfold_block_rows(paged_decode_attention_chunked(
+        folded, kp, vp, tbl, sl, interpret=True, name="paged_block"),
+        L, HK)
+    for l in range(L):
+        np.testing.assert_allclose(
+            np.asarray(got[:, l]),
+            np.asarray(paged_decode_attention_dense(q4[:, l], kp, vp,
+                                                    tbl, sl)),
+            atol=5e-5, rtol=1e-4)
 
 
 def test_kernel_custom_scale():
