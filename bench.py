@@ -765,7 +765,7 @@ def bench_paged_ab(batch=4, context=2048, heads=32, kv_heads=32,
     lens = jnp.full((batch,), context - 7, jnp.int32)
 
     t_kernel = _scan_timed(
-        lambda qq, *a: paged_decode_attention(qq, *a, use_kernel=True),
+        lambda qq, *a: paged_decode_attention(qq, *a, kernel_mode="pallas"),
         (q, kp, vp, tbl, lens), iters)
     t_dense = _scan_timed(
         lambda qq, *a: paged_decode_attention_dense(qq, *a),

@@ -881,94 +881,75 @@ _block_copy.__name__ = _block_copy.__qualname__ = "kv_block_copy"
 _kv_block_copy = jax.jit(_block_copy, donate_argnums=(0,))
 
 
-def paged_prefill_write(k_pool, v_pool, block_row, k_new, v_new):
-    """Write a prompt's KV [S, Hk, D] into the pool blocks listed in
-    `block_row` [max_blocks_per_seq]. S is padded to a block multiple by
-    the caller. Functional: returns the pools with the rows set — a
-    serving program that was handed the pools donated writes them in
-    place; called eagerly (the tests' reference) each result is a new
-    pool."""
-    s = k_new.shape[0]
-    bs = k_pool.shape[1]
-    nb = s // bs
-    kb = k_new.reshape(nb, bs, *k_new.shape[1:]).astype(k_pool.dtype)
-    vb = v_new.reshape(nb, bs, *v_new.shape[1:]).astype(v_pool.dtype)
-    blocks = block_row[:nb]
-    return k_pool.at[blocks].set(kb), v_pool.at[blocks].set(vb)
-
-
-def paged_prefill_write_q(k_pool, v_pool, k_scale, v_scale, block_row,
-                          k_new, v_new):
-    """Quantized :func:`paged_prefill_write`: rows quantize per
-    (position, kv-head) with the absmax formula
-    (``quantization.quantize_rows``) before landing; scales land in
-    the per-block scale arrays. Returns (k_pool, v_pool, k_scale,
-    v_scale)."""
+def _pools_and_rows(k_pool, v_pool, k_new, v_new, k_scale, v_scale):
+    """What a write sets, and with what: a full-precision cache's two
+    pools take the rows as they are; an int8 cache (its scale arrays
+    passed) takes them quantized per (row, kv-head) by the absmax
+    formula (``quantization.quantize_rows``), and its scale arrays take
+    the scales — THE quantization point of the int8 KV tier."""
+    if k_scale is None:
+        return (k_pool, v_pool), (k_new, v_new)
     from ..quantization import quantize_rows
-    s = k_new.shape[0]
-    bs = k_pool.shape[1]
-    nb = s // bs
     kq, ks = quantize_rows(k_new)
     vq, vs = quantize_rows(v_new)
-    kb = kq.reshape(nb, bs, *kq.shape[1:])
-    vb = vq.reshape(nb, bs, *vq.shape[1:])
-    ksb = ks.reshape(nb, bs, -1)
-    vsb = vs.reshape(nb, bs, -1)
+    return (k_pool, v_pool, k_scale, v_scale), (kq, vq, ks, vs)
+
+
+def paged_prefill_write(k_pool, v_pool, block_row, k_new, v_new,
+                        k_scale=None, v_scale=None):
+    """Write a prompt's KV [S, Hk, D] into the pool blocks listed in
+    `block_row` [max_blocks_per_seq], whole pages at a time. S is padded
+    to a block multiple by the caller. Functional: returns the pools
+    (then an int8 cache's scale arrays, when passed) with the rows set —
+    a serving program that was handed them donated writes them in
+    place; called eagerly (the tests' reference) each result is a new
+    array."""
+    bs = k_pool.shape[1]
+    nb = k_new.shape[0] // bs
+    pools, rows = _pools_and_rows(k_pool, v_pool, k_new, v_new, k_scale,
+                                  v_scale)
+    pages = [r.reshape(nb, bs, *r.shape[1:]).astype(p.dtype)
+             for p, r in zip(pools, rows)]
     blocks = block_row[:nb]
-    return (k_pool.at[blocks].set(kb), v_pool.at[blocks].set(vb),
-            k_scale.at[blocks].set(ksb), v_scale.at[blocks].set(vsb))
+    return tuple(p.at[blocks].set(pg) for p, pg in zip(pools, pages))
+
+
+def _write_rows(k_pool, v_pool, blocks, offs, valid, k_new, v_new,
+                k_scale, v_scale):
+    """The masked row scatter behind every row-by-row write: row ``i``
+    of ``k_new``/``v_new`` [N, Hk, D] lands at ``[blocks[i], offs[i]]``
+    where ``valid[i]``; the caller pointed every other row at the null
+    block's row 0, which keeps what it holds. Returns the pools, then
+    the scale arrays of an int8 cache."""
+    pools, rows = _pools_and_rows(k_pool, v_pool, k_new, v_new, k_scale,
+                                  v_scale)
+
+    def put(pool, new):
+        keep = valid[(slice(None),) + (None,) * (new.ndim - 1)]
+        return pool.at[blocks, offs].set(
+            jnp.where(keep, new.astype(pool.dtype), pool[blocks, offs]))
+
+    return tuple(put(p, r) for p, r in zip(pools, rows))
 
 
 def paged_prefill_write_masked(k_pool, v_pool, block_row, k_new, v_new,
-                               start, write_start, total_len):
+                               start, write_start, total_len,
+                               k_scale=None, v_scale=None):
     """Write a prefill TAIL's KV into the pool: ``k_new``/``v_new``
     [S, Hk, D] hold positions ``start .. start+S-1``; only positions in
     ``[write_start, total_len)`` actually land (shared prefix rows and
     bucket padding are masked to the null block 0 — padding must never
     poison cached content). All operands static-shaped; start/
-    write_start/total_len are traced scalars."""
-    s = k_new.shape[0]
+    write_start/total_len are traced scalars. An int8 cache passes its
+    scale arrays and gets them back behind the pools."""
     bs = k_pool.shape[1]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
+    pos = start + jnp.arange(k_new.shape[0], dtype=jnp.int32)
     valid = (pos >= write_start) & (pos < total_len)
     b_idx = jnp.where(valid, pos // bs, 0)
     blocks = jnp.where(valid, block_row[b_idx], 0)
     offs = jnp.where(valid, pos % bs, 0)
-    k_pool = k_pool.at[blocks, offs].set(
-        jnp.where(valid[:, None, None], k_new.astype(k_pool.dtype),
-                  k_pool[blocks, offs]))
-    v_pool = v_pool.at[blocks, offs].set(
-        jnp.where(valid[:, None, None], v_new.astype(v_pool.dtype),
-                  v_pool[blocks, offs]))
-    return k_pool, v_pool
-
-
-def paged_prefill_write_masked_q(k_pool, v_pool, k_scale, v_scale,
-                                 block_row, k_new, v_new, start,
-                                 write_start, total_len):
-    """Quantized :func:`paged_prefill_write_masked`: the same validity
-    masking (shared prefix rows and bucket padding go to the null
-    block), rows quantized per (position, kv-head) on the way in.
-    Returns (k_pool, v_pool, k_scale, v_scale)."""
-    from ..quantization import quantize_rows
-    s = k_new.shape[0]
-    bs = k_pool.shape[1]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    valid = (pos >= write_start) & (pos < total_len)
-    b_idx = jnp.where(valid, pos // bs, 0)
-    blocks = jnp.where(valid, block_row[b_idx], 0)
-    offs = jnp.where(valid, pos % bs, 0)
-    kq, ks = quantize_rows(k_new)
-    vq, vs = quantize_rows(v_new)
-    k_pool = k_pool.at[blocks, offs].set(
-        jnp.where(valid[:, None, None], kq, k_pool[blocks, offs]))
-    v_pool = v_pool.at[blocks, offs].set(
-        jnp.where(valid[:, None, None], vq, v_pool[blocks, offs]))
-    k_scale = k_scale.at[blocks, offs].set(
-        jnp.where(valid[:, None], ks, k_scale[blocks, offs]))
-    v_scale = v_scale.at[blocks, offs].set(
-        jnp.where(valid[:, None], vs, v_scale[blocks, offs]))
-    return k_pool, v_pool, k_scale, v_scale
+    return _write_rows(k_pool, v_pool, blocks, offs, valid, k_new, v_new,
+                       k_scale, v_scale)
 
 
 def _gather_kv(pool, index, scale, dtype):
@@ -1025,61 +1006,30 @@ def paged_prefix_attention_dense(q, k_pool, v_pool, block_row, q_start,
 
 
 def paged_decode_write(k_pool, v_pool, block_tables, positions, k_new,
-                       v_new, active):
+                       v_new, active, k_scale=None, v_scale=None):
     """Scatter one new token's KV per slot: k_new/v_new [B, Hk, D] at
     `positions` [B] (the token's index). Inactive slots write to the null
-    block 0 slot 0 — harmless, masked everywhere."""
+    block 0 slot 0 — harmless, masked everywhere. An int8 cache passes
+    its scale arrays and gets them back behind the pools."""
     bs = k_pool.shape[1]
     b_idx = positions // bs
     offs = positions % bs
     rows = jnp.arange(block_tables.shape[0], dtype=jnp.int32)
     blocks = jnp.where(active, block_tables[rows, b_idx], 0)
     offs = jnp.where(active, offs, 0)
-    k_pool = k_pool.at[blocks, offs].set(
-        jnp.where(active[:, None, None], k_new.astype(k_pool.dtype),
-                  k_pool[blocks, offs]))
-    v_pool = v_pool.at[blocks, offs].set(
-        jnp.where(active[:, None, None], v_new.astype(v_pool.dtype),
-                  v_pool[blocks, offs]))
-    return k_pool, v_pool
-
-
-def paged_decode_write_q(k_pool, v_pool, k_scale, v_scale, block_tables,
-                         positions, k_new, v_new, active):
-    """Quantized :func:`paged_decode_write`: one row per slot, scale
-    per (slot, kv-head), inactive slots to the null block. Returns
-    (k_pool, v_pool, k_scale, v_scale)."""
-    from ..quantization import quantize_rows
-    bs = k_pool.shape[1]
-    b_idx = positions // bs
-    offs = positions % bs
-    rows = jnp.arange(block_tables.shape[0], dtype=jnp.int32)
-    blocks = jnp.where(active, block_tables[rows, b_idx], 0)
-    offs = jnp.where(active, offs, 0)
-    kq, ks = quantize_rows(k_new)
-    vq, vs = quantize_rows(v_new)
-    k_pool = k_pool.at[blocks, offs].set(
-        jnp.where(active[:, None, None], kq, k_pool[blocks, offs]))
-    v_pool = v_pool.at[blocks, offs].set(
-        jnp.where(active[:, None, None], vq, v_pool[blocks, offs]))
-    k_scale = k_scale.at[blocks, offs].set(
-        jnp.where(active[:, None], ks, k_scale[blocks, offs]))
-    v_scale = v_scale.at[blocks, offs].set(
-        jnp.where(active[:, None], vs, v_scale[blocks, offs]))
-    return k_pool, v_pool, k_scale, v_scale
+    return _write_rows(k_pool, v_pool, blocks, offs, active, k_new, v_new,
+                       k_scale, v_scale)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
-                           scale=None, use_kernel=None, k_scale=None,
-                           v_scale=None, kernel_mode=None,
-                           kernel_name="paged_decode"):
+                           scale=None, k_scale=None, v_scale=None,
+                           kernel_mode=None, kernel_name="paged_decode"):
     """Masked decode attention over the paged cache — THE kernel
     routing point (docs/PERF.md "Pallas serving-kernel tier").
 
     q [B, Hq, D] (one query token per slot); returns [B, Hq, D].
     Routing (``kernel_mode``: the engine's construction-resolved
-    ``FLAGS_paged_kernel``; the legacy ``use_kernel`` bool maps to
-    pallas/dense): ``auto`` takes the fused Pallas kernel on TPU —
+    ``FLAGS_paged_kernel``): ``auto`` takes the fused Pallas kernel on TPU —
     full-precision AND int8 pools (the kernel carries the scale rows
     and dequantizes in VMEM), the chunked flash-decode variant past
     ``_CHUNK_MIN_PAGES`` — and the dense XLA reference below on CPU;
@@ -1090,8 +1040,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
     (tools/kernel_gate.py pins movement and silence). ``kernel_name``
     is the Pallas call's name in a device trace.
     """
-    if kernel_mode is None and use_kernel is not None:
-        kernel_mode = "pallas" if use_kernel else "dense"
     mode = resolve_paged_kernel(kernel_mode)
     if mode == "dense" or (k_scale is None) != (v_scale is None):
         # forced dense: the pre-kernel path, byte-for-byte, before any
@@ -1243,37 +1191,10 @@ def paged_spec_write(k_pool, v_pool, block_tables, start_lens, k_new,
     rows = jnp.arange(b, dtype=jnp.int32)[:, None]
     blocks = jnp.where(valid, block_tables[rows, b_idx], 0)
     offs = jnp.where(valid, pos % bs, 0)
-    blocks_f = blocks.reshape(-1)
-    offs_f = offs.reshape(-1)
-    valid_f = valid.reshape(-1)
-    if k_scale is not None:
-        from ..quantization import quantize_rows
-        kq, ks = quantize_rows(k_new)
-        vq, vs = quantize_rows(v_new)
-        kf = kq.reshape(b * s, *kq.shape[2:])
-        vf = vq.reshape(b * s, *vq.shape[2:])
-        ksf = ks.reshape(b * s, -1)
-        vsf = vs.reshape(b * s, -1)
-        k_pool = k_pool.at[blocks_f, offs_f].set(
-            jnp.where(valid_f[:, None, None], kf,
-                      k_pool[blocks_f, offs_f]))
-        v_pool = v_pool.at[blocks_f, offs_f].set(
-            jnp.where(valid_f[:, None, None], vf,
-                      v_pool[blocks_f, offs_f]))
-        k_scale = k_scale.at[blocks_f, offs_f].set(
-            jnp.where(valid_f[:, None], ksf,
-                      k_scale[blocks_f, offs_f]))
-        v_scale = v_scale.at[blocks_f, offs_f].set(
-            jnp.where(valid_f[:, None], vsf,
-                      v_scale[blocks_f, offs_f]))
-        return k_pool, v_pool, k_scale, v_scale
-    kf = k_new.reshape(b * s, *k_new.shape[2:]).astype(k_pool.dtype)
-    vf = v_new.reshape(b * s, *v_new.shape[2:]).astype(v_pool.dtype)
-    k_pool = k_pool.at[blocks_f, offs_f].set(
-        jnp.where(valid_f[:, None, None], kf, k_pool[blocks_f, offs_f]))
-    v_pool = v_pool.at[blocks_f, offs_f].set(
-        jnp.where(valid_f[:, None, None], vf, v_pool[blocks_f, offs_f]))
-    return k_pool, v_pool
+    return _write_rows(
+        k_pool, v_pool, blocks.reshape(-1), offs.reshape(-1),
+        valid.reshape(-1), k_new.reshape(b * s, *k_new.shape[2:]),
+        v_new.reshape(b * s, *v_new.shape[2:]), k_scale, v_scale)
 
 
 def paged_spec_attention_dense(q, k_pool, v_pool, block_tables,

@@ -10,7 +10,9 @@ elementwise. No KV-cache branching in the training path.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import threading
 
 import jax
@@ -38,18 +40,12 @@ def _aot_wrap(jitted, tag):
     return wrap(jitted, tag)
 
 
-def _named_jit(fn, name, donate_argnums=()):
-    """``jax.jit(fn)`` as the program ``jit_<name>``: the name a profiler
-    trace shows on the device's ``XLA Modules`` line and the host's
-    ``PjitFunction(<name>)`` events, so busy time splits by program
-    (``benchmarks/span_reduce.py``). Every serving closure is called
-    ``fn`` where it is written. ``donate_argnums`` names the KV pools
-    (and int8 scale arrays) of a program that writes them: it takes
-    their buffers and writes in place, and the caller rebinds what comes
-    back (``PagedKVCache.rebind_pools``) before anything reads the
-    cache."""
-    fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn, donate_argnums=donate_argnums)
+def _layer_scales(k_scales, v_scales, i):
+    """Layer ``i``'s scale arrays as the keywords the cache's write and
+    attention functions take them by: none for a full-precision cache,
+    whose lists are empty."""
+    return {"k_scale": k_scales[i], "v_scale": v_scales[i]} \
+        if k_scales else {}
 
 
 @dataclasses.dataclass
@@ -174,17 +170,26 @@ class LlamaAttention(nn.Layer):
         self.o_proj = nn.Linear(q_out, d, weight_attr=_normal_attr(std),
                                 bias_attr=False)
 
+    def qkv(self, h, position_offset=0):
+        """q [b, s, heads, hd], k, v [b, s, kv heads, hd] of the normed
+        hidden ``h``, q and k with rotary positions from
+        ``position_offset`` (a scalar, or one offset a row of the
+        batch)."""
+        b, s, _ = h.shape
+        q = self.q_proj(h).reshape([b, s, self.num_heads, self.head_dim])
+        k = self.k_proj(h).reshape([b, s, self.num_kv_heads,
+                                    self.head_dim])
+        v = self.v_proj(h).reshape([b, s, self.num_kv_heads,
+                                    self.head_dim])
+        q, k = apply_rope(q, k, theta=self.rope_theta,
+                          position_offset=position_offset)
+        return q, k, v
+
     def forward(self, x, cache=None, position_offset=0, kv_sink=None):
         from .. import ops
         b, s, _ = x.shape
         d = self.num_heads * self.head_dim
-        q = ops.reshape(self.q_proj(x), [b, s, self.num_heads, self.head_dim])
-        k = ops.reshape(self.k_proj(x),
-                        [b, s, self.num_kv_heads, self.head_dim])
-        v = ops.reshape(self.v_proj(x),
-                        [b, s, self.num_kv_heads, self.head_dim])
-        q, k = apply_rope(q, k, theta=self.rope_theta,
-                          position_offset=position_offset)
+        q, k, v = self.qkv(x, position_offset)
         if kv_sink is not None:  # paged prefill captures post-rope KV
             kv_sink.append((k, v))
         if cache is None:
@@ -266,9 +271,153 @@ class LlamaBlock(nn.Layer):
 
 class PagedServingModel(nn.Layer):
     """What every model served through ``ServingEngine`` shares: its
-    parameters handed to the jitted ``paged_*`` programs as arguments and
-    rebound after the trace, the lock that serializes those calls, and
-    the AOT-cache tag that folds the serving mesh in."""
+    serving programs in one dict, the one way they are built, named and
+    called on a ``PagedKVCache`` (``_paged_call``), the decoder stack
+    over the paged cache, and the AOT-cache tag that folds the serving
+    mesh in.
+
+    A serving program is ``program(param_arrays, *head, k_pools,
+    v_pools, k_scales, v_scales, *tail) -> (*outputs, k_pools, v_pools,
+    k_scales, v_scales)``: the cache's device state in the middle as the
+    cache holds it (the scale lists empty for a full-precision cache),
+    donated together and returned written in place. ``_build_<job>(
+    quantized, mode)`` builds the program of a job."""
+
+    @property
+    def paged_programs(self):
+        """The serving programs built so far, keyed ``(job, quantized,
+        kernel mode)``; the mode is None where a job's program does not
+        depend on it. Cleared when the serving mesh changes, so that
+        programs re-lower against the new shardings."""
+        return self.__dict__.setdefault("_paged_programs", {})
+
+    def serving_program(self, job, quantized=False, mode=None):
+        """The program of ``job`` for an int8 (``quantized``) or a
+        full-precision cache, built on first use."""
+        key = (job, bool(quantized), mode)
+        programs = self.paged_programs
+        if key not in programs:
+            programs[key] = getattr(self, "_build_" + job)(*key[1:])
+        return programs[key]
+
+    def _as_program(self, body, tag, pools_at, quantized=False,
+                    mode=None):
+        """``body(*head, k_pools, v_pools, k_scales, v_scales, *tail)``
+        as the serving program ``jit_<tag, dots as underscores>[_q8]``,
+        the name a profiler trace shows on the device's ``XLA Modules``
+        line and the host's ``PjitFunction(<name>)`` events, so busy
+        time splits by program (``benchmarks/span_reduce.py``). The
+        parameters are its first argument (bound into the module for the
+        trace); the four lists from argument ``pools_at`` are donated: it
+        takes their buffers and writes in place, and the caller rebinds
+        what comes back before anything reads the cache. AOT tag
+        ``<tag>[.q8][.k-<mode>][.mesh<spec>]``."""
+        rebind = self._param_rebind()
+
+        def fn(param_arrays, *args):
+            from ..core.autograd import no_grad
+            rebind(param_arrays)
+            with no_grad():
+                return body(*args)
+
+        if quantized:
+            tag += ".q8"
+        fn.__name__ = fn.__qualname__ = tag.replace(".", "_")
+        if mode not in (None, "auto"):
+            tag += f".k-{mode}"
+        return _aot_wrap(
+            jax.jit(fn, donate_argnums=tuple(range(pools_at,
+                                                   pools_at + 4))),
+            self._aot_tag(tag))
+
+    def paged_call_args(self, cache, job, head, tail=(), mode=None):
+        """``(program, args)``: the program of ``job`` for ``cache`` and
+        the arguments a ``paged_*`` entry point calls it with — the
+        parameters, ``head``, the cache's pools and scale arrays,
+        ``tail``. For whoever lowers or runs the program beside the
+        entry point; ``_paged_call`` is this plus the call."""
+        return self.serving_program(job, cache.quantized, mode), (
+            self._param_arrays(), *head, cache.k_pools, cache.v_pools,
+            cache.k_scales or [], cache.v_scales or [], *tail)
+
+    @contextlib.contextmanager
+    def _paged_call(self, cache, job, mode=None):
+        """THE call protocol of a serving program. Holds the model's lock
+        and the cache's: nobody reads the cache between the dispatch,
+        which deletes the pools it is handed, and the rebind of those it
+        returns. Yields ``(call, rebind)``: ``call(head, tail)``
+        dispatches the program and returns its other outputs;
+        ``rebind()`` hands the cache its pools back, for an entry point
+        that times that (the exit does it otherwise)."""
+        with self._paged_lock(), cache.pool_lock:
+            returned = []
+
+            def call(head, tail=()):
+                program, args = self.paged_call_args(cache, job, head,
+                                                     tail, mode)
+                try:
+                    *out, k, v, ks, vs = program(*args)
+                finally:
+                    # tracing left tracers bound into the module's
+                    # parameters; restore
+                    self._param_rebind()(args[0])
+                returned.append((k, v, ks, vs))
+                return out
+
+            def rebind():
+                cache.rebind_pools(*returned.pop())
+
+            try:
+                yield call, rebind
+            finally:
+                if returned:
+                    rebind()
+
+    @staticmethod
+    def _padded(cache, ids, pad_to):
+        """``ids`` as [1, S] int64 zero-padded to whole blocks, or to
+        the bucket ``pad_to`` (serving/bucketing.py) under the slot's
+        cap. Padding beyond a slot's allocated blocks is safe: those
+        table entries are 0, the reserved null block, and everything
+        past the true length is masked."""
+        ids = np.asarray(ids).reshape(-1)
+        bs = cache.block_size
+        spad = -(-ids.shape[0] // bs) * bs
+        if pad_to is not None:
+            cap = cache.max_blocks_per_seq * bs
+            spad = -(-min(max(int(pad_to), spad), cap) // bs) * bs
+        out = np.zeros((1, spad), np.int64)
+        out[0, :ids.shape[0]] = ids
+        return out
+
+    def _paged_stack(self, x, position_offset, pools, write, attend,
+                     mlp=None):
+        """The decoder stack over the paged cache, written once: ``x``
+        [b, s, d] is the embedded input of the positions from
+        ``position_offset``; layer ``i`` hands its post-rope keys and
+        values [b, s, Hk, D] to the job's ``write(k_pool, v_pool, k, v,
+        **scales) -> (k_pool, v_pool, *scales)`` and its queries to
+        ``attend(q, k_pool, v_pool, **scales)`` over what was written
+        (any shape of b x s rows). ``pools`` are the cache's four lists;
+        ``mlp(blk, m)`` stands in for ``blk.mlp(m)``. Returns the final
+        norm's output and the four lists written."""
+        k_pools, v_pools, k_scales, v_scales = pools
+        b, s, _ = x.shape
+        new = ([], [], [], [])
+        for i, blk in enumerate(self.layers):
+            attn = blk.self_attn
+            q, k, v = attn.qkv(blk.input_layernorm(x), position_offset)
+            scales = _layer_scales(k_scales, v_scales, i)
+            layer = write(k_pools[i], v_pools[i], k._data, v._data,
+                          **scales)
+            for pool_list, pool in zip(new, layer):
+                pool_list.append(pool)
+            out = attend(q._data, *layer[:2],
+                         **dict(zip(scales, layer[2:])))
+            x = x + attn.o_proj(Tensor(out.reshape(b, s, -1)))
+            m = blk.post_attention_layernorm(x)
+            x = x + (blk.mlp(m) if mlp is None else mlp(blk, m))
+        return self.norm(x), new
 
     def _param_rebind(self):
         if not hasattr(self, "_pb_names"):
@@ -350,7 +499,6 @@ class Llama(PagedServingModel):
 
     def forward(self, input_ids, caches=None, position_offset=0,
                 kv_sink=None):
-        from .. import ops
         new_caches = None
         if caches is None:
             x = self.forward_hidden(input_ids, kv_sink=kv_sink)
@@ -362,11 +510,7 @@ class Llama(PagedServingModel):
                              position_offset=position_offset)
                 new_caches.append(c)
             x = self.norm(x)
-        if self.lm_head is not None:
-            logits = self.lm_head(x)
-        else:
-            logits = ops.matmul(x, self.embed_tokens.weight,
-                                transpose_y=True)
+        logits = self._logits(x)
         if caches is None:
             return logits
         return logits, new_caches
@@ -391,22 +535,13 @@ class Llama(PagedServingModel):
     # Reference: block_multi_head_attention_kernel.cu (paged cache) +
     # masked_multihead_attention_kernel.cu (decode). See inference/paged.py.
 
-    # every jitted serving entry point this model caches; cleared when
-    # the serving mesh changes so programs re-lower against the new
-    # shardings (and re-fingerprint in the AOT cache under the new tag)
-    _PAGED_JIT_ATTRS = ("_paged_prefill_jit", "_paged_prefill_q8_jit",
-                       "_paged_extend_jit",
-                       "_paged_extend_q8_jit", "_paged_decode_jit",
-                       "_paged_decode_q8_jit", "_paged_spec_jit",
-                       "_paged_spec_q8_jit")
-
     def apply_serving_mesh(self, mesh):
         """Lay the model out for mesh-sharded serving
         (serving/mesh.py; docs/SERVING.md "Mesh-sharded serving"):
         every parameter is ``device_put`` with its ``NamedSharding``
         along the mesh's model axis (column-parallel q/k/v/gate/up,
         row-parallel o/down, everything else replicated) and the
-        cached paged jit entry points drop so they re-lower sharded —
+        serving programs built so far drop so they re-lower sharded —
         their AOT tags fold the mesh shape in (``_aot_tag``), so a
         1x8 executable can never be served from a 1x1 cache entry.
         Idempotent for the same mesh spec; ``mesh=None`` is a no-op
@@ -425,8 +560,33 @@ class Llama(PagedServingModel):
             for n, p in self.named_parameters():
                 p._data = jax.device_put(p._data, mesh.param_sharding(n))
             self.__dict__["_serving_mesh"] = mesh
-            for attr in self._PAGED_JIT_ATTRS:
-                self.__dict__.pop(attr, None)
+            self.paged_programs.clear()
+
+    def _logits(self, hidden):
+        if self.lm_head is not None:
+            return self.lm_head(hidden)
+        from .. import ops
+        return ops.matmul(hidden, self.embed_tokens.weight,
+                          transpose_y=True)
+
+    def _next_token(self, hidden, pick, key=None, temp=None):
+        """The head over the stack's normed output, at the positions
+        ``pick`` takes from the logits [b, s, vocab]: their arg-max, or
+        (``key`` given) a sample at temperature ``temp`` where that is
+        positive."""
+        from .generation import sample_token
+        last = pick(self._logits(hidden)._data)
+
+        def greedy():
+            return jnp.argmax(last, axis=-1).astype(jnp.int32)
+
+        if key is None:
+            return greedy()
+        return jax.lax.cond(
+            temp > 0,
+            lambda: sample_token(last / jnp.maximum(temp, 1e-6),
+                                 temperature=1.0, key=key),
+            greedy)
 
     def paged_prefill(self, cache, slot, prompt_ids, temperature=0.0,
                       pad_to=None):
@@ -437,99 +597,49 @@ class Llama(PagedServingModel):
 
         ``pad_to`` (serving/bucketing.py): pad the prompt to a bucketed
         length instead of the next block multiple, so warm serving traces
-        a bounded set of prefill shapes. Padding beyond the slot's
-        allocated blocks is safe: the extra table entries are 0, the
-        reserved null block, and everything past ``true_len`` is masked.
-        """
+        a bounded set of prefill shapes (``_padded``)."""
         from ..core.random import next_key
 
-        # nobody reads the cache between the dispatch, which deletes the
-        # pools it is handed, and the rebind of those it returns
-        with self._paged_lock(), cache.pool_lock:
+        with self._paged_call(cache, "prefill") as (call, rebind):
             with _phase("serving.prefill.forward"):
-                prompt = np.asarray(prompt_ids).reshape(-1)
-                s = prompt.shape[0]
-                bs = cache.block_size
-                spad = -(-s // bs) * bs
-                if pad_to is not None:
-                    cap = cache.max_blocks_per_seq * bs
-                    want = min(max(int(pad_to), spad), cap)
-                    spad = -(-want // bs) * bs
-                ids = np.zeros((1, spad), np.int64)
-                ids[:, :s] = prompt
-
-                attr = "_paged_prefill_q8_jit" if cache.quantized \
-                    else "_paged_prefill_jit"
-                if getattr(self, attr, None) is None:
-                    setattr(self, attr,
-                            self._build_prefill(cache.quantized))
-                arrs = self._param_arrays()
-                tok, *pools = getattr(self, attr)(
-                    arrs, jnp.asarray(ids), jnp.int32(s),
-                    jnp.asarray(cache.block_tables[slot]),
-                    cache.k_pools, cache.v_pools,
-                    cache.k_scales if cache.quantized else [],
-                    cache.v_scales if cache.quantized else [],
-                    next_key(), jnp.float32(temperature))
-                # tracing left tracers bound into the module params;
-                # restore
-                self._param_rebind()(arrs)
+                s = int(np.asarray(prompt_ids).size)
+                ids = self._padded(cache, prompt_ids, pad_to)
+                tok, = call(
+                    (jnp.asarray(ids), jnp.int32(s),
+                     jnp.asarray(cache.block_tables[slot])),
+                    (next_key(), jnp.float32(temperature)))
             # what is left of the write on the host: the program wrote
             # into the pools it was handed; take them back
             with _phase("serving.prefill.pool_write",
-                        layers=cache.num_layers, tokens=spad):
-                cache.rebind_pools(*pools)
+                        layers=cache.num_layers, tokens=ids.shape[1]):
+                rebind()
                 cache.seq_lens[slot] = s
         with _phase("serving.prefill.readback"):  # waits for the device
             return int(tok)
 
-    def _build_prefill(self, quantized):
+    def _build_prefill(self, quantized, mode):
         """The dense causal prefill program: the prompt's logits at its
         last true position sampled, and every layer's post-rope K and V
-        written into the blocks of the slot's table row ``row`` — the
-        pools (and, ``quantized``, their scale arrays) come in donated
-        and go back out, written in place."""
-        rebind = self._param_rebind()
-
-        def fn(param_arrays, ids_arr, true_len, row, k_pools, v_pools,
-               k_scales, v_scales, key, temp):
-            from ..core.autograd import no_grad
-            from ..inference.paged import (paged_prefill_write,
-                                           paged_prefill_write_q)
-            from .generation import sample_token
-            rebind(param_arrays)
+        written, whole pages at a time, into the blocks of the slot's
+        table row ``row``."""
+        def body(ids_arr, true_len, row, k_pools, v_pools, k_scales,
+                 v_scales, key, temp):
+            from ..inference.paged import paged_prefill_write
             sink = []
-            with no_grad():
-                logits = self.forward(Tensor(ids_arr), kv_sink=sink)
-            last = jnp.take_along_axis(
-                logits._data, (true_len - 1)[None, None, None],
-                axis=1)[:, 0]
-            tok = jax.lax.cond(
-                temp > 0,
-                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                     temperature=1.0, key=key),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            new_k, new_v, new_ks, new_vs = [], [], [], []
+            hidden = self.forward_hidden(Tensor(ids_arr), kv_sink=sink)
+            tok = self._next_token(
+                hidden, lambda logits: jnp.take_along_axis(
+                    logits, (true_len - 1)[None, None, None],
+                    axis=1)[:, 0], key, temp)
+            new = ([], [], [], [])
             for i, (k, v) in enumerate(sink):
-                if quantized:
-                    kp, vp, ksc, vsc = paged_prefill_write_q(
-                        k_pools[i], v_pools[i], k_scales[i], v_scales[i],
-                        row, k._data[0], v._data[0])
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-                else:
-                    kp, vp = paged_prefill_write(
-                        k_pools[i], v_pools[i], row, k._data[0],
-                        v._data[0])
-                new_k.append(kp)
-                new_v.append(vp)
-            return tok[0], new_k, new_v, new_ks, new_vs
-        tag = "llama.paged_prefill.q8" if quantized \
-            else "llama.paged_prefill"
-        return _aot_wrap(
-            _named_jit(fn, tag.replace(".", "_"),
-                       donate_argnums=(4, 5, 6, 7)),
-            self._aot_tag(tag))
+                layer = paged_prefill_write(
+                    k_pools[i], v_pools[i], row, k._data[0], v._data[0],
+                    **_layer_scales(k_scales, v_scales, i))
+                for pool_list, pool in zip(new, layer):
+                    pool_list.append(pool)
+            return (tok[0], *new)
+        return self._as_program(body, "llama.paged_prefill", 4, quantized)
 
     def paged_prefill_extend(self, cache, slot, ids, tail_start,
                              write_start, temperature=0.0, pad_to=None):
@@ -554,166 +664,40 @@ class Llama(PagedServingModel):
         with _phase("serving.prefill.forward"):
             ids = np.asarray(ids).reshape(-1)
             total = ids.shape[0]
-            bs = cache.block_size
-            s_tail = total - tail_start
-            spad = -(-s_tail // bs) * bs
-            if pad_to is not None:
-                cap = cache.max_blocks_per_seq * bs
-                want = min(max(int(pad_to), spad), cap)
-                spad = -(-want // bs) * bs
-            tail = np.zeros((1, spad), np.int64)
-            tail[0, :s_tail] = ids[tail_start:]
-
-            # int8 pools thread their scale arrays through the program
-            # and dequantize at the gathers; its own jit + AOT tag so a
-            # model can serve quantized and full-precision caches side
-            # by side
-            attr = "_paged_extend_q8_jit" if cache.quantized \
-                else "_paged_extend_jit"
-            if getattr(self, attr, None) is None:
-                setattr(self, attr, self._build_extend_q8()
-                        if cache.quantized else self._build_extend())
-            scales = (cache.k_scales, cache.v_scales) \
-                if cache.quantized else ()
-            with self._paged_lock(), cache.pool_lock:
-                arrs = self._param_arrays()
-                tok, *pools = getattr(self, attr)(
-                    arrs, jnp.asarray(tail), jnp.int32(tail_start),
-                    jnp.int32(write_start), jnp.int32(total),
-                    jnp.asarray(cache.block_tables[slot]),
-                    cache.k_pools, cache.v_pools, *scales, next_key(),
-                    jnp.float32(temperature))
-                self._param_rebind()(arrs)
-                cache.rebind_pools(*pools)
+            tail = self._padded(cache, ids[tail_start:], pad_to)
+            with self._paged_call(cache, "extend") as (call, _):
+                tok, = call(
+                    (jnp.asarray(tail), jnp.int32(tail_start),
+                     jnp.int32(write_start), jnp.int32(total),
+                     jnp.asarray(cache.block_tables[slot])),
+                    (next_key(), jnp.float32(temperature)))
             cache.seq_lens[slot] = total
         with _phase("serving.prefill.readback"):  # waits for the device
             return int(tok)
 
-    def _build_extend(self):
-        """The tail-extend program of ``paged_prefill_extend``: the pool
-        write is inside it (``paged_prefill_write_masked``), in place in
-        the donated pools."""
-        rebind = self._param_rebind()
-        cfg = self.config
-        hq = cfg.num_heads
-        hk = cfg.num_kv_heads
-        hd = cfg.head_dim
-
-        def fn(param_arrays, tail_ids, t_start, w_start, t_total,
-               row, k_pools, v_pools, key, temp):
-            from ..core.autograd import no_grad
-            from ..inference.paged import (
-                paged_prefill_write_masked,
-                paged_prefix_attention_dense)
-            from .generation import sample_token
-            rebind(param_arrays)
-            s = tail_ids.shape[1]
-            with no_grad():
-                x = self.embed_tokens(Tensor(tail_ids))
-                new_k, new_v = [], []
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    h = blk.input_layernorm(x)
-                    q = attn.q_proj(h).reshape([1, s, hq, hd])
-                    k = attn.k_proj(h).reshape([1, s, hk, hd])
-                    v = attn.v_proj(h).reshape([1, s, hk, hd])
-                    q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                      position_offset=t_start)
-                    kp, vp = paged_prefill_write_masked(
-                        k_pools[i], v_pools[i], row, k._data[0],
-                        v._data[0], t_start, w_start, t_total)
-                    out = paged_prefix_attention_dense(
-                        q._data[0], kp, vp, row, t_start, t_total)
-                    x = x + attn.o_proj(
-                        Tensor(out.reshape(1, s, hq * hd)))
-                    x = x + blk.mlp(blk.post_attention_layernorm(x))
-                    new_k.append(kp)
-                    new_v.append(vp)
-                x = self.norm(x)
-                if self.lm_head is not None:
-                    logits = self.lm_head(x)
-                else:
-                    from .. import ops
-                    logits = ops.matmul(x, self.embed_tokens.weight,
-                                        transpose_y=True)
-            last = jnp.take_along_axis(
-                logits._data, (t_total - 1 - t_start)[None, None, None],
-                axis=1)[:, 0]
-            tok = jax.lax.cond(
-                temp > 0,
-                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                     temperature=1.0, key=key),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return tok[0], new_k, new_v
-        return _aot_wrap(
-            _named_jit(fn, "llama_paged_extend", donate_argnums=(6, 7)),
-            self._aot_tag("llama.paged_extend"))
-
-    def _build_extend_q8(self):
-        """Quantized twin of the `_paged_extend_jit` program
-        (FLAGS_kv_cache_dtype=int8): identical structure, but tail KV
-        quantizes per (position, kv-head) on write
-        (`paged_prefill_write_masked_q`) and the prefix attention
-        dequantizes in its gather."""
-        rebind = self._param_rebind()
-        cfg = self.config
-        hq = cfg.num_heads
-        hk = cfg.num_kv_heads
-        hd = cfg.head_dim
-
-        def fn(param_arrays, tail_ids, t_start, w_start, t_total, row,
-               k_pools, v_pools, k_scales, v_scales, key, temp):
-            from ..core.autograd import no_grad
-            from ..inference.paged import (paged_prefill_write_masked_q,
+    def _build_extend(self, quantized, mode):
+        """The tail-extend program of ``paged_prefill_extend``: each
+        layer writes the tail row by row
+        (``paged_prefill_write_masked``) and attends it over the slot's
+        whole paged context."""
+        def body(tail_ids, t_start, w_start, t_total, row, k_pools,
+                 v_pools, k_scales, v_scales, key, temp):
+            from ..inference.paged import (paged_prefill_write_masked,
                                            paged_prefix_attention_dense)
-            from .generation import sample_token
-            rebind(param_arrays)
-            s = tail_ids.shape[1]
-            with no_grad():
-                x = self.embed_tokens(Tensor(tail_ids))
-                new_k, new_v, new_ks, new_vs = [], [], [], []
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    h = blk.input_layernorm(x)
-                    q = attn.q_proj(h).reshape([1, s, hq, hd])
-                    k = attn.k_proj(h).reshape([1, s, hk, hd])
-                    v = attn.v_proj(h).reshape([1, s, hk, hd])
-                    q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                      position_offset=t_start)
-                    kp, vp, ksc, vsc = paged_prefill_write_masked_q(
-                        k_pools[i], v_pools[i], k_scales[i],
-                        v_scales[i], row, k._data[0], v._data[0],
-                        t_start, w_start, t_total)
-                    out = paged_prefix_attention_dense(
-                        q._data[0], kp, vp, row, t_start, t_total,
-                        k_scale=ksc, v_scale=vsc)
-                    x = x + attn.o_proj(
-                        Tensor(out.reshape(1, s, hq * hd)))
-                    x = x + blk.mlp(blk.post_attention_layernorm(x))
-                    new_k.append(kp)
-                    new_v.append(vp)
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-                x = self.norm(x)
-                if self.lm_head is not None:
-                    logits = self.lm_head(x)
-                else:
-                    from .. import ops
-                    logits = ops.matmul(x, self.embed_tokens.weight,
-                                        transpose_y=True)
-            last = jnp.take_along_axis(
-                logits._data, (t_total - 1 - t_start)[None, None, None],
-                axis=1)[:, 0]
-            tok = jax.lax.cond(
-                temp > 0,
-                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                     temperature=1.0, key=key),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return tok[0], new_k, new_v, new_ks, new_vs
-        return _aot_wrap(
-            _named_jit(fn, "llama_paged_extend_q8",
-                       donate_argnums=(6, 7, 8, 9)),
-            self._aot_tag("llama.paged_extend.q8"))
+            hidden, new = self._paged_stack(
+                self.embed_tokens(Tensor(tail_ids)), t_start,
+                (k_pools, v_pools, k_scales, v_scales),
+                lambda kp, vp, k, v, **scales: paged_prefill_write_masked(
+                    kp, vp, row, k[0], v[0], t_start, w_start, t_total,
+                    **scales),
+                lambda q, kp, vp, **scales: paged_prefix_attention_dense(
+                    q[0], kp, vp, row, t_start, t_total, **scales))
+            tok = self._next_token(
+                hidden, lambda logits: jnp.take_along_axis(
+                    logits, (t_total - 1 - t_start)[None, None, None],
+                    axis=1)[:, 0], key, temp)
+            return (tok[0], *new)
+        return self._as_program(body, "llama.paged_extend", 6, quantized)
 
     def paged_decode_step(self, cache, last_tokens, active,
                           temperature=0.0, kernel_mode=None):
@@ -724,192 +708,64 @@ class Llama(PagedServingModel):
 
         ``kernel_mode`` is the engine's construction-resolved
         ``FLAGS_paged_kernel`` (auto|pallas|dense) — it picks the
-        attention route inside the traced program, so the decode jits
-        cache PER MODE (engines with different routing can share one
+        attention route inside the traced program, so the decode program
+        is kept PER MODE (engines with different routing can share one
         model without serving each other's programs)."""
         from ..core.random import next_key
         from ..inference.paged import resolve_paged_kernel
 
         mode = resolve_paged_kernel(kernel_mode)
-        attr = "_paged_decode_q8_jit" if cache.quantized \
-            else "_paged_decode_jit"
-        jits = self.__dict__.setdefault(attr, {})
-        if jits.get(mode) is None:
-            jits[mode] = (self._build_decode_q8 if cache.quantized
-                          else self._build_decode)(mode)
-        scales = (cache.k_scales, cache.v_scales) \
-            if cache.quantized else ()
-        with self._paged_lock(), cache.pool_lock:
-            arrs = self._param_arrays()
-            toks, *pools = jits[mode](
-                arrs, jnp.asarray(last_tokens, jnp.int32),
-                cache.k_pools, cache.v_pools, *scales,
-                cache.block_tables, jnp.asarray(cache.seq_lens),
-                jnp.asarray(active), next_key(),
-                jnp.float32(temperature))
-            self._param_rebind()(arrs)
-            cache.rebind_pools(*pools)
+        with self._paged_call(cache, "decode", mode) as (call, _):
+            toks, = call(
+                (jnp.asarray(last_tokens, jnp.int32),),
+                (cache.block_tables, jnp.asarray(cache.seq_lens),
+                 jnp.asarray(active), next_key(),
+                 jnp.float32(temperature)))
         act = np.asarray(active)
         cache.seq_lens = np.where(act, cache.seq_lens + 1,
                                   cache.seq_lens).astype(np.int32)
         return toks
 
-    def _build_decode(self, mode="auto"):
+    def _build_decode(self, quantized, mode):
         """The batched decode program: each live slot's incoming token
-        written into the donated pools (``paged_decode_write``), then
-        attended against them by the route ``mode`` picks."""
-        rebind = self._param_rebind()
-        cfg = self.config
-        hq = cfg.num_heads
-        hk = cfg.num_kv_heads
-        hd = cfg.head_dim
-        # mesh-sharded serving: captured at build time — the jit is
-        # rebuilt (apply_serving_mesh clears it) when the mesh
-        # changes. A model-sharded mesh runs the attention
-        # explicitly sharded per kv-head under shard_map.
+        written into the pools (``paged_decode_write``), then attended
+        against them by the route ``mode`` picks — an int8 pool
+        dequantizes inside the Pallas kernel's VMEM gather on the pallas
+        route, in the dense reference's XLA gather otherwise."""
+        # mesh-sharded serving: captured at build time — the program is
+        # rebuilt (apply_serving_mesh drops it) when the mesh changes.
+        # A model-sharded mesh runs the attention explicitly sharded
+        # per kv-head under shard_map.
         mesh = self.__dict__.get("_serving_mesh")
         use_tp = mesh is not None and mesh.shard_map_armed
 
-        def fn(param_arrays, toks, k_pools, v_pools, tables, lens,
-               active, key, temp):
+        def body(toks, k_pools, v_pools, k_scales, v_scales, tables,
+                 lens, active, key, temp):
             from ..inference.paged import (paged_decode_attention,
                                            paged_decode_attention_tp,
                                            paged_decode_write)
-            from .generation import sample_token
-            from ..core.autograd import no_grad
-            rebind(param_arrays)
-            b = toks.shape[0]
-            with no_grad():
-                x = self.embed_tokens(Tensor(toks[:, None]))
-                new_k, new_v = [], []
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    h = blk.input_layernorm(x)
-                    q = attn.q_proj(h).reshape([b, 1, hq, hd])
-                    k = attn.k_proj(h).reshape([b, 1, hk, hd])
-                    v = attn.v_proj(h).reshape([b, 1, hk, hd])
-                    q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                      position_offset=lens)
-                    kp, vp = paged_decode_write(
-                        k_pools[i], v_pools[i], tables, lens,
-                        k._data[:, 0], v._data[:, 0], active)
-                    if use_tp:
-                        out = paged_decode_attention_tp(
-                            q._data[:, 0], kp, vp, tables,
-                            jnp.where(active, lens + 1, lens), mesh,
-                            kernel_mode=mode)
-                    else:
-                        out = paged_decode_attention(
-                            q._data[:, 0], kp, vp, tables,
-                            jnp.where(active, lens + 1, lens),
-                            kernel_mode=mode)
-                    x = x + attn.o_proj(
-                        Tensor(out.reshape(b, 1, hq * hd)))
-                    x = x + blk.mlp(blk.post_attention_layernorm(x))
-                    new_k.append(kp)
-                    new_v.append(vp)
-                x = self.norm(x)
-                if self.lm_head is not None:
-                    logits = self.lm_head(x)
-                else:
-                    from .. import ops
-                    logits = ops.matmul(x, self.embed_tokens.weight,
-                                        transpose_y=True)
-            last = logits._data[:, 0]
-            nxt = jax.lax.cond(
-                temp > 0,
-                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                     temperature=1.0, key=key),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return nxt, new_k, new_v
-        tag = "llama.paged_decode" + (
-            "" if mode == "auto" else f".k-{mode}")
-        return _aot_wrap(
-            _named_jit(fn, "llama_paged_decode", donate_argnums=(2, 3)),
-            self._aot_tag(tag))
 
-    def _build_decode_q8(self, kernel_mode="auto"):
-        """Quantized twin of the `_paged_decode_jit` program: the
-        incoming token's KV quantizes on write (`paged_decode_write_q`)
-        and the attention dequantizes the int8 pool per routing mode —
-        fused inside the Pallas kernel's VMEM gather on the pallas
-        route, or in the dense reference's XLA gather when
-        ``kernel_mode`` forces dense (or auto resolves there)."""
-        rebind = self._param_rebind()
-        cfg = self.config
-        hq = cfg.num_heads
-        hk = cfg.num_kv_heads
-        hd = cfg.head_dim
-        mesh = self.__dict__.get("_serving_mesh")
-        use_tp = mesh is not None and mesh.shard_map_armed
-
-        def fn(param_arrays, toks, k_pools, v_pools, k_scales, v_scales,
-               tables, lens, active, key, temp):
-            from ..core.autograd import no_grad
-            from ..inference.paged import (paged_decode_attention,
-                                           paged_decode_attention_tp,
-                                           paged_decode_write_q)
-            from .generation import sample_token
-            rebind(param_arrays)
-            b = toks.shape[0]
-            with no_grad():
-                x = self.embed_tokens(Tensor(toks[:, None]))
-                new_k, new_v, new_ks, new_vs = [], [], [], []
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    h = blk.input_layernorm(x)
-                    q = attn.q_proj(h).reshape([b, 1, hq, hd])
-                    k = attn.k_proj(h).reshape([b, 1, hk, hd])
-                    v = attn.v_proj(h).reshape([b, 1, hk, hd])
-                    q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                      position_offset=lens)
-                    kp, vp, ksc, vsc = paged_decode_write_q(
-                        k_pools[i], v_pools[i], k_scales[i],
-                        v_scales[i], tables, lens, k._data[:, 0],
-                        v._data[:, 0], active)
-                    if use_tp:
-                        out = paged_decode_attention_tp(
-                            q._data[:, 0], kp, vp, tables,
-                            jnp.where(active, lens + 1, lens), mesh,
-                            k_scale=ksc, v_scale=vsc,
-                            kernel_mode=kernel_mode)
-                    else:
-                        out = paged_decode_attention(
-                            q._data[:, 0], kp, vp, tables,
-                            jnp.where(active, lens + 1, lens),
-                            k_scale=ksc, v_scale=vsc,
-                            kernel_mode=kernel_mode)
-                    x = x + attn.o_proj(
-                        Tensor(out.reshape(b, 1, hq * hd)))
-                    x = x + blk.mlp(blk.post_attention_layernorm(x))
-                    new_k.append(kp)
-                    new_v.append(vp)
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-                x = self.norm(x)
-                if self.lm_head is not None:
-                    logits = self.lm_head(x)
-                else:
-                    from .. import ops
-                    logits = ops.matmul(x, self.embed_tokens.weight,
-                                        transpose_y=True)
-            last = logits._data[:, 0]
-            nxt = jax.lax.cond(
-                temp > 0,
-                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                     temperature=1.0, key=key),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return nxt, new_k, new_v, new_ks, new_vs
-        tag = "llama.paged_decode.q8" + (
-            "" if kernel_mode == "auto" else f".k-{kernel_mode}")
-        return _aot_wrap(
-            _named_jit(fn, "llama_paged_decode_q8",
-                       donate_argnums=(2, 3, 4, 5)),
-            self._aot_tag(tag))
+            attention = functools.partial(
+                paged_decode_attention_tp, mesh=mesh) if use_tp \
+                else paged_decode_attention
+            hidden, new = self._paged_stack(
+                self.embed_tokens(Tensor(toks[:, None])), lens,
+                (k_pools, v_pools, k_scales, v_scales),
+                lambda kp, vp, k, v, **scales: paged_decode_write(
+                    kp, vp, tables, lens, k[:, 0], v[:, 0], active,
+                    **scales),
+                lambda q, kp, vp, **scales: attention(
+                    q[:, 0], kp, vp, tables,
+                    jnp.where(active, lens + 1, lens), kernel_mode=mode,
+                    **scales))
+            nxt = self._next_token(hidden, lambda logits: logits[:, 0],
+                                   key, temp)
+            return (nxt, *new)
+        return self._as_program(body, "llama.paged_decode", 2, quantized, mode)
 
     # -- self-speculative decode (docs/SERVING.md "Decode speed tiers") --
 
-    def _build_spec_jit(self, quantized):
+    def _build_spec(self, quantized, mode):
         """The speculative VERIFY program: one batched multi-position
         sweep over every live slot. For slot ``b``, input positions
         ``seq_lens[b] + i`` carry ``toks[b, i]`` (the last emitted
@@ -920,65 +776,21 @@ class Llama(PagedServingModel):
         sequential decode would emit after consuming input ``i``.
         Greedy only (the scheduler gates speculation on temperature 0);
         host-side acceptance decides how many rows survive."""
-        rebind = self._param_rebind()
-        cfg = self.config
-        hq = cfg.num_heads
-        hk = cfg.num_kv_heads
-        hd = cfg.head_dim
-
-        def fn(param_arrays, toks, lens, n_inputs, active, tables,
-               k_pools, v_pools, k_scales, v_scales):
-            from ..core.autograd import no_grad
+        def body(toks, lens, n_inputs, active, tables, k_pools, v_pools,
+                 k_scales, v_scales):
             from ..inference.paged import (paged_spec_attention_dense,
                                            paged_spec_write)
-            rebind(param_arrays)
-            b, s = toks.shape
-            with no_grad():
-                x = self.embed_tokens(Tensor(toks))
-                new_k, new_v, new_ks, new_vs = [], [], [], []
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    h = blk.input_layernorm(x)
-                    q = attn.q_proj(h).reshape([b, s, hq, hd])
-                    k = attn.k_proj(h).reshape([b, s, hk, hd])
-                    v = attn.v_proj(h).reshape([b, s, hk, hd])
-                    q, k = apply_rope(q, k, theta=attn.rope_theta,
-                                      position_offset=lens)
-                    if quantized:
-                        kp, vp, ksc, vsc = paged_spec_write(
-                            k_pools[i], v_pools[i], tables, lens,
-                            k._data, v._data, n_inputs, active,
-                            k_scale=k_scales[i], v_scale=v_scales[i])
-                        out = paged_spec_attention_dense(
-                            q._data, kp, vp, tables, lens, active,
-                            k_scale=ksc, v_scale=vsc)
-                        new_ks.append(ksc)
-                        new_vs.append(vsc)
-                    else:
-                        kp, vp = paged_spec_write(
-                            k_pools[i], v_pools[i], tables, lens,
-                            k._data, v._data, n_inputs, active)
-                        out = paged_spec_attention_dense(
-                            q._data, kp, vp, tables, lens, active)
-                    x = x + attn.o_proj(
-                        Tensor(out.reshape(b, s, hq * hd)))
-                    x = x + blk.mlp(blk.post_attention_layernorm(x))
-                    new_k.append(kp)
-                    new_v.append(vp)
-                x = self.norm(x)
-                if self.lm_head is not None:
-                    logits = self.lm_head(x)
-                else:
-                    from .. import ops
-                    logits = ops.matmul(x, self.embed_tokens.weight,
-                                        transpose_y=True)
-            nxt = jnp.argmax(logits._data, axis=-1).astype(jnp.int32)
-            return nxt, new_k, new_v, new_ks, new_vs
-        tag = "llama.paged_spec.q8" if quantized else "llama.paged_spec"
-        return _aot_wrap(
-            _named_jit(fn, tag.replace(".", "_"),
-                       donate_argnums=(6, 7, 8, 9)),
-            self._aot_tag(tag))
+            hidden, new = self._paged_stack(
+                self.embed_tokens(Tensor(toks)), lens,
+                (k_pools, v_pools, k_scales, v_scales),
+                lambda kp, vp, k, v, **scales: paged_spec_write(
+                    kp, vp, tables, lens, k, v, n_inputs, active,
+                    **scales),
+                lambda q, kp, vp, **scales: paged_spec_attention_dense(
+                    q, kp, vp, tables, lens, active, **scales))
+            return (self._next_token(hidden, lambda logits: logits),
+                    *new)
+        return self._as_program(body, "llama.paged_spec", 6, quantized)
 
     def paged_spec_step(self, cache, last_tokens, draft_tokens, n_inputs,
                         active):
@@ -992,25 +804,14 @@ class Llama(PagedServingModel):
         writes. Pools update in place; ``seq_lens`` do NOT advance —
         the caller (scheduler ``_decode_spec``) accepts the longest
         matching prefix and rolls rejected rows back."""
-        attr = "_paged_spec_q8_jit" if cache.quantized \
-            else "_paged_spec_jit"
-        if getattr(self, attr, None) is None:
-            setattr(self, attr, self._build_spec_jit(cache.quantized))
         toks = np.concatenate(
             [np.asarray(last_tokens).reshape(-1, 1),
              np.asarray(draft_tokens)], axis=1)
-        with self._paged_lock(), cache.pool_lock:
-            arrs = self._param_arrays()
-            nxt, *pools = getattr(self, attr)(
-                arrs, jnp.asarray(toks, jnp.int32),
-                jnp.asarray(cache.seq_lens),
-                jnp.asarray(n_inputs, jnp.int32),
-                jnp.asarray(active), cache.block_tables,
-                cache.k_pools, cache.v_pools,
-                cache.k_scales if cache.quantized else [],
-                cache.v_scales if cache.quantized else [])
-            self._param_rebind()(arrs)
-            cache.rebind_pools(*pools)
+        with self._paged_call(cache, "spec") as (call, _):
+            nxt, = call(
+                (jnp.asarray(toks, jnp.int32), jnp.asarray(cache.seq_lens),
+                 jnp.asarray(n_inputs, jnp.int32), jnp.asarray(active),
+                 cache.block_tables))
         return nxt
 
     def forward_hidden(self, input_ids, kv_sink=None):

@@ -39,8 +39,7 @@ from ..core.tensor import Tensor
 from ..distributed.moe import DroplessMoE
 from ..nn import functional as F
 from ..profiler.tracing import phase as _phase
-from .llama import (PagedServingModel, _aot_wrap, _named_jit, _normal_attr,
-                    apply_rope)
+from .llama import PagedServingModel, _normal_attr, apply_rope
 
 __all__ = ["SDAR", "SDARConfig", "block_causal_mask"]
 
@@ -211,24 +210,6 @@ class SDAR(PagedServingModel):
 
     # -- served path: programs over the paged cache ----------------------
 
-    def _jit(self, name, build):
-        """The cached serving program ``name``, built on first use."""
-        jits = self.__dict__.setdefault("_paged_jits", {})
-        if name not in jits:
-            jits[name] = build()
-        return jits[name]
-
-    def _padded(self, cache, ids, pad_to):
-        ids = np.asarray(ids).reshape(-1)
-        bs = cache.block_size
-        spad = -(-ids.shape[0] // bs) * bs
-        if pad_to is not None:
-            cap = cache.max_blocks_per_seq * bs
-            spad = -(-min(max(int(pad_to), spad), cap) // bs) * bs
-        out = np.zeros((1, spad), np.int64)
-        out[0, :ids.shape[0]] = ids
-        return out
-
     def _check_cache(self, cache):
         if cache.quantized:
             raise ValueError(
@@ -261,40 +242,31 @@ class SDAR(PagedServingModel):
         from ..inference.paged import resolve_paged_kernel
         self._check_cache(cache)
         mode = resolve_paged_kernel(kernel_mode)
-        with self._paged_lock(), cache.pool_lock:
+        with self._paged_call(cache, "prefill", mode) as (call, rebind):
             with _phase("serving.prefill.forward"):
                 n = int(np.asarray(prompt_ids).size)
                 ids = self._padded(cache, prompt_ids, pad_to)
-                arrs = self._param_arrays()
-                pools = self._jit(("prefill", mode),
-                                  lambda: self._build_prefill(mode))(
-                    arrs, jnp.asarray(ids), jnp.int32(n),
-                    self._table_row(cache, slot),
-                    cache.k_pools, cache.v_pools)
-                self._param_rebind()(arrs)
+                call((jnp.asarray(ids), jnp.int32(n),
+                      self._table_row(cache, slot)))
             with _phase("serving.prefill.pool_write",
                         layers=cache.num_layers, tokens=ids.shape[1]):
-                cache.rebind_pools(*pools)
+                rebind()
                 cache.seq_lens[slot] = n
 
-    def _build_prefill(self, mode):
-        rebind = self._param_rebind()
+    def _build_prefill(self, quantized, mode):
         block_length = self.config.block_length
 
-        def fn(param_arrays, ids_arr, n, row, k_pools, v_pools):
-            from ..core.autograd import no_grad
+        def body(ids_arr, n, row, k_pools, v_pools, k_scales, v_scales):
             from ..inference.paged import paged_prefill_write_masked
-            rebind(param_arrays)
             s = ids_arr.shape[1]
             pos = jnp.arange(s, dtype=jnp.int32)
             mask = block_causal_mask(pos, pos, block_length)
             sink = []
-            with no_grad():
-                x = self.embed_tokens(Tensor(ids_arr))
-                last = len(self.layers) - 1
-                for i, blk in enumerate(self.layers):
-                    x = self._layer(blk, x, mask, kernel_mode=mode,
-                                    kv_sink=sink, attention=i < last)
+            x = self.embed_tokens(Tensor(ids_arr))
+            last = len(self.layers) - 1
+            for i, blk in enumerate(self.layers):
+                x = self._layer(blk, x, mask, kernel_mode=mode,
+                                kv_sink=sink, attention=i < last)
             new_k, new_v = [], []
             # row by row, the padding to the null block: a scatter of
             # whole [16, 4, 128] pages makes the v5e compiler re-lay the
@@ -306,12 +278,8 @@ class SDAR(PagedServingModel):
                     zero, zero, n)
                 new_k.append(kp)
                 new_v.append(vp)
-            return new_k, new_v
-        tag = "sdar.paged_prefill" + ("" if mode == "auto"
-                                      else f".k-{mode}")
-        return _aot_wrap(
-            _named_jit(fn, "sdar_paged_prefill", donate_argnums=(4, 5)),
-            self._aot_tag(tag))
+            return new_k, new_v, k_scales, v_scales
+        return self._as_program(body, "sdar.paged_prefill", 4, mode=mode)
 
     def paged_prefill_extend(self, cache, slot, ids, tail_start,
                              write_start, temperature=0.0, pad_to=None,
@@ -327,55 +295,43 @@ class SDAR(PagedServingModel):
             ids = np.asarray(ids).reshape(-1)
             total = ids.shape[0]
             tail = self._padded(cache, ids[tail_start:], pad_to)
-            with self._paged_lock(), cache.pool_lock:
-                arrs = self._param_arrays()
-                pools = self._jit(("extend", mode),
-                                  lambda: self._build_extend(mode))(
-                    arrs, jnp.asarray(tail), jnp.int32(tail_start),
-                    jnp.int32(write_start), jnp.int32(total),
-                    self._table_row(cache, slot),
-                    cache.k_pools, cache.v_pools)
-                self._param_rebind()(arrs)
-                cache.rebind_pools(*pools)
+            with self._paged_call(cache, "extend", mode) as (call, _):
+                call((jnp.asarray(tail), jnp.int32(tail_start),
+                      jnp.int32(write_start), jnp.int32(total),
+                      self._table_row(cache, slot)))
             cache.seq_lens[slot] = total
 
-    def _build_extend(self, mode):
-        rebind = self._param_rebind()
+    def _build_extend(self, quantized, mode):
         block_length = self.config.block_length
 
-        def fn(param_arrays, tail_ids, t_start, w_start, t_total, row,
-               k_pools, v_pools):
-            from ..core.autograd import no_grad
+        def body(tail_ids, t_start, w_start, t_total, row, k_pools,
+                 v_pools, k_scales, v_scales):
             from ..inference.paged import (paged_prefill_write_masked,
                                            paged_prefix_attention_dense)
-            rebind(param_arrays)
             new_k, new_v = [], []
-            with no_grad():
-                x = self.embed_tokens(Tensor(tail_ids))
-                last = len(self.layers) - 1
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    q, k, v = attn.qkv(blk.input_layernorm(x),
-                                       position_offset=t_start)
-                    kp, vp = paged_prefill_write_masked(
-                        k_pools[i], v_pools[i], row, k._data[0],
-                        v._data[0], t_start, w_start, t_total)
-                    new_k.append(kp)
-                    new_v.append(vp)
-                    if i == last:
-                        break
-                    out = paged_prefix_attention_dense(
-                        q._data[0], kp, vp, row, t_start, t_total,
-                        block_len=block_length)
-                    h = x + attn.out(Tensor(out[None]))
-                    x = h + blk.mlp(blk.post_attention_layernorm(h),
-                                    kernel_mode=mode)
-            return new_k, new_v
-        tag = "sdar.paged_extend" + ("" if mode == "auto"
-                                     else f".k-{mode}")
-        return _aot_wrap(
-            _named_jit(fn, "sdar_paged_extend", donate_argnums=(6, 7)),
-            self._aot_tag(tag))
+            x = self.embed_tokens(Tensor(tail_ids))
+            last = len(self.layers) - 1
+            # not ``_paged_stack``: the last layer stops at its keys and
+            # values, as the prefill's does
+            for i, blk in enumerate(self.layers):
+                attn = blk.self_attn
+                q, k, v = attn.qkv(blk.input_layernorm(x),
+                                   position_offset=t_start)
+                kp, vp = paged_prefill_write_masked(
+                    k_pools[i], v_pools[i], row, k._data[0],
+                    v._data[0], t_start, w_start, t_total)
+                new_k.append(kp)
+                new_v.append(vp)
+                if i == last:
+                    break
+                out = paged_prefix_attention_dense(
+                    q._data[0], kp, vp, row, t_start, t_total,
+                    block_len=block_length)
+                h = x + attn.out(Tensor(out[None]))
+                x = h + blk.mlp(blk.post_attention_layernorm(h),
+                                kernel_mode=mode)
+            return new_k, new_v, k_scales, v_scales
+        return self._as_program(body, "sdar.paged_extend", 6, mode=mode)
 
     def paged_block_step(self, cache, block_ids, active, kernel_mode=None,
                          moe_sink=None):
@@ -400,15 +356,11 @@ class SDAR(PagedServingModel):
         from ..inference.paged import resolve_paged_kernel
         self._check_cache(cache)
         mode = resolve_paged_kernel(kernel_mode)
-        with self._paged_lock(), cache.pool_lock:
-            arrs = self._param_arrays()
-            packed, moe, *pools = self._jit(
-                ("block_step", mode), lambda: self._build_block_step(mode))(
-                arrs, jnp.asarray(block_ids, jnp.int32), cache.k_pools,
-                cache.v_pools, cache.block_tables,
-                jnp.asarray(cache.seq_lens), jnp.asarray(active))
-            self._param_rebind()(arrs)
-            cache.rebind_pools(*pools)
+        with self._paged_call(cache, "block_step", mode) as (call, _):
+            packed, moe = call(
+                (jnp.asarray(block_ids, jnp.int32),),
+                (cache.block_tables, jnp.asarray(cache.seq_lens),
+                 jnp.asarray(active)))
         # the read-back's transfer queues behind the program now, not
         # when the host comes to ask for it
         packed.copy_to_host_async()
@@ -430,45 +382,36 @@ class SDAR(PagedServingModel):
                 packed[3 * n:].astype(np.int64).reshape(
                     cfg.num_layers, cfg.num_experts))
 
-    def _build_block_step(self, mode):
-        rebind = self._param_rebind()
+    def _build_block_step(self, quantized, mode):
         cfg = self.config
         block_length = cfg.block_length
 
-        def fn(param_arrays, ids, k_pools, v_pools, tables, lens, active):
-            from ..core.autograd import no_grad
+        def body(ids, k_pools, v_pools, k_scales, v_scales, tables, lens,
+                 active):
             from ..inference.paged import (paged_block_attention,
                                            paged_spec_write)
-            rebind(param_arrays)
             b = ids.shape[0]
             whole = jnp.full((b,), block_length, jnp.int32)
             seen = jnp.where(active, lens + block_length, lens)
             counts, routed, moe_in, moe_out = [], [], [], []
-            new_k, new_v = [], []
-            with no_grad():
-                x = self.embed_tokens(Tensor(ids))
-                for i, blk in enumerate(self.layers):
-                    attn = blk.self_attn
-                    q, k, v = attn.qkv(blk.input_layernorm(x),
-                                       position_offset=lens)
-                    kp, vp = paged_spec_write(
-                        k_pools[i], v_pools[i], tables, lens, k._data,
-                        v._data, whole, active)
-                    out = paged_block_attention(
-                        q._data, kp, vp, tables, seen, kernel_mode=mode)
-                    h = x + attn.out(Tensor(out))
-                    m = blk.post_attention_layernorm(h)
-                    y = blk.mlp(m, kernel_mode=mode, counts_sink=counts,
-                                route_sink=routed,
-                                valid=Tensor(jnp.repeat(
-                                    active, block_length)),
-                                kernel_tag="_step")
-                    moe_in.append(m._data.reshape(-1, m.shape[-1]))
-                    moe_out.append(y._data.reshape(-1, y.shape[-1]))
-                    x = h + y
-                    new_k.append(kp)
-                    new_v.append(vp)
-                x = self.norm(x)
+
+            def experts(blk, m):
+                y = blk.mlp(m, kernel_mode=mode, counts_sink=counts,
+                            route_sink=routed,
+                            valid=Tensor(jnp.repeat(active, block_length)),
+                            kernel_tag="_step")
+                moe_in.append(m._data.reshape(-1, m.shape[-1]))
+                moe_out.append(y._data.reshape(-1, y.shape[-1]))
+                return y
+
+            x, new = self._paged_stack(
+                self.embed_tokens(Tensor(ids)), lens,
+                (k_pools, v_pools, k_scales, v_scales),
+                lambda kp, vp, k, v: paged_spec_write(
+                    kp, vp, tables, lens, k, v, whole, active),
+                lambda q, kp, vp: paged_block_attention(
+                    q, kp, vp, tables, seen, kernel_mode=mode),
+                mlp=experts)
             # float32 logits straight off the MXU's accumulator: a
             # bfloat16 round of them would move a probability by 2 %
             logits = jnp.matmul(x._data, self.lm_head.weight._data,
@@ -485,11 +428,8 @@ class SDAR(PagedServingModel):
                    jnp.stack([jnp.stack([w._data for w, _ in routed]),
                               jnp.stack([e._data.astype(f32)
                                          for _, e in routed])]))
-            return packed, moe, new_k, new_v
-        tag = "sdar.block_step" + ("" if mode == "auto" else f".k-{mode}")
-        return _aot_wrap(
-            _named_jit(fn, "sdar_block_step", donate_argnums=(2, 3)),
-            self._aot_tag(tag))
+            return (packed, moe, *new)
+        return self._as_program(body, "sdar.block_step", 2, mode=mode)
 
     def apply_serving_mesh(self, mesh):
         if mesh is not None:

@@ -65,14 +65,12 @@ def _lower(model, program, one_chip, quantized):
     key, temp = s(key.shape, key.dtype), s((), jnp.float32)
     try:
         if program == "decode":
-            build = model._build_decode_q8 if quantized \
-                else model._build_decode
-            return build("pallas")._jitted.lower(
-                params, s((SLOTS,), jnp.int32), pools, pools,
-                *([scales, scales] if quantized else []),
-                s((SLOTS, PAGES), jnp.int32), s((SLOTS,), jnp.int32),
-                s((SLOTS,), jnp.bool_), key, temp)
-        return model._build_prefill(quantized)._jitted.lower(
+            return model.serving_program(
+                "decode", quantized, "pallas")._jitted.lower(
+                params, s((SLOTS,), jnp.int32), pools, pools, scales,
+                scales, s((SLOTS, PAGES), jnp.int32),
+                s((SLOTS,), jnp.int32), s((SLOTS,), jnp.bool_), key, temp)
+        return model.serving_program("prefill", quantized)._jitted.lower(
             params, s((1, 512), jnp.int64), s((), jnp.int32),
             s((PAGES,), jnp.int32), pools, pools, scales, scales, key,
             temp)
@@ -138,17 +136,17 @@ def _lower_sdar(model, program, one_chip):
     params = tuple(s(a.shape, jnp.bfloat16) for a in arrs)
     row, scalar = s((PAGES,), jnp.int32), s((), jnp.int32)
     try:
+        jitted = model.serving_program(program, mode="pallas")._jitted
         if program == "block_step":
-            return model._build_block_step("pallas")._jitted.lower(
-                params, s((SDAR_SLOTS, 4), jnp.int32), pools, pools,
-                s((SDAR_SLOTS, PAGES), jnp.int32),
+            return jitted.lower(
+                params, s((SDAR_SLOTS, 4), jnp.int32), pools, pools, [],
+                [], s((SDAR_SLOTS, PAGES), jnp.int32),
                 s((SDAR_SLOTS,), jnp.int32), s((SDAR_SLOTS,), jnp.bool_))
         if program == "prefill":
-            return model._build_prefill("pallas")._jitted.lower(
-                params, s((1, 512), jnp.int64), scalar, row, pools, pools)
-        return model._build_extend("pallas")._jitted.lower(
-            params, s((1, 512), jnp.int64), scalar, scalar, scalar, row,
-            pools, pools)
+            return jitted.lower(params, s((1, 512), jnp.int64), scalar,
+                                row, pools, pools, [], [])
+        return jitted.lower(params, s((1, 512), jnp.int64), scalar, scalar,
+                            scalar, row, pools, pools, [], [])
     finally:
         model._param_rebind()(arrs)
 

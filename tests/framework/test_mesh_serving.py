@@ -240,15 +240,17 @@ def test_aot_fingerprint_differs_across_mesh_shapes():
 
     m = _model()
     assert m._aot_tag("llama.paged_decode") == "llama.paged_decode"
-    m.__dict__["_paged_decode_jit"] = object()  # a cached program
+    m.paged_programs["decode", False, "auto"] = object()  # built
     m.apply_serving_mesh(ServingMesh(1, 2))
-    # mesh application drops cached programs so they re-lower sharded
-    assert "_paged_decode_jit" not in m.__dict__
+    # mesh application empties the one dict of serving programs so they
+    # re-lower sharded
+    assert m.paged_programs == {}
     t12 = m._aot_tag("llama.paged_decode")
     assert t12 == "llama.paged_decode.mesh1x2"
-    m.__dict__["_paged_decode_jit"] = object()
+    m.paged_programs["decode", False, "auto"] = object()
+    m.paged_programs["prefill", True, None] = object()
     m.apply_serving_mesh(ServingMesh(2, 4))
-    assert "_paged_decode_jit" not in m.__dict__
+    assert m.paged_programs == {}
     t24 = m._aot_tag("llama.paged_decode")
     assert t24 == "llama.paged_decode.mesh2x4"
     # even on identical lowered text the store entries stay disjoint
@@ -397,17 +399,15 @@ def test_sharded_programs_take_the_pools_donated(mixed_base, spec,
     import jax
 
     quantized = kv_dtype is not None
-    program = model.__dict__["_paged_decode_q8_jit" if quantized
-                             else "_paged_decode_jit"]["auto"]
-    scales = (cache.k_scales, cache.v_scales) if quantized else ()
-    arrs = model._param_arrays()
+    assert ("decode", quantized, "auto") in model.paged_programs
+    program, args = model.paged_call_args(
+        cache, "decode", (jnp.zeros((4,), jnp.int32),),
+        (cache.block_tables, jnp.asarray(cache.seq_lens),
+         jnp.ones((4,), bool), jax.random.key(0), jnp.float32(0.0)),
+        mode="auto")
     try:
-        lowered = program._jitted.lower(
-            arrs, jnp.zeros((4,), jnp.int32), cache.k_pools,
-            cache.v_pools, *scales, cache.block_tables,
-            jnp.asarray(cache.seq_lens), jnp.ones((4,), bool),
-            jax.random.key(0), jnp.float32(0.0))
+        lowered = program._jitted.lower(*args)
     finally:
-        model._param_rebind()(arrs)
+        model._param_rebind()(args[0])
     assert_lowered_donates(lowered, (2, 3, 4, 5) if quantized else (2, 3))
     eng.close()

@@ -204,13 +204,13 @@ def _cache(model, kv_dtype, max_batch=3):
 def _eager_prefill(model, cache, slot, prompt, spad):
     """The eager reference of a prefill: the plain forward's post-rope K
     and V of every layer, written by the eager ``paged_prefill_write``
-    (``_q``), one pool at a time as the serving path did before the
-    write moved into the program. Returns the greedy first token."""
+    (an int8 cache's scales passed), one pool at a time as the serving
+    path did before the write moved into the program. Returns the
+    greedy first token."""
     import jax.numpy as jnp
 
     from paddle_tpu.core.autograd import no_grad
-    from paddle_tpu.inference.paged import (paged_prefill_write,
-                                            paged_prefill_write_q)
+    from paddle_tpu.inference.paged import paged_prefill_write
 
     ids = np.zeros((1, spad), np.int64)
     ids[0, :len(prompt)] = prompt
@@ -221,9 +221,10 @@ def _eager_prefill(model, cache, slot, prompt, spad):
     for i, (k, v) in enumerate(sink):
         if cache.quantized:
             (cache.k_pools[i], cache.v_pools[i], cache.k_scales[i],
-             cache.v_scales[i]) = paged_prefill_write_q(
-                cache.k_pools[i], cache.v_pools[i], cache.k_scales[i],
-                cache.v_scales[i], row, k._data[0], v._data[0])
+             cache.v_scales[i]) = paged_prefill_write(
+                cache.k_pools[i], cache.v_pools[i], row, k._data[0],
+                v._data[0], k_scale=cache.k_scales[i],
+                v_scale=cache.v_scales[i])
         else:
             cache.k_pools[i], cache.v_pools[i] = paged_prefill_write(
                 cache.k_pools[i], cache.v_pools[i], row, k._data[0],
@@ -238,8 +239,7 @@ def _assert_decode_wrote_only_its_rows(old, new, cache, lens, active):
     pool is what it was."""
     import jax.numpy as jnp
 
-    from paddle_tpu.inference.paged import (paged_decode_write,
-                                            paged_decode_write_q)
+    from paddle_tpu.inference.paged import paged_decode_write
 
     n = cache.num_layers
     slots = np.arange(cache.max_batch)
@@ -260,10 +260,10 @@ def _assert_decode_wrote_only_its_rows(old, new, cache, lens, active):
                                     new[2 * n + j][blocks, offs],
                                     jnp.float32)
                     for j in (i, n + i)]
-            want = paged_decode_write_q(
-                old[i], old[n + i], old[2 * n + i], old[3 * n + i],
-                jnp.asarray(cache.block_tables), jnp.asarray(lens),
-                rows[0], rows[1], act)
+            want = paged_decode_write(
+                old[i], old[n + i], jnp.asarray(cache.block_tables),
+                jnp.asarray(lens), rows[0], rows[1], act,
+                k_scale=old[2 * n + i], v_scale=old[3 * n + i])
             got = (new[i], new[n + i], new[2 * n + i], new[3 * n + i])
         for g, w in zip(got, want):
             assert np.array_equal(np.asarray(g), np.asarray(w)), i
@@ -308,26 +308,22 @@ def test_decode_steps_write_the_donated_pools_and_nothing_else(
         last[slot] = model.paged_prefill(cache, slot, prompt)
         assert model.paged_prefill(ref, slot, prompt) == last[slot]
     active = np.array([True, True, False])
-    attr = "_paged_decode_q8_jit" if kv_dtype else "_paged_decode_jit"
     ref_last = last.copy()
     for _ in range(4):
         handed_in = cache.pool_arrays()
         toks = np.asarray(model.paged_decode_step(cache, last, active))
         assert all(a.is_deleted() for a in handed_in)
         # the twin leaves ``ref``'s pools alive: old and new side by side
-        twin = undonated_twin(model.__dict__[attr]["auto"])
-        arrs = model._param_arrays()
         old = ref.pool_arrays()
-        scales = (ref.k_scales, ref.v_scales) if ref.quantized else ()
         lens = ref.seq_lens.copy()
+        program, args = model.paged_call_args(
+            ref, "decode", (jnp.asarray(ref_last, jnp.int32),),
+            (ref.block_tables, jnp.asarray(lens), jnp.asarray(active),
+             jax.random.key(0), jnp.float32(0.0)), mode="auto")
         try:
-            ref_toks, *new = twin(
-                arrs, jnp.asarray(ref_last, jnp.int32), ref.k_pools,
-                ref.v_pools, *scales, ref.block_tables,
-                jnp.asarray(lens), jnp.asarray(active),
-                jax.random.key(0), jnp.float32(0.0))
+            ref_toks, *new = undonated_twin(program)(*args)
         finally:
-            model._param_rebind()(arrs)
+            model._param_rebind()(args[0])
         assert not any(a.is_deleted() for a in old)
         ref.rebind_pools(*new)
         ref.seq_lens = np.where(active, lens + 1, lens).astype(np.int32)
@@ -350,23 +346,21 @@ def test_lowered_programs_mark_every_pool_as_donated(model, program):
     quantized = program.endswith("int8")
     cache = _cache(model, "int8" if quantized else None)
     arrs = model._param_arrays()
-    scales = [cache.k_scales, cache.v_scales] if quantized else [[], []]
     key, temp = jax.random.key(0), jnp.float32(0.0)
     try:
         if program.startswith("prefill"):
-            lowered = model._build_prefill(quantized)._jitted.lower(
-                arrs, jnp.zeros((1, 16), jnp.int64), jnp.int32(9),
-                jnp.asarray(cache.block_tables[0]), cache.k_pools,
-                cache.v_pools, *scales, key, temp)
+            jitted, args = model.paged_call_args(
+                cache, "prefill",
+                (jnp.zeros((1, 16), jnp.int64), jnp.int32(9),
+                 jnp.asarray(cache.block_tables[0])), (key, temp))
+            lowered = jitted._jitted.lower(*args)
             donated = (4, 5, 6, 7) if quantized else (4, 5)
         elif program.startswith("decode"):
-            build = model._build_decode_q8 if quantized \
-                else model._build_decode
-            lowered = build("dense")._jitted.lower(
-                arrs, jnp.zeros((3,), jnp.int32), cache.k_pools,
-                cache.v_pools, *(scales if quantized else []),
-                cache.block_tables, jnp.asarray(cache.seq_lens),
-                jnp.ones((3,), bool), key, temp)
+            jitted, args = model.paged_call_args(
+                cache, "decode", (jnp.zeros((3,), jnp.int32),),
+                (cache.block_tables, jnp.asarray(cache.seq_lens),
+                 jnp.ones((3,), bool), key, temp), mode="dense")
+            lowered = jitted._jitted.lower(*args)
             donated = (2, 3, 4, 5) if quantized else (2, 3)
         else:
             lowered = paged._kv_block_copy.lower(

@@ -302,7 +302,7 @@ def test_flag_routes_engine(tiny_llama):
     # counters move at TRACE time (one movement per compiled program) —
     # drop the cached decode programs so this engine's first step
     # retraces and the movement is observable
-    tiny_llama.__dict__.pop("_paged_decode_q8_jit", None)
+    tiny_llama.paged_programs.clear()
     try:
         paddle.set_flags({"FLAGS_paged_kernel": "pallas"})
         eng = tiny_engine(tiny_llama, kv_cache_dtype="int8")

@@ -441,8 +441,7 @@ def test_extend_writes_the_donated_pools_like_the_eager_reference(
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.inference.paged import (paged_prefill_write_masked,
-                                            paged_prefill_write_masked_q)
+    from paddle_tpu.inference.paged import paged_prefill_write_masked
     from paddle_tpu.quantization import dequantize_rows
 
     rng = np.random.default_rng(27)
@@ -461,24 +460,20 @@ def test_extend_writes_the_donated_pools_like_the_eager_reference(
                                      temperature=0.0)
     assert all(a.is_deleted() for a in handed_in)
 
-    program = getattr(model, "_paged_extend_q8_jit" if kv_dtype
-                      else "_paged_extend_jit")
     tail = np.zeros((1, 8), np.int64)
     tail[0, :5] = second[16:]
     row = jnp.asarray(ref.block_tables[slot])
-    scales = (ref.k_scales, ref.v_scales) if kv_dtype else ()
-    args = (jnp.asarray(tail), jnp.int32(16), jnp.int32(16),
-            jnp.int32(21), row, ref.k_pools, ref.v_pools, *scales,
-            jax.random.key(0), jnp.float32(0.0))
-    arrs = model._param_arrays()
+    program, args = model.paged_call_args(
+        ref, "extend",
+        (jnp.asarray(tail), jnp.int32(16), jnp.int32(16), jnp.int32(21),
+         row), (jax.random.key(0), jnp.float32(0.0)))
     old = ref.pool_arrays()
     try:
-        assert_lowered_donates(
-            program._jitted.lower(arrs, *args),
-            (6, 7, 8, 9) if kv_dtype else (6, 7))
-        ref_tok, *new = undonated_twin(program)(arrs, *args)
+        assert_lowered_donates(program._jitted.lower(*args),
+                               (6, 7, 8, 9) if kv_dtype else (6, 7))
+        ref_tok, *new = undonated_twin(program)(*args)
     finally:
-        model._param_rebind()(arrs)
+        model._param_rebind()(args[0])
     assert not any(a.is_deleted() for a in old)
     ref.rebind_pools(*new)
     assert tok == int(ref_tok)
@@ -494,9 +489,10 @@ def test_extend_writes_the_donated_pools_like_the_eager_reference(
             k, v = (dequantize_rows(new[j][blocks, pos % 8],
                                     new[2 * n + j][blocks, pos % 8],
                                     jnp.float32) for j in (i, n + i))
-            want = paged_prefill_write_masked_q(
-                old[i], old[n + i], old[2 * n + i], old[3 * n + i], row,
-                k, v, jnp.int32(16), jnp.int32(16), jnp.int32(21))
+            want = paged_prefill_write_masked(
+                old[i], old[n + i], row, k, v, jnp.int32(16),
+                jnp.int32(16), jnp.int32(21), k_scale=old[2 * n + i],
+                v_scale=old[3 * n + i])
             got = (new[i], new[n + i], new[2 * n + i], new[3 * n + i])
         else:
             want = paged_prefill_write_masked(

@@ -332,6 +332,37 @@ def test_a_llama_whose_head_is_not_hidden_over_heads_serves():
     assert logits[len(prompt) - 1:-1].argmax(-1).tolist() == toks
 
 
+@pytest.mark.parametrize("which, kwargs, want", [
+    ("llama", {}, {("prefill", False, None), ("decode", False, "auto")}),
+    ("llama", {"kv_cache_dtype": "int8", "spec": True},
+     {("prefill", True, None), ("decode", True, "auto"),
+      ("spec", True, None)}),
+    ("sdar", {"paged_kernel": "pallas"},
+     {("prefill", False, "pallas"), ("block_step", False, "pallas")}),
+], ids=["llama", "llama-int8-spec", "sdar"])
+def test_warmup_leaves_one_program_a_job_in_the_models_one_dict(
+        model, which, kwargs, want):
+    """A model holds its serving programs in ``paged_programs``, keyed
+    (job, quantized, kernel mode): ``warmup()`` builds one a job the
+    engine will run, whatever the number of buckets, and a second
+    ``warmup()`` builds none."""
+    if which == "llama":
+        paddle.seed(2)
+        model = Llama(LlamaConfig.tiny())
+        model.eval()
+    before = dict(model.paged_programs)
+    eng = ServingEngine(model, max_batch=2, block_size=8, max_seq_len=32,
+                        bucket_cap=32, temperature=0.0, background=False,
+                        dtype=jnp.float32, **kwargs)
+    n = eng.warmup()
+    assert set(model.paged_programs) - set(before) == want - set(before)
+    assert want <= set(model.paged_programs)
+    built = dict(model.paged_programs)
+    assert eng.warmup() == n
+    assert model.paged_programs == built  # the same program objects
+    eng.close()
+
+
 # -- the dropless expert layer ----------------------------------------------
 
 def _experts(rng, e=8, d=64, f=48):

@@ -311,23 +311,19 @@ def test_verify_sweep_writes_the_donated_pools_like_the_eager_reference(
                                            active))
     assert all(a.is_deleted() for a in handed_in)
 
-    program = getattr(model, "_paged_spec_q8_jit" if kv_dtype
-                      else "_paged_spec_jit")
     toks = np.concatenate([last.reshape(-1, 1), drafts], axis=1)
     lens = jnp.asarray(ref.seq_lens)
-    args = (jnp.asarray(toks, jnp.int32), lens,
-            jnp.asarray(n_inputs, jnp.int32), jnp.asarray(active),
-            ref.block_tables, ref.k_pools, ref.v_pools,
-            ref.k_scales if kv_dtype else [],
-            ref.v_scales if kv_dtype else [])
-    arrs = model._param_arrays()
+    program, args = model.paged_call_args(
+        ref, "spec",
+        (jnp.asarray(toks, jnp.int32), lens,
+         jnp.asarray(n_inputs, jnp.int32), jnp.asarray(active),
+         ref.block_tables))
     old = ref.pool_arrays()
     try:
-        assert_lowered_donates(program._jitted.lower(arrs, *args),
-                               (6, 7, 8, 9))
-        ref_out, *new = undonated_twin(program)(arrs, *args)
+        assert_lowered_donates(program._jitted.lower(*args), (6, 7, 8, 9))
+        ref_out, *new = undonated_twin(program)(*args)
     finally:
-        model._param_rebind()(arrs)
+        model._param_rebind()(args[0])
     assert not any(a.is_deleted() for a in old)
     ref.rebind_pools(*new)
     assert np.array_equal(out[active], np.asarray(ref_out)[active])

@@ -107,8 +107,7 @@ def check_equivalence(model):
 def check_counters(model):
     # counters move at trace time: drop the cached decode programs so
     # the serve retraces and the movement is observable
-    for attr in ("_paged_decode_jit", "_paged_decode_q8_jit"):
-        model.__dict__.pop(attr, None)
+    model.paged_programs.clear()
     before = _kern_counters()
     _serve(model, paged_kernel="pallas", kv_cache_dtype="int8")
     after = _kern_counters()
@@ -149,8 +148,7 @@ def check_forced_off(model):
     # silence requires no retrace on a fresh jit either: clear caches so
     # the forced-dense serve traces its own program and STILL moves
     # nothing
-    for attr in ("_paged_decode_jit", "_paged_decode_q8_jit"):
-        model.__dict__.pop(attr, None)
+    model.paged_programs.clear()
     off = _serve(model, paged_kernel="dense", kv_cache_dtype="int8")
     silent = _kern_counters() == before
     import jax
