@@ -54,7 +54,7 @@ from ..core import resilience
 from ..profiler import metrics as _metrics
 from ..profiler import tracing as _tracing
 from .bucketing import bucket_lengths
-from .scheduler import (AdmissionRejected, HandoffError,
+from .scheduler import (AdmissionRejected, HandoffError, merge_tokens,
                         QueueFullError, RequestStatus, Scheduler)
 
 __all__ = ["ServingEngine", "RequestHandle", "QueueFullError",
@@ -483,10 +483,17 @@ class ServingEngine:
                                         np.int64), active,
                         kernel_mode=kernel_mode)
                 else:
-                    sched.model.paged_decode_step(
-                        cache, np.zeros((cache.max_batch,), np.int64),
-                        active, temperature=sched.temperature,
-                        kernel_mode=kernel_mode)
+                    def step(toks):
+                        return sched.model.paged_decode_step(
+                            cache, toks, active,
+                            temperature=sched.temperature,
+                            kernel_mode=kernel_mode)
+
+                    # as the loop calls it: with tokens from the host,
+                    # with a step's own output still on the device, and
+                    # with that merged with a fresh slot's token
+                    host = np.zeros((cache.max_batch,), np.int64)
+                    step(merge_tokens(step(step(host)), host, active))
 
             # role-specialized warm sets (disaggregated serving):
             # prefill replicas run ONLY the bucket ladder (they never

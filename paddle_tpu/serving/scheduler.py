@@ -17,7 +17,11 @@ around every entry point). Each ``step()`` is one scheduling iteration:
    the budget is charged for the *uncovered* tail only, and the
    prefill runs the tail-extend program (zero FLOPs for covered
    blocks);
-3. **decode** — ONE jitted step for every live slot. Pool exhaustion
+3. **decode** — ONE jitted step for every live slot, dispatched one
+   step ahead: a step's tokens stay on the device as the next step's
+   input, and the host reads and emits them while that next step runs
+   (``_decode``; docs/SERVING.md "The decode loop runs one step
+   ahead"). Pool exhaustion
    preempts the newest-admitted victim (free blocks + requeue at the
    queue front for re-prefill) instead of truncating anyone —
    ``serving.preempt`` counts it, and greedy outputs stay bit-identical
@@ -59,6 +63,7 @@ ladder degrades service gracefully under sustained overload.
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 
@@ -197,6 +202,11 @@ _m_shed = _metrics.counter("serving.shed")
 _m_errors = _metrics.counter("serving.errors")
 _m_cb_errors = _metrics.counter("serving.callback_errors")
 _m_steps = _metrics.counter("serving.steps")
+# decode dispatches made while the step before was still unread on the
+# device, and those made after reading it (the first step after idle or
+# a drain, the speculative and block paths)
+_m_ahead = _metrics.counter("serving.decode.ahead")
+_m_in_order = _metrics.counter("serving.decode.in_order")
 # context tokens (of K and of V) every layer's decode attention read:
 # sum of seq_len + 1 over the live slots of each decode step
 _m_ctx_tokens = _metrics.counter("serving.decode.context_tokens")
@@ -262,6 +272,47 @@ def _saved_s():
         from .aot_cache import thread_saved_seconds
         _aot_saved_s = thread_saved_seconds
     return _aot_saved_s()
+
+
+@functools.cache
+def _token_merge():
+    import jax
+    import jax.numpy as jnp
+
+    def serving_token_merge(prev, host, fresh):
+        return jnp.where(fresh, host, prev)
+    return jax.jit(serving_token_merge)
+
+
+def merge_tokens(prev, host, fresh):
+    """A decode step's token input made on the device: ``prev``, the
+    tokens the step before returned (a device array [max_batch], never
+    read here), with ``host`` put in at the slots ``fresh`` marks — the
+    slots admitted since, whose first token a prefill gave the host."""
+    return _token_merge()(prev, np.asarray(host, np.int32),
+                          np.asarray(fresh, bool))
+
+
+class _Flight:
+    """A batched decode program dispatched and not read yet: its tokens
+    (still a device array), the request each slot ran for, and what
+    timing the read-back needs."""
+
+    __slots__ = ("toks", "reqs", "step_no", "t_ns", "comp_us", "built",
+                 "timed")
+
+    def __init__(self, toks, step_no, t_ns, comp_us, built):
+        self.toks = toks
+        self.reqs = {}  # slot -> request, the plain path's
+        self.step_no = step_no
+        self.t_ns = t_ns  # as the dispatch began
+        self.comp_us = comp_us
+        # the dispatch compiled or loaded its program: read in order, so
+        # that the step that paid for it bills it to its own requests
+        self.built = built
+        # False once a prefill was read back behind it: when its tokens
+        # arrived can no longer be seen
+        self.timed = True
 
 
 class Scheduler:
@@ -419,6 +470,12 @@ class Scheduler:
         self._last_tok = np.zeros((max_batch,), np.int64)
         self._remaining = np.zeros((max_batch,), np.int64)
         self._step_no = 0  # ``serving.steps`` as the running step began
+        # the plain decode path runs one step ahead: the step dispatched
+        # and not read yet, when the host last saw a step's tokens arrive
+        # (perf_counter_ns), and the last step time it could see (us)
+        self._flight = None
+        self._seen_ns = 0
+        self._dec_us = None
         # block-diffusion decoding, per slot: the open block's ids, which
         # of its positions are still masked (state, never read off the
         # ids: a prompt may hold the mask id), how many leading positions
@@ -601,18 +658,25 @@ class Scheduler:
 
     @property
     def has_work(self):
-        return bool(self.queue or self.running)
+        return bool(self.queue or self.running
+                    or self._flight is not None)
 
     def inflight(self):
         """Live (non-terminal) requests: queued + running — the number
-        a drain must let finish (frontend lifecycle, /readyz body)."""
-        return len(self.queue) + len(self.running)
+        a drain must let finish (frontend lifecycle, /readyz body). A
+        decode step still in flight with nobody left running (its slots
+        finished on an EOS the step before) counts as one: a step has
+        yet to read it."""
+        return len(self.queue) + len(self.running) \
+            or int(self._flight is not None)
 
     # -- the scheduling iteration -------------------------------------
 
     def step(self):
         """One iteration: sweep -> admit -> decode. Returns the list of
-        (rid, token) emitted this step (prefill first tokens included)."""
+        (rid, token) emitted this step (prefill first tokens included;
+        on the plain decode path the tokens of the decode step dispatched
+        the step before, which this one read)."""
         # the step's number joins a request's prefill / decode_step
         # span to the phase spans of the step that ran it
         self._step_no = _m_steps.value
@@ -621,7 +685,7 @@ class Scheduler:
             t0 = time.monotonic()
             self.accounting.step_begin()
             with _phase("serving.sweep"):
-                self._sweep()
+                out = self._sweep()
             # overload control (serving/overload.py): pressure ->
             # brownout ladder update -> shed lowest-priority/newest
             # queued requests while over the watermarks — BEFORE
@@ -630,7 +694,7 @@ class Scheduler:
             with _phase("serving.overload"):
                 self.overload.control(self)
             with _phase("serving.admit"):
-                out = self._admit()
+                out += self._admit()
             with _phase("serving.decode"):
                 out += self._decode()
             _m_steps.inc()
@@ -665,11 +729,22 @@ class Scheduler:
             elif req.deadline is not None and req.deadline.expired():
                 self.queue.remove(req)
                 self._expire(req)
+        out = []
         for slot, req in list(self.running.items()):
-            if req.cancel_requested:
+            cancelled = req.cancel_requested
+            if not cancelled and not (req.deadline is not None
+                                      and req.deadline.expired()):
+                continue
+            # the token it has in flight was made before this boundary:
+            # it is delivered first, as the in-order loop had delivered it
+            out += self.land()
+            if req.done:  # that token was its last
+                continue
+            if cancelled:
                 self._finish(req, RequestStatus.CANCELLED)
-            elif req.deadline is not None and req.deadline.expired():
+            else:
                 self._expire(req)
+        return out
 
     def _expire(self, req):
         with _tracing.attach(req.span):  # flight record gets trace_id
@@ -771,6 +846,9 @@ class Scheduler:
             comp0 = _compile_s()  # compile billed to THIS request
             saved0 = _saved_s()   # ...and so are AOT-cache savings
             t_pf = time.perf_counter_ns()
+            # the prefill is dispatched behind the decode step in flight
+            # and waits for what is left of it
+            wait_us = self._left_of_flight(t_pf)
             if self._block_len > 1:
                 tok = None  # a block-diffusion prefill samples nothing
                 pad_to = self._prefill_blocks(req, slot, ids, plan)
@@ -829,8 +907,8 @@ class Scheduler:
                 # the admission model's EWMA sees the COMPILE-FREE cost
                 # per computed token — a cold bucket's compile must not
                 # poison the steady-state service-time estimate
-                self.overload.observe_prefill(pad_to,
-                                              max(pf_us - comp_us, 0.0))
+                self.overload.observe_prefill(
+                    pad_to, max(pf_us - comp_us - wait_us, 0.0))
                 self._last_tok[slot] = tok
                 self._remaining[slot] = \
                     req.max_new_tokens - len(req.generated) - 1
@@ -864,17 +942,27 @@ class Scheduler:
                 return s
         return cands[0]
 
-    def _make_writable(self, grow):
-        """Make each running slot's next ``grow`` positions writable
-        (1: a token; a block's length: an open block, every step): grow
-        tables (cold cached prefixes are LRU-evicted before anything
-        else — eviction always runs before preemption), copy-on-write
-        shared blocks; preempt a victim on true pool exhaustion (never
-        truncate)."""
+    def _runs_next(self, slot):
+        """Whether the slot is in the next decode step: not when the one
+        token it has left is the one in flight."""
+        flight = self._flight
+        owed = flight is not None \
+            and flight.reqs.get(slot) is self.running[slot]
+        return self._remaining[slot] > owed
+
+    def _make_writable(self, grow, landed=None):
+        """Make the next ``grow`` positions writable (1: a token; a
+        block's length: an open block, every step) of each running slot
+        that is in the next step: grow tables (cold cached prefixes are
+        LRU-evicted before anything else — eviction always runs before
+        preemption), copy-on-write shared blocks; preempt a victim on
+        true pool exhaustion (never truncate). A preemption requeues
+        prompt + generated, which must hold the token in flight: the
+        step in flight is read first (its tokens go to ``landed``), and
+        the blocks of those it finished may do."""
         for slot in list(self.running):
-            if slot not in self.running:  # preempted as a victim
-                continue
-            while True:
+            while slot in self.running and (
+                    self._flight is None or self._runs_next(slot)):
                 new_len = int(self.cache.seq_lens[slot]) + grow
                 denied = self.cache.prepare_append(slot, new_len) \
                     if grow == 1 else \
@@ -889,6 +977,9 @@ class Scheduler:
                     raise RuntimeError(
                         f"serving: request {req.rid} outgrew "
                         f"max_blocks_per_seq: {denied.detail}")
+                if self._flight is not None:
+                    landed += self.land()
+                    continue
                 if len(self.running) == 1:
                     # unreachable since validate_request bounds each
                     # request's worst-case demand to the pool; keep
@@ -906,38 +997,89 @@ class Scheduler:
                 if victim == slot:
                     break  # grower preempted itself; re-prefills later
 
-    def _timed_decode_dispatch(self, dispatch, batch, ctx_tokens,
-                               **stats):
-        """Run one batched decode program under the shared
-        instrumentation contract — the dispatch and the read-back that
-        waits for its tokens each a phase, compile + AOT-saved deltas
-        billed through the accountant, pure device time fed to overload
-        control — so the plain and speculative paths can never drift
-        apart in what they report. ``dispatch`` returns the program's
-        tokens still on the device; ``stats`` join ``batch`` and
-        ``context_tokens`` on the dispatch span. Returns (tokens as
-        numpy, wall us of both)."""
+    def _dispatch_decode(self, dispatch, batch, ctx_tokens, **stats):
+        """Dispatch one batched decode program under the shared
+        instrumentation contract: the dispatch a phase, compile +
+        AOT-saved deltas billed through the accountant, whether the step
+        before was still unread counted. ``dispatch`` returns the
+        program's tokens still on the device; ``stats`` join ``batch``
+        and ``context_tokens`` on the dispatch span. Returns the
+        :class:`_Flight` that ``_await_tokens`` reads."""
+        (_m_in_order if self._flight is None else _m_ahead).inc()
         comp0 = _compile_s()
         saved0 = _saved_s()
-        t_dec = time.perf_counter_ns()
+        t_ns = time.perf_counter_ns()
         with _phase("serving.decode.dispatch", batch=batch,
                     context_tokens=ctx_tokens, **stats):
-            out = dispatch()
-        with _phase("serving.decode.readback"):  # waits for the device
-            out = np.asarray(out)
-        dec_us = (time.perf_counter_ns() - t_dec) / 1000.0
+            toks = dispatch()
         _m_ctx_tokens.inc(ctx_tokens)
-        dec_comp_us = (_compile_s() - comp0) * 1e6
-        self.accounting.note_decode_compile(dec_comp_us)
-        self.accounting.note_decode_aot_saved((_saved_s() - saved0) * 1e6)
-        self.overload.observe_decode(max(dec_us - dec_comp_us, 0.0))
-        return out, dec_us
+        comp_us = (_compile_s() - comp0) * 1e6
+        saved_us = (_saved_s() - saved0) * 1e6
+        self.accounting.note_decode_compile(comp_us)
+        self.accounting.note_decode_aot_saved(saved_us)
+        return _Flight(toks, self._step_no, t_ns, comp_us,
+                       built=comp_us > 0.0 or saved_us > 0.0)
+
+    def _await_tokens(self, flight):
+        """The read-back that waits for a dispatched step's tokens, a
+        phase of its own. Returns (tokens as numpy, us the step took as
+        the host can see it): from the later of its dispatch and the
+        arrival of the step before to the arrival of its own tokens —
+        in order that is the wall time of dispatch + read-back, one step
+        ahead the time between two arrivals. Compile excluded, it feeds
+        overload control's service-time estimate, unless a prefill was
+        read back in between (``_left_of_flight``)."""
+        with _phase("serving.decode.readback"):  # waits for the device
+            toks = np.asarray(flight.toks)
+        now = time.perf_counter_ns()
+        dec_us = (now - max(flight.t_ns, self._seen_ns)) / 1000.0
+        self._seen_ns = now
+        if flight.timed:
+            self._dec_us = max(dec_us - flight.comp_us, 0.0)
+            self.overload.observe_decode(self._dec_us)
+        return toks, dec_us
+
+    def _left_of_flight(self, at_ns):
+        """Microseconds the decode step in flight still had to run at
+        ``at_ns``, by the last step time the host saw: a prefill
+        dispatched behind it waits that long on the device, which is not
+        the prefill's cost. The prefill's read-back then hides when the
+        step's tokens arrived, so that step feeds the service-time
+        estimate nothing. 0 with nothing in flight."""
+        flight = self._flight
+        if flight is None:
+            return 0.0
+        flight.timed = False
+        if self._dec_us is None:
+            return 0.0
+        ran_us = (at_ns - max(flight.t_ns, self._seen_ns)) / 1000.0
+        return max(self._dec_us - ran_us, 0.0)
+
+    def _timed_decode_dispatch(self, dispatch, batch, ctx_tokens,
+                               **stats):
+        """One decode program dispatched and read in order
+        (``_dispatch_decode`` + ``_await_tokens``): the speculative and
+        the block path, whose next input is made on the host. Returns
+        (tokens as numpy, wall us of both)."""
+        return self._await_tokens(
+            self._dispatch_decode(dispatch, batch, ctx_tokens, **stats))
 
     def _decode(self):
-        if not self.running:
-            return []
+        """The plain path runs one step ahead. Who is in step K+1 is
+        known from the counts before step K's tokens are read, and its
+        token input *is* step K's output: so K+1 is dispatched with that
+        array, still on the device (``_launch``), and only then are K's
+        tokens read, emitted and its finished requests freed
+        (``land``) — while the device runs K+1. In order is the same
+        code with the read before the next dispatch: whatever must see
+        every token on the host first calls ``land`` (a preemption, a
+        swept running request), and a step whose next input is made on
+        the host (speculation's drafts) or that built its program is
+        read as soon as it is dispatched."""
         if self._block_len > 1:
-            return self._decode_block()
+            return self._decode_block() if self.running else []
+        if not self.running:
+            return self.land()
         if self.spec:
             out = self._decode_spec()
             if out is not None:
@@ -945,26 +1087,72 @@ class Scheduler:
             # nothing proposed (or speculative capacity unavailable):
             # this step runs the plain single-token path below —
             # bit-equivalent, just not multiplied
-        with _phase("serving.decode.prepare"):
-            self._make_writable(1)
-            if not self.running:
-                return []
-            active = np.zeros((self.cache.max_batch,), bool)
-            for slot in self.running:
-                active[slot] = True
-            batch = len(self.running)
-            ctx_tokens = int(self.cache.seq_lens[active].sum()) + batch
+        out = []
+        flight = self._launch(out)
+        out += self.land()
+        self._flight = flight
+        if flight is not None and (self.spec or flight.built):
+            out += self.land()
+        return out
 
+    def _launch(self, landed):
+        """Prepare and dispatch the next plain decode step from host
+        state alone; None when no running slot has a token left to make.
+        Tokens of a step it had to read first go to ``landed``."""
+        with _phase("serving.decode.prepare"):
+            self._make_writable(1, landed)
+            live = [s for s in self.running if self._runs_next(s)]
+            if not live:
+                return None
+            active = np.zeros((self.cache.max_batch,), bool)
+            active[live] = True
+            batch = len(live)
+            ctx_tokens = int(self.cache.seq_lens[active].sum()) + batch
         # decode compiles split across the batch
-        toks, dec_us = self._timed_decode_dispatch(
+        flight = self._dispatch_decode(
             lambda: self.model.paged_decode_step(
-                self.cache, np.asarray(self._last_tok), active,
+                self.cache, self._token_input(live), active,
                 temperature=self.temperature,
                 kernel_mode=self.kernel_mode),
             batch, ctx_tokens)
+        flight.reqs = {s: self.running[s] for s in live}
+        return flight
+
+    def _token_input(self, live):
+        """The token input of the step over the slots ``live``: the
+        host's last tokens with nothing in flight; else the output of
+        the step in flight, still on the device, with the slots admitted
+        since it was dispatched (their first token is on the host, from
+        their prefill) put in there."""
+        prev = self._flight
+        if prev is None:
+            return np.asarray(self._last_tok)
+        fresh = [s for s in live
+                 if prev.reqs.get(s) is not self.running[s]]
+        if not fresh:
+            return prev.toks
+        mask = np.zeros((self.cache.max_batch,), bool)
+        mask[fresh] = True
+        return merge_tokens(prev.toks, self._last_tok, mask)
+
+    def land(self):
+        """Read the decode step in flight, if there is one: its tokens
+        reach the host, are emitted, and the requests whose count ran
+        out (or that emitted EOS) finish. Returns the (rid, token) list.
+        The loop calls it after dispatching the next step; whoever needs
+        every running request's tokens on the host calls it first (a
+        preemption, a cancellation, a test's in-order reference)."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return []
+        toks, dec_us = self._await_tokens(flight)
         out = []
         with _phase("serving.decode.emit"):
-            for slot, req in list(self.running.items()):
+            for slot, req in flight.reqs.items():
+                if self.running.get(slot) is not req:
+                    # it emitted EOS in the step before, after this one
+                    # was dispatched: this token is dropped
+                    continue
                 t = int(toks[slot])
                 self._last_tok[slot] = t
                 self._remaining[slot] -= 1
@@ -972,9 +1160,9 @@ class Scheduler:
                 # request's trace gets a slice of that step's wall time
                 _tracing.record_span("serving.decode_step", req.span,
                                      dec_us, token=len(req.generated),
-                                     batch=len(self.running),
+                                     batch=len(flight.reqs),
                                      route=self.kernel_route,
-                                     step=self._step_no)
+                                     step=flight.step_no)
                 self.accounting.note_decode(req)
                 self._emit(req, t)
                 out.append((req.rid, t))
@@ -1342,6 +1530,10 @@ class Scheduler:
     def fail_all(self, exc=None):
         """Engine died: terminate every live request with ERROR so no
         consumer blocks forever (the frontend re-raises the cause)."""
+        try:
+            self.land()  # what the last step made is delivered first
+        except Exception:  # noqa: BLE001 — the program that died made it
+            pass
         for req in list(self.queue):
             self._finish(req, RequestStatus.ERROR)
         self.queue.clear()
