@@ -213,18 +213,18 @@ def train_phase(config, batch, seq, steps, *, dtype, platform,
 # serve
 # ---------------------------------------------------------------------------
 
-def _build_llama(config, dtype):
-    """Seeded Llama constructed directly in ``dtype`` (a float32 copy of
-    the 8B widths is a transient the chip cannot hold); ``to`` then casts
-    what a layer pinned to float32, the embedding and the norm weights."""
+def _build(cls, config, dtype):
+    """Seeded ``cls(config)`` constructed directly in ``dtype`` (a
+    float32 copy of the 8B widths is a transient the chip cannot hold);
+    ``to`` then casts what a layer pinned to float32, the embedding and
+    the norm weights."""
     import paddle_tpu as paddle
-    from paddle_tpu.models import Llama
 
     paddle.seed(0)
     prev = paddle.get_default_dtype()
     paddle.set_default_dtype(dtype)
     try:
-        model = Llama(config)
+        model = cls(config)
     finally:
         paddle.set_default_dtype(prev)
     model.to(dtype=dtype)
@@ -350,9 +350,10 @@ def serve_phase(config, engines, new_tokens, *, dtype, platform,
     the first-step logits of a fixed prompt for the four-chip phase."""
     import numpy as np
 
+    from paddle_tpu.models import Llama
     from paddle_tpu.profiler import metrics
 
-    model = _build_llama(config, dtype)
+    model = _build(Llama, config, dtype)
     _require_on(platform, model, "serve")
     facts = {"layers": config.num_layers, "params": model.num_params(),
              "param_bytes": int(sum(p._data.nbytes
@@ -532,6 +533,71 @@ def kernels_phase(checks, tol):
 
 
 # ---------------------------------------------------------------------------
+# a hybrid model: recurrent state beside the paged cache
+# ---------------------------------------------------------------------------
+
+def hybrid_phase(config, prompt_len, steps, *, dtype, platform,
+                 paged_kernel, tol):
+    """A small Jamba (``models/jamba.py``): one prefill at a padded
+    bucket and ``steps`` decode steps through a cache that holds
+    recurrent state beside its pools, on the kernels' route
+    (``paged_kernel``: the chunked scan, the in-place state update, the
+    paged attention) and again on the plain route; the slot's state and
+    the tokens of both must agree. Lowers and runs both selective-scan
+    kernels on whatever backend this is."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.inference.paged import PagedKVCache
+    from paddle_tpu.models import Jamba
+
+    model = _build(Jamba, config, dtype)
+    _require_on(platform, model, "hybrid")
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(3, config.vocab_size, size=prompt_len)
+    slots, block = 8, 16
+    pages = -(-(2 * prompt_len + steps) // block)
+    active = np.zeros((slots,), bool)
+    runs = {}
+    for mode in (paged_kernel, "dense"):
+        cache = PagedKVCache(
+            model.kv_cache_layers, config.num_kv_heads, config.head_dim,
+            num_blocks=slots * pages + 1, block_size=block,
+            max_blocks_per_seq=pages, max_batch=slots,
+            dtype=jnp.dtype(dtype), recurrent_state=model.recurrent_state)
+        cache.alloc_slot(block)            # an idle slot in front
+        slot = cache.alloc_slot(prompt_len)
+        active[:] = False
+        active[slot] = True
+        toks = [model.paged_prefill(cache, slot, prompt,
+                                    pad_to=2 * prompt_len,
+                                    kernel_mode=mode)]
+        last = np.zeros((slots,), np.int64)
+        for _ in range(steps):
+            last[slot] = toks[-1]
+            _require(cache.ensure_capacity(
+                slot, int(cache.seq_lens[slot]) + 1), "hybrid: no block")
+            toks.append(int(np.asarray(model.paged_decode_step(
+                cache, last, active, kernel_mode=mode))[slot]))
+        with cache.pool_lock:
+            runs[mode] = (toks, np.array(cache.ssm_state[:, slot]),
+                          np.array(cache.ssm_state[:, 0]))
+    (toks, state, idle), (plain_toks, plain_state, _) = \
+        runs[paged_kernel], runs["dense"]
+    err = _rel_err(state, plain_state)
+    _require(err <= tol, f"hybrid: the slot's state on the kernels' route "
+             f"differs from the plain route's by {err:.3e} of its scale "
+             f"(tolerance {tol:.3e})")
+    _require(not idle.any(), "hybrid: an idle slot's state moved")
+    same = sum(a == b for a, b in zip(toks, plain_toks))
+    _require(same >= len(toks) - 1, f"hybrid: {len(toks) - same} of "
+             f"{len(toks)} tokens differ between the two routes")
+    return {"state_rel_err": float(f"{err:.3e}"), "tokens": len(toks),
+            "tokens_same": same,
+            "state_bytes_per_slot": cache.state_bytes() // slots}
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 
@@ -661,7 +727,7 @@ def main():
               file=sys.stderr)
         return 2
 
-    from paddle_tpu.models import GPTConfig, LlamaConfig
+    from paddle_tpu.models import GPTConfig, JambaConfig, LlamaConfig
     from paddle_tpu.utils import configure_compile_cache
 
     cache_dir = configure_compile_cache()
@@ -705,6 +771,14 @@ def main():
     _timed("kernels", kernels_phase,
            kernel_checks(gpt, llama, chunked_kw, paged_kw, dtype=dtype),
            BF16_TOL)
+
+    # one period of a hybrid stack at a width whose E = 512 is whole
+    # lane tiles: the scan and state-update kernels' lowering, every call
+    _timed("hybrid", hybrid_phase, JambaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_layers=4, num_heads=2, num_kv_heads=1, attn_layer_period=4,
+        attn_layer_offset=2, mamba_dt_rank=16), 100, 8, dtype=dtype,
+        platform="tpu", paged_kernel=None, tol=BF16_TOL)
 
     if device["count"] >= 4:
         _timed("four_chip_serve", four_chip_serve, model, prompt, logits,

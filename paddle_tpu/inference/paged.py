@@ -71,6 +71,9 @@ _KERN_INTERPRET = _metrics.counter("serving.kernel.interpret")
 # wrote a copy of every pool
 _KV_DONATED = _metrics.counter("serving.kv.donated_calls")
 _KV_COPIED = _metrics.counter("serving.kv.copied_calls")
+# bytes of recurrent state a cache holds beside its pools (0 where every
+# layer of the model is attention)
+_STATE_BYTES = _metrics.gauge("serving.ssm.state_bytes")
 
 __all__ = ["PagedKVCache", "paged_prefill_write",
            "paged_prefill_write_masked", "paged_decode_attention",
@@ -80,6 +83,7 @@ __all__ = ["PagedKVCache", "paged_prefill_write",
            "paged_spec_write", "paged_spec_attention_dense",
            "ContinuousBatchingEngine", "validate_request",
            "chunk_digests", "PrefixPlan", "CapacityError",
+           "RecurrentStateSpec",
            "resolve_kv_dtype", "quant_block_ratio",
            "resolve_paged_kernel", "kernel_route"]
 
@@ -216,6 +220,20 @@ class CapacityError:
         return f"CapacityError({self.reason!r}, {self.detail!r})"
 
 
+@dataclass(frozen=True)
+class RecurrentStateSpec:
+    """What the layers of a model that are not attention carry from
+    step to step, a slot: ``layers`` of them, each a float32 state
+    ``[states, channels]`` (the channels on the lanes) and the last
+    ``conv_tail`` inputs of a causal convolution ``[conv_tail,
+    channels]``. A constant size a slot, whatever its length."""
+
+    layers: int
+    channels: int
+    states: int
+    conv_tail: int
+
+
 @dataclass
 class PrefixPlan:
     """Host-side admission plan from ``PagedKVCache.plan_prefix``: which
@@ -268,6 +286,20 @@ class PagedKVCache:
     [max_batch] int32, the free-list of block ids, per-block refcounts,
     and the content-addressed prefix index.
 
+    **Recurrent state** (``recurrent_state``, a
+    :class:`RecurrentStateSpec`; ``models/jamba.py``): the layers of a
+    hybrid model that are not attention keep, a slot, a state that is not
+    paged, not shared and not addressed by a block table. The cache
+    holds it stacked, one array a kind — ``ssm_state`` [state layers,
+    slots, states, channels] float32 and ``conv_state`` [state layers,
+    conv_tail, slots, channels] in the compute type — beside pools that
+    then have only the attention layers' ``num_layers``. It rides the
+    same protocol: donated with the pools, returned, rebound under
+    ``pool_lock``. ``alloc_slot`` marks a slot's state ``state_fresh``
+    (nothing of the slot's last request may be read); a prefill writes it
+    from zero. Such a cache plans no prefix hit and registers no chunk:
+    the state at a prefix's end exists nowhere (docs/SERVING.md).
+
     **Prefix sharing** (vLLM shared-block / SGLang RadixAttention
     style): a block registered in the prefix index is immutable in its
     registered rows and may back several slots at once (refcount > 1).
@@ -285,7 +317,7 @@ class PagedKVCache:
     def __init__(self, num_layers, num_kv_heads, head_dim, *, num_blocks,
                  block_size=16, max_blocks_per_seq, max_batch,
                  dtype=jnp.bfloat16, kv_dtype=None, pool_sharding=None,
-                 scale_sharding=None, num_slices=1):
+                 scale_sharding=None, num_slices=1, recurrent_state=None):
         self.num_layers = num_layers
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -346,6 +378,24 @@ class PagedKVCache:
                              for _ in range(num_layers)]
         else:
             self.k_scales = self.v_scales = None
+        self.state_spec = recurrent_state
+        self.ssm_state = self.conv_state = None
+        # slots whose state holds nothing of their request yet: set when
+        # a slot is allocated, cleared by the prefill that writes it
+        self.state_fresh = np.ones((max_batch,), bool)
+        if recurrent_state is not None:
+            if pool_sharding is not None or self.num_slices > 1:
+                raise ValueError(
+                    "PagedKVCache: recurrent state is held on one "
+                    "device: a serving mesh has no sharding rule for "
+                    "it.")
+            st = recurrent_state
+            self.ssm_state = jnp.zeros(
+                (st.layers, max_batch, st.states, st.channels),
+                jnp.float32)
+            self.conv_state = jnp.zeros(
+                (st.layers, st.conv_tail, max_batch, st.channels), dtype)
+            _STATE_BYTES.set(self.state_bytes())
         # held from the dispatch of a pool-writing program until its
         # pools are rebound, and by any reader off the engine's thread
         self.pool_lock = threading.RLock()
@@ -458,6 +508,19 @@ class PagedKVCache:
                 "cached_free": cached,
                 "free": free}
 
+    def state_bytes(self):
+        """Bytes of recurrent state held beside the pools (0 where the
+        model has none): a constant a slot, whatever is live."""
+        if self.state_spec is None:
+            return 0
+        return int(self.ssm_state.nbytes + self.conv_state.nbytes)
+
+    def state_args(self):
+        """The recurrent state as a serving program takes it, after the
+        four pool lists: one more donated argument, or none."""
+        return () if self.state_spec is None \
+            else ((self.ssm_state, self.conv_state),)
+
     def occupancy_slices(self):
         """Per-slice occupancy dicts, index == slice id (a single
         aggregate entry for the unsliced cache)."""
@@ -466,8 +529,9 @@ class PagedKVCache:
         return [self.occupancy(slice=i) for i in range(self.num_slices)]
 
     def pool_bytes(self, slice=None):
-        """Total HBM footprint of the K+V pools (static: allocated at
-        construction, independent of occupancy). Quantized pools count
+        """Total HBM footprint of the K+V pools and of the recurrent
+        state beside them (static: allocated at construction,
+        independent of occupancy). Quantized pools count
         their int8 rows PLUS the float32 scale arrays — the multiplier
         ``occupancy()`` shows must never be paid for twice in hidden
         bytes (tools/spec_gate.py pins consistency). ``slice=i``
@@ -484,7 +548,7 @@ class PagedKVCache:
         if slice is not None and self.num_slices > 1:
             usable = int((self._block_owner == slice).sum())
             return int(total * usable / max(self.num_blocks - 1, 1))
-        return total
+        return total + self.state_bytes()
 
     # -- block primitives --------------------------------------------------
 
@@ -558,8 +622,9 @@ class PagedKVCache:
             else out
 
     def rebind_pools(self, k_pools, v_pools, k_scales=None,
-                     v_scales=None):
-        """Take the pools a pool-writing program returned (the caller
+                     v_scales=None, state=None):
+        """Take the pools (and the recurrent state, where the cache
+        holds one) a pool-writing program returned (the caller
         holds ``pool_lock`` since before the dispatch). The pools handed
         in are still bound here, so one look at the first says whether
         the program consumed them (``serving.kv.donated_calls``) or
@@ -571,6 +636,8 @@ class PagedKVCache:
         if self.quantized:
             self.k_scales = list(k_scales)
             self.v_scales = list(v_scales)
+        if state is not None:
+            self.ssm_state, self.conv_state = state
 
     def write_blocks(self, dst, src):
         """Overwrite pool blocks ``dst`` in every layer's pools (and
@@ -632,6 +699,7 @@ class PagedKVCache:
         row[:need] = blocks
         self.block_tables[slot] = row
         self.seq_lens[slot] = 0
+        self.state_fresh[slot] = True
         return slot
 
     def ensure_capacity(self, slot, new_len):
@@ -755,6 +823,14 @@ class PagedKVCache:
         ids = np.asarray(token_ids).reshape(-1)
         n = int(ids.size)
         bs = self.block_size
+        if self.state_spec is not None:
+            # the no-hit plan: a prefix's K and V blocks could be mapped,
+            # but the recurrent state at its end exists nowhere
+            return PrefixPlan(
+                ids=ids, num_tokens=n,
+                chunks_total=max(1, math.ceil(n / bs)), digests=[],
+                matched_full=0, matched_blocks=[], partial_block=None,
+                partial_len=0, partial_shared=False, covered_tokens=0)
         digests = chunk_digests(ids, bs)
         matched, blocks = 0, []
         for d in digests:
@@ -833,6 +909,7 @@ class PagedKVCache:
         row[:len(blocks)] = blocks
         self.block_tables[slot] = row
         self.seq_lens[slot] = 0
+        self.state_fresh[slot] = True
         # a COW-extended partial match counts as a HIT (its registered
         # tokens were served from cache even though the block itself is
         # a fresh copy) — keeps these counters consistent with the
@@ -847,7 +924,11 @@ class PagedKVCache:
         prefix index (after the prefill wrote them — their rows are
         immutable from here on: appends only ever touch rows past the
         registered token count, and shared writes COW first). First
-        registration wins; an already-indexed digest keeps its block."""
+        registration wins; an already-indexed digest keeps its block. A
+        cache that holds recurrent state registers nothing
+        (``plan_prefix``)."""
+        if self.state_spec is not None:
+            return
         blocks = self._slot_blocks[slot]
         for i in range(plan.matched_full, len(plan.digests)):
             d = plan.digests[i]
@@ -1329,10 +1410,11 @@ class ContinuousBatchingEngine:
         num_blocks = sized_num_blocks(
             num_blocks, max_batch, mbps, kv_dtype, hd, dtype)
         self.cache = PagedKVCache(
-            cfg.num_layers, cfg.num_kv_heads, hd,
-            num_blocks=num_blocks,
+            getattr(model, "kv_cache_layers", cfg.num_layers),
+            cfg.num_kv_heads, hd, num_blocks=num_blocks,
             block_size=block_size, max_blocks_per_seq=mbps,
-            max_batch=max_batch, dtype=dtype, kv_dtype=kv_dtype)
+            max_batch=max_batch, dtype=dtype, kv_dtype=kv_dtype,
+            recurrent_state=getattr(model, "recurrent_state", None))
         self.waiting: list[_Request] = []
         self.running: dict[int, _Request] = {}  # slot -> request
         self.finished: dict[int, _Request] = {}

@@ -1,8 +1,10 @@
 """Model zoo: LLM families mirroring the reference's headline workloads
-(BASELINE.json config ladder: GPT-2, Llama, Mixtral/MoE, ViT), and SDAR
-(sparse experts, generation by diffusion over blocks)."""
+(BASELINE.json config ladder: GPT-2, Llama, Mixtral/MoE, ViT), SDAR
+(sparse experts, generation by diffusion over blocks) and Jamba
+(state-space layers with attention among them)."""
 
 from .gpt import GPT, GPTConfig  # noqa: F401
+from .jamba import Jamba, JambaConfig  # noqa: F401
 from .llama import Llama, LlamaConfig  # noqa: F401
 from .mixtral import Mixtral, MixtralConfig  # noqa: F401
 from .sdar import SDAR, SDARConfig  # noqa: F401

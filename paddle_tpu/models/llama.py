@@ -277,11 +277,26 @@ class PagedServingModel(nn.Layer):
     mesh in.
 
     A serving program is ``program(param_arrays, *head, k_pools,
-    v_pools, k_scales, v_scales, *tail) -> (*outputs, k_pools, v_pools,
-    k_scales, v_scales)``: the cache's device state in the middle as the
-    cache holds it (the scale lists empty for a full-precision cache),
-    donated together and returned written in place. ``_build_<job>(
-    quantized, mode)`` builds the program of a job."""
+    v_pools, k_scales, v_scales[, state], *tail) -> (*outputs, k_pools,
+    v_pools, k_scales, v_scales[, state])``: the cache's device state in
+    the middle as the cache holds it (the scale lists empty for a
+    full-precision cache; ``state``, the stacked recurrent state, only
+    where the model has layers that carry one), donated together and
+    returned written in place. ``_build_<job>(quantized, mode)`` builds
+    the program of a job."""
+
+    @property
+    def kv_cache_layers(self):
+        """Layers that write K and V pools: what the cache's
+        ``num_layers`` is built from."""
+        return self.config.num_layers
+
+    @property
+    def recurrent_state(self):
+        """The ``inference.paged.RecurrentStateSpec`` of the layers that
+        carry state from step to step instead of writing K and V; None
+        where every layer is attention."""
+        return None
 
     @property
     def paged_programs(self):
@@ -302,15 +317,17 @@ class PagedServingModel(nn.Layer):
 
     def _as_program(self, body, tag, pools_at, quantized=False,
                     mode=None):
-        """``body(*head, k_pools, v_pools, k_scales, v_scales, *tail)``
-        as the serving program ``jit_<tag, dots as underscores>[_q8]``,
+        """``body(*head, k_pools, v_pools, k_scales, v_scales[, state],
+        *tail)`` as the serving program ``jit_<tag, dots as underscores>[_q8]``,
         the name a profiler trace shows on the device's ``XLA Modules``
         line and the host's ``PjitFunction(<name>)`` events, so busy
         time splits by program (``benchmarks/span_reduce.py``). The
         parameters are its first argument (bound into the module for the
-        trace); the four lists from argument ``pools_at`` are donated: it
-        takes their buffers and writes in place, and the caller rebinds
-        what comes back before anything reads the cache. AOT tag
+        trace); the arguments from ``pools_at`` that hold the cache (the
+        four lists, and the recurrent state where the model declares
+        one) are donated:
+        it takes their buffers and writes in place, and the caller
+        rebinds what comes back before anything reads the cache. AOT tag
         ``<tag>[.q8][.k-<mode>][.mesh<spec>]``."""
         rebind = self._param_rebind()
 
@@ -325,20 +342,22 @@ class PagedServingModel(nn.Layer):
         fn.__name__ = fn.__qualname__ = tag.replace(".", "_")
         if mode not in (None, "auto"):
             tag += f".k-{mode}"
+        held = 4 + (self.recurrent_state is not None)
         return _aot_wrap(
             jax.jit(fn, donate_argnums=tuple(range(pools_at,
-                                                   pools_at + 4))),
+                                                   pools_at + held))),
             self._aot_tag(tag))
 
     def paged_call_args(self, cache, job, head, tail=(), mode=None):
         """``(program, args)``: the program of ``job`` for ``cache`` and
         the arguments a ``paged_*`` entry point calls it with — the
-        parameters, ``head``, the cache's pools and scale arrays,
-        ``tail``. For whoever lowers or runs the program beside the
+        parameters, ``head``, the cache's pools and scale arrays (and
+        its recurrent state, where it holds one), ``tail``. For whoever lowers or runs the program beside the
         entry point; ``_paged_call`` is this plus the call."""
         return self.serving_program(job, cache.quantized, mode), (
             self._param_arrays(), *head, cache.k_pools, cache.v_pools,
-            cache.k_scales or [], cache.v_scales or [], *tail)
+            cache.k_scales or [], cache.v_scales or [],
+            *cache.state_args(), *tail)
 
     @contextlib.contextmanager
     def _paged_call(self, cache, job, mode=None):
@@ -355,14 +374,15 @@ class PagedServingModel(nn.Layer):
             def call(head, tail=()):
                 program, args = self.paged_call_args(cache, job, head,
                                                      tail, mode)
+                held = 4 + len(cache.state_args())
                 try:
-                    *out, k, v, ks, vs = program(*args)
+                    out = program(*args)
                 finally:
                     # tracing left tracers bound into the module's
                     # parameters; restore
                     self._param_rebind()(args[0])
-                returned.append((k, v, ks, vs))
-                return out
+                returned.append(out[-held:])
+                return list(out[:-held])
 
             def rebind():
                 cache.rebind_pools(*returned.pop())
@@ -390,34 +410,84 @@ class PagedServingModel(nn.Layer):
         out[0, :ids.shape[0]] = ids
         return out
 
+    @staticmethod
+    def _table_row(cache, slot):
+        """The slot's table row as the prefill programs take it: a copy.
+        A prefill that samples nothing is not waited for, and a backend
+        may read a host array it was handed after the call returned (the
+        CPU's does: a view of ``block_tables`` showed the program, 10
+        times of 20, what the host wrote into it afterwards). The
+        scheduler's next moves are on this row: it grows for the open
+        block, and is zeroed if the slot is preempted."""
+        return jnp.asarray(cache.block_tables[slot].copy())
+
     def _paged_stack(self, x, position_offset, pools, write, attend,
-                     mlp=None):
-        """The decoder stack over the paged cache, written once: ``x``
-        [b, s, d] is the embedded input of the positions from
-        ``position_offset``; layer ``i`` hands its post-rope keys and
-        values [b, s, Hk, D] to the job's ``write(k_pool, v_pool, k, v,
-        **scales) -> (k_pool, v_pool, *scales)`` and its queries to
-        ``attend(q, k_pool, v_pool, **scales)`` over what was written
-        (any shape of b x s rows). ``pools`` are the cache's four lists;
-        ``mlp(blk, m)`` stands in for ``blk.mlp(m)``. Returns the final
-        norm's output and the four lists written."""
+                     mlp=None, state=None, mix=None):
+        """The decoder stack over the paged cache, written once, for
+        both kinds of layer: ``x`` [b, s, d] is the embedded input of
+        the positions from ``position_offset``. The ``i``-th attention
+        layer hands its post-rope keys and values [b, s, Hk, D] to the
+        job's ``write(k_pool, v_pool, k, v, **scales) -> (k_pool,
+        v_pool, *scales)`` and its queries to ``attend(q, k_pool,
+        v_pool, **scales)`` over what was written (any shape of b x s
+        rows). A layer whose ``self_attn`` is None carries state
+        instead: the ``j``-th such hands its ``mixer`` and its normed
+        input to the job's ``mix(mixer, h, state, j) -> (out, state)``.
+        ``pools`` are the cache's four lists, ``state`` its recurrent
+        state; ``mlp(blk, m)`` stands in for ``blk.mlp(m)``. Returns the
+        final norm's output, the four lists written and the state."""
         k_pools, v_pools, k_scales, v_scales = pools
         b, s, _ = x.shape
         new = ([], [], [], [])
-        for i, blk in enumerate(self.layers):
+        carried = 0
+        for blk in self.layers:
             attn = blk.self_attn
-            q, k, v = attn.qkv(blk.input_layernorm(x), position_offset)
-            scales = _layer_scales(k_scales, v_scales, i)
-            layer = write(k_pools[i], v_pools[i], k._data, v._data,
-                          **scales)
-            for pool_list, pool in zip(new, layer):
-                pool_list.append(pool)
-            out = attend(q._data, *layer[:2],
-                         **dict(zip(scales, layer[2:])))
-            x = x + attn.o_proj(Tensor(out.reshape(b, s, -1)))
+            if attn is None:
+                out, state = mix(blk.mixer, blk.input_layernorm(x), state,
+                                 carried)
+                carried += 1
+                x = x + out
+            else:
+                i = len(new[0])
+                q, k, v = attn.qkv(blk.input_layernorm(x),
+                                   position_offset)
+                scales = _layer_scales(k_scales, v_scales, i)
+                layer = write(k_pools[i], v_pools[i], k._data, v._data,
+                              **scales)
+                for pool_list, pool in zip(new, layer):
+                    pool_list.append(pool)
+                out = attend(q._data, *layer[:2],
+                             **dict(zip(scales, layer[2:])))
+                x = x + attn.o_proj(Tensor(out.reshape(b, s, -1)))
             m = blk.post_attention_layernorm(x)
             x = x + (blk.mlp(m) if mlp is None else mlp(blk, m))
-        return self.norm(x), new
+        return self.norm(x), new, state
+
+    def _logits(self, hidden):
+        if self.lm_head is not None:
+            return self.lm_head(hidden)
+        from .. import ops
+        return ops.matmul(hidden, self.embed_tokens.weight,
+                          transpose_y=True)
+
+    def _next_token(self, hidden, pick, key=None, temp=None):
+        """The head over the stack's normed output, at the positions
+        ``pick`` takes from the logits [b, s, vocab]: their arg-max, or
+        (``key`` given) a sample at temperature ``temp`` where that is
+        positive."""
+        from .generation import sample_token
+        last = pick(self._logits(hidden)._data)
+
+        def greedy():
+            return jnp.argmax(last, axis=-1).astype(jnp.int32)
+
+        if key is None:
+            return greedy()
+        return jax.lax.cond(
+            temp > 0,
+            lambda: sample_token(last / jnp.maximum(temp, 1e-6),
+                                 temperature=1.0, key=key),
+            greedy)
 
     def _param_rebind(self):
         if not hasattr(self, "_pb_names"):
@@ -562,32 +632,6 @@ class Llama(PagedServingModel):
             self.__dict__["_serving_mesh"] = mesh
             self.paged_programs.clear()
 
-    def _logits(self, hidden):
-        if self.lm_head is not None:
-            return self.lm_head(hidden)
-        from .. import ops
-        return ops.matmul(hidden, self.embed_tokens.weight,
-                          transpose_y=True)
-
-    def _next_token(self, hidden, pick, key=None, temp=None):
-        """The head over the stack's normed output, at the positions
-        ``pick`` takes from the logits [b, s, vocab]: their arg-max, or
-        (``key`` given) a sample at temperature ``temp`` where that is
-        positive."""
-        from .generation import sample_token
-        last = pick(self._logits(hidden)._data)
-
-        def greedy():
-            return jnp.argmax(last, axis=-1).astype(jnp.int32)
-
-        if key is None:
-            return greedy()
-        return jax.lax.cond(
-            temp > 0,
-            lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                 temperature=1.0, key=key),
-            greedy)
-
     def paged_prefill(self, cache, slot, prompt_ids, temperature=0.0,
                       pad_to=None):
         """Run the prompt through the dense forward (causal), write its
@@ -684,7 +728,7 @@ class Llama(PagedServingModel):
                  v_pools, k_scales, v_scales, key, temp):
             from ..inference.paged import (paged_prefill_write_masked,
                                            paged_prefix_attention_dense)
-            hidden, new = self._paged_stack(
+            hidden, new, _ = self._paged_stack(
                 self.embed_tokens(Tensor(tail_ids)), t_start,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v, **scales: paged_prefill_write_masked(
@@ -748,7 +792,7 @@ class Llama(PagedServingModel):
             attention = functools.partial(
                 paged_decode_attention_tp, mesh=mesh) if use_tp \
                 else paged_decode_attention
-            hidden, new = self._paged_stack(
+            hidden, new, _ = self._paged_stack(
                 self.embed_tokens(Tensor(toks[:, None])), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v, **scales: paged_decode_write(
@@ -780,7 +824,7 @@ class Llama(PagedServingModel):
                  k_scales, v_scales):
             from ..inference.paged import (paged_spec_attention_dense,
                                            paged_spec_write)
-            hidden, new = self._paged_stack(
+            hidden, new, _ = self._paged_stack(
                 self.embed_tokens(Tensor(toks)), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v, **scales: paged_spec_write(

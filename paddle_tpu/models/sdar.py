@@ -221,17 +221,6 @@ class SDAR(PagedServingModel):
                 f"multiple of block_length {self.config.block_length}, "
                 "so that a page's keys depend on nothing after it.")
 
-    @staticmethod
-    def _table_row(cache, slot):
-        """The slot's table row as the prefill programs take it: a copy.
-        Nobody waits for a prefill that samples nothing, and a backend
-        may read a host array it was handed after the call returned (the
-        CPU's does: a view of ``block_tables`` showed the program, 10
-        times of 20, what the host wrote into it afterwards). The
-        scheduler's next moves are on this row: it grows for the open
-        block, and is zeroed if the slot is preempted."""
-        return jnp.asarray(cache.block_tables[slot].copy())
-
     def paged_prefill(self, cache, slot, prompt_ids, temperature=0.0,
                       pad_to=None, kernel_mode=None):
         """Run ``prompt_ids`` (whole blocks: a multiple of
@@ -404,7 +393,7 @@ class SDAR(PagedServingModel):
                 moe_out.append(y._data.reshape(-1, y.shape[-1]))
                 return y
 
-            x, new = self._paged_stack(
+            x, new, _ = self._paged_stack(
                 self.embed_tokens(Tensor(ids)), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v: paged_spec_write(

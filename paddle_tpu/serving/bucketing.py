@@ -69,8 +69,10 @@ def bucket_length(n_tokens, block_size, cap, max_len=None):
 
 
 def bucket_lengths(block_size, cap, max_len):
-    """Every bucket a serving config can produce, ascending — what a
-    warmup loop should prefill through so live traffic never compiles."""
+    """Every bucket a serving config can produce, ascending: the
+    ladder up to the cap, then every block multiple past it.
+    ``ServingEngine.warmup`` prefills through those up to the cap, so
+    that live traffic under the cap never compiles."""
     out, seen = [], set()
     n = 1
     while n <= max_len:

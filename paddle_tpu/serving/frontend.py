@@ -446,8 +446,9 @@ class ServingEngine:
 
     def warmup(self):
         """Precompile the bounded serving program set — every prefill
-        bucket the config can produce (``bucket_lengths``: the
-        log2(cap) ladder) plus the batched decode step — then flip
+        bucket up to the cap (``bucket_lengths``: the log2(cap) ladder;
+        a prompt longer than the cap compiles the program of its own
+        length when it comes) plus the batched decode step — then flip
         WARMING -> READY. This is the cold-start gate: constructed
         with ``ready=False``, an engine rejects submits until warmup
         finishes, so live traffic NEVER pays a first-bucket compile.
@@ -469,6 +470,15 @@ class ServingEngine:
             cache = sched.cache
             buckets = bucket_lengths(cache.block_size, sched.bucket_cap,
                                      sched.max_seq_len)
+            if sched.bucket_cap:
+                # the ladder ends at the cap. Past it a prompt pads to
+                # its own multiple of the block, one program a length
+                # (192 of them at the flag's cap of 1024 under a
+                # max_seq_len of 4096): rare by construction
+                # (bucketing.py: cap at the p99 prompt), so each is
+                # compiled when such a prompt first comes, not here
+                buckets = [b for b in buckets
+                           if b <= sched.bucket_cap] or buckets[:1]
             t0 = time.perf_counter_ns()
             n = 0
             kernel_mode = getattr(sched, "kernel_mode", None)
@@ -528,14 +538,10 @@ class ServingEngine:
                         continue  # pool smaller than the ladder tail
                     try:
                         ids = np.zeros((b,), np.int64)
-                        if width > 1:
-                            sched.model.paged_prefill(
-                                cache, slot, ids, pad_to=b,
-                                kernel_mode=kernel_mode)
-                        else:
-                            sched.model.paged_prefill(
-                                cache, slot, ids,
-                                temperature=sched.temperature, pad_to=b)
+                        sched.model.paged_prefill(
+                            cache, slot, ids,
+                            temperature=sched.temperature, pad_to=b,
+                            **sched.prefill_route)
                         n += 1
                         if not decoded:
                             # one decode step warms the (single) decode

@@ -220,6 +220,18 @@ _geometry = geometry  # pre-PR-20 internal name
 
 # -- export ----------------------------------------------------------------
 
+def _refuse_recurrent_state(cache, what):
+    """A frame carries K and V blocks. A cache that also holds recurrent
+    state (``PagedKVCache.state_spec``) cannot be handed over in one:
+    the state at the prefix's end is in no block."""
+    if cache.state_spec is not None:
+        raise ValueError(
+            f"{what}: this cache holds recurrent state beside its KV "
+            "pools, and a transfer frame carries K and V blocks only: "
+            "the state at the prefix's end would be missing on the "
+            "other side.")
+
+
 def export_prefix(cache, token_ids):
     """Serialize the finished KV blocks covering ``token_ids`` out of
     ``cache`` into a crc-framed transfer frame.
@@ -234,6 +246,7 @@ def export_prefix(cache, token_ids):
     Returns ``(frame_bytes, ExportedPrefix)``. Pure read — refcounts,
     indices, and pools are untouched.
     """
+    _refuse_recurrent_state(cache, "export_prefix")
     ids = np.ascontiguousarray(np.asarray(token_ids).reshape(-1),
                                dtype=np.int64)
     plan = cache.plan_prefix(ids)
@@ -322,6 +335,7 @@ def import_prefix(cache, frame):
     exactly as it was. Digests already resident are deduped (their
     local block wins). Returns :class:`ImportResult`.
     """
+    _refuse_recurrent_state(cache, "import_prefix")
     payload = unpack_frame(frame)
     try:
         obj = pickle.loads(payload)

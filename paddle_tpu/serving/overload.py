@@ -28,7 +28,8 @@ load-shedding control loop a production front door needs:
   an int class — smaller is more important (:data:`HIGH` = 0,
   :data:`NORMAL` = 1 the default, :data:`LOW` = 2; any int works).
   Each step the controller computes an overload **pressure** (max of
-  queue-depth vs ``FLAGS_shed_queue_frac``·max_queue, KV occupancy vs
+  queue depth beyond the free decode slots vs
+  ``FLAGS_shed_queue_frac``·max_queue, KV occupancy vs
   ``FLAGS_shed_kv_frac``, predicted queue wait vs ``FLAGS_shed_wait_s``
   — all zero below the ``FLAGS_shed_min_queue`` backlog floor: a full
   pool with an empty queue is a busy engine keeping up, not overload).
@@ -380,7 +381,14 @@ class OverloadController:
             return 0.0
         parts = [0.0]
         if sched.max_queue:
-            parts.append(q / max(self.queue_frac * sched.max_queue, 1.0))
+            # the backlog is what is queued beyond the free decode
+            # slots: a request a slot stands free for is an admission
+            # that has not happened yet (a closed loop opens with all
+            # its clients queued at once on an idle engine), not load
+            # the engine cannot take
+            backlog = q - (sched.cache.max_batch - len(sched.running))
+            parts.append(backlog / max(self.queue_frac * sched.max_queue,
+                                       1.0))
         # mesh-sliced caches: the KV watermark reads the BINDING slice
         # (the one the next admission would land on) — aggregate
         # headroom is a lie when the binding slice is full. Unsliced

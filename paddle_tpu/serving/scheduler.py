@@ -210,6 +210,9 @@ _m_in_order = _metrics.counter("serving.decode.in_order")
 # context tokens (of K and of V) every layer's decode attention read:
 # sum of seq_len + 1 over the live slots of each decode step
 _m_ctx_tokens = _metrics.counter("serving.decode.context_tokens")
+# slots whose recurrent state a decode step updated, summed over the
+# steps (a model with state-space layers; silent otherwise)
+_m_state_slot_steps = _metrics.counter("serving.ssm.state_slot_steps")
 _h_ttft = _metrics.histogram("serving.ttft_us", bounds=_US_BOUNDS)
 _h_itl = _metrics.histogram("serving.itl_us", bounds=_US_BOUNDS)
 _h_queue_wait = _metrics.histogram("serving.queue_wait_us",
@@ -378,8 +381,19 @@ class Scheduler:
         compute_dt = dtype if dtype is not None else jnp.bfloat16
         num_blocks = sized_num_blocks(
             num_blocks, max_batch, mbps, kv_dtype, hd, compute_dt)
+        # layers that carry state from step to step instead of K and V
+        # (models/jamba.py): the cache holds it beside the pools, and
+        # what cannot be served with it is refused here, by what the
+        # model declares
+        state_spec = getattr(model, "recurrent_state", None)
+        # what a prefill call takes beside its prompt: the kernel route,
+        # where the model's prefill has a kernel to route (a scan over
+        # the state, block attention), and nothing where it has none
+        self.prefill_route = {"kernel_mode": self.kernel_mode} \
+            if state_spec is not None or self._block_len > 1 else {}
         self.cache = PagedKVCache(
-            cfg.num_layers, cfg.num_kv_heads, hd,
+            getattr(model, "kv_cache_layers", cfg.num_layers),
+            cfg.num_kv_heads, hd, recurrent_state=state_spec,
             num_blocks=num_blocks,
             block_size=block_size, max_blocks_per_seq=mbps,
             max_batch=max_batch, dtype=compute_dt, kv_dtype=kv_dtype,
@@ -422,6 +436,12 @@ class Scheduler:
                 "serving: a block-diffusion model is served greedy "
                 "(temperature 0): the unmasking rule ranks arg-max "
                 "confidences.")
+        if state_spec is not None and armed_spec:
+            raise ValueError(
+                "serving: speculation (FLAGS_serving_spec) is not served "
+                "with a model that carries recurrent state: rejected "
+                "drafts roll K and V back by truncating blocks, and a "
+                "recurrence has no such rewind.")
         self.spec = armed_spec and temperature == 0.0
         self.prefill_token_budget = (
             flags_mod.flag("FLAGS_serving_prefill_budget")
@@ -488,6 +508,11 @@ class Scheduler:
         # called with a dict for every slot-forward of a block step when
         # set (the benchmark's reference check records through it)
         self.block_observer = None
+        # (slot, list) when set, for a model with recurrent state: every
+        # plain decode step appends what the slot's recurrence was fed
+        # (``Jamba.paged_decode_step`` reads it under the cache's lock;
+        # the benchmark's check replays the recurrence from it)
+        self.state_observer = None
 
     # -- submission / cancellation ------------------------------------
 
@@ -526,6 +551,12 @@ class Scheduler:
             validate_request(prompt, whole - prompt.size,
                              self.max_seq_len, self.cache,
                              who="serving.submit")
+        if prefill_only and self.cache.state_spec is not None:
+            raise ValueError(
+                "serving.submit: prefill_only hands a prompt's K and V "
+                "blocks to another replica; this model also carries "
+                "recurrent state, which no block holds and no transfer "
+                "frame carries.")
         if prefill_only and not self.prefix_cache:
             raise ValueError(
                 "serving.submit: prefill_only requires the prefix "
@@ -592,6 +623,11 @@ class Scheduler:
             raise HandoffError(
                 "serving.admit_handoff: a block-diffusion model is not "
                 "served disaggregated")
+        if self.cache.state_spec is not None:
+            raise HandoffError(
+                "serving.admit_handoff: imported blocks hold K and V "
+                "only; this model's recurrent state at the prompt's end "
+                "exists on no replica but the one that prefilled it")
         prompt = validate_request(prompt_ids, max_new_tokens,
                                   self.max_seq_len, self.cache,
                                   who="serving.admit_handoff")
@@ -866,7 +902,8 @@ class Scheduler:
                     tok = int(self.model.paged_prefill_extend(
                         self.cache, slot, ids, tail_start,
                         plan.write_start,
-                        temperature=self.temperature, pad_to=pad_to))
+                        temperature=self.temperature, pad_to=pad_to,
+                        **self.prefill_route))
             else:
                 pad_to = bucket_length(ids_len, bs, self.bucket_cap,
                                        max_len=self.max_seq_len)
@@ -877,7 +914,8 @@ class Scheduler:
                                    step=self._step_no):
                     tok = int(self.model.paged_prefill(
                         self.cache, slot, ids,
-                        temperature=self.temperature, pad_to=pad_to))
+                        temperature=self.temperature, pad_to=pad_to,
+                        **self.prefill_route))
             pf_us = (time.perf_counter_ns() - t_pf) / 1000.0
             with _phase("serving.admit.finish"):
                 comp_us = (_compile_s() - comp0) * 1e6
@@ -1108,13 +1146,19 @@ class Scheduler:
             active[live] = True
             batch = len(live)
             ctx_tokens = int(self.cache.seq_lens[active].sum()) + batch
+        stats, probe = {}, {}
+        if self.cache.state_spec is not None:
+            # the live slots are the ones whose state the step updates
+            stats["state_slots"] = batch
+            _m_state_slot_steps.inc(batch)
+            probe["state_observer"] = lambda: self.state_observer
         # decode compiles split across the batch
         flight = self._dispatch_decode(
             lambda: self.model.paged_decode_step(
                 self.cache, self._token_input(live), active,
                 temperature=self.temperature,
-                kernel_mode=self.kernel_mode),
-            batch, ctx_tokens)
+                kernel_mode=self.kernel_mode, **probe),
+            batch, ctx_tokens, **stats)
         flight.reqs = {s: self.running[s] for s in live}
         return flight
 

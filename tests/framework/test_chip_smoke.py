@@ -12,7 +12,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
 import chip_smoke  # noqa: E402
-from paddle_tpu.models import GPTConfig, LlamaConfig  # noqa: E402
+from paddle_tpu.models import (GPTConfig, JambaConfig,  # noqa: E402
+                               LlamaConfig)
 
 
 def test_main_refuses_without_tpu(capsys):
@@ -70,3 +71,19 @@ def test_serve_phase_tiny():
     assert facts["chunked"]["prefix_hit_blocks"] > 0
     assert facts["chunked"]["pallas_vs_dense_rel_err"] <= 1e-4
     assert logits.shape == (model.config.vocab_size,)
+
+
+def test_hybrid_phase_tiny():
+    """A prefill at a padded bucket and decode steps of a tiny Jamba on
+    the interpreted kernels against the plain route: the state agrees
+    and the idle slot's is untouched."""
+    config = JambaConfig(vocab_size=256, hidden_size=32,
+                         intermediate_size=64, num_layers=4, num_heads=4,
+                         num_kv_heads=1, attn_layer_period=4,
+                         attn_layer_offset=2, mamba_dt_rank=4)
+    facts = chip_smoke.hybrid_phase(config, 20, 5, dtype="float32",
+                                    platform="cpu", paged_kernel="pallas",
+                                    tol=1e-4)
+    assert facts["tokens"] == facts["tokens_same"] == 6
+    assert facts["state_rel_err"] <= 1e-4
+    assert facts["state_bytes_per_slot"] == 3 * (16 * 64 * 4 + 3 * 64 * 4)

@@ -180,3 +180,93 @@ def test_the_block_diffusion_programs_alias_every_pool_and_copy_none(
     # two [512, 32, 2048] float32 arrays (134 MB each); no pool beside
     assert mem.temp_size_in_bytes < (3 if program == "extend" else 1) \
         * one_pool
+
+
+# -- the hybrid model's programs (ISSUE 32) -----------------------------------
+
+JAMBA_SLOTS, JAMBA_PAGES = 128, 256
+JAMBA_BLOCKS = JAMBA_SLOTS * JAMBA_PAGES + 1
+JAMBA_POOL = (JAMBA_BLOCKS, BLOCK, 1, 128)
+JAMBA_STATE = (1, JAMBA_SLOTS, 16, 5120)       # [state layers, slots, N, E]
+JAMBA_TAIL = (1, 3, JAMBA_SLOTS, 5120)         # [state layers, K-1, slots, E]
+
+
+@pytest.fixture(scope="module")
+def jamba():
+    """AI21-Jamba2-3B's mixer and attention geometry (E 5120, N 16, R
+    160, 20 query heads on 1 KV head of 128), one layer of each kind;
+    the feed-forward and the vocabulary cut down (they touch neither
+    pool nor state) so the parameters are 60M, not 3B."""
+    from paddle_tpu.models import Jamba, JambaConfig
+
+    paddle.seed(0)
+    m = Jamba(JambaConfig(vocab_size=1024, intermediate_size=1024,
+                          num_layers=2, attn_layer_period=2,
+                          attn_layer_offset=1))
+    m.eval()
+    return m
+
+
+def _lower_jamba(model, program, one_chip):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = [s(JAMBA_POOL, jnp.bfloat16)]
+    state = (s(JAMBA_STATE, jnp.float32), s(JAMBA_TAIL, jnp.bfloat16))
+    arrs = model._param_arrays()
+    params = tuple(s(a.shape, jnp.bfloat16) for a in arrs)
+    key = jax.random.key(0)
+    key, temp = s(key.shape, key.dtype), s((), jnp.float32)
+    row, scalar = s((JAMBA_PAGES,), jnp.int32), s((), jnp.int32)
+    try:
+        jitted = model.serving_program(program, mode="pallas")._jitted
+        if program == "decode":
+            return jitted.lower(
+                params, s((JAMBA_SLOTS,), jnp.int32), pools, pools, [], [],
+                state, s((JAMBA_SLOTS, JAMBA_PAGES), jnp.int32),
+                s((JAMBA_SLOTS,), jnp.int32), s((JAMBA_SLOTS,), jnp.bool_),
+                scalar, key, temp)
+        if program == "prefill":
+            return jitted.lower(params, s((1, 512), jnp.int64), scalar, row,
+                                scalar, pools, pools, [], [], state, key,
+                                temp)
+        return jitted.lower(params, s((1, 512), jnp.int64), scalar, scalar,
+                            s((), jnp.bool_), row, scalar, pools, pools, [],
+                            [], state, key, temp)
+    finally:
+        model._param_rebind()(arrs)
+
+
+@pytest.mark.parametrize("program, kernels", [
+    ("decode", 2), ("prefill", 1), ("extend", 1)])
+def test_the_hybrid_programs_alias_pools_and_state_and_copy_none(
+        monkeypatch, one_chip, jamba, program, kernels):
+    """The recurrent state rides the programs beside the pools: the v5e
+    compiler pairs both pools and both state arrays with outputs and
+    puts no whole-pool or whole-state ``copy`` in front of the paged
+    call (20 query rows on one KV head), the state-update kernel (in
+    place in the stacked array) or the prefill's row writes."""
+    monkeypatch.setenv("PADDLE_PALLAS_FORCE_COMPILE", "1")
+    compiled = _lower_jamba(jamba, program, one_chip).compile()
+    text = compiled.as_text()
+    header = text[:text.index("\n")]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                         header)
+    assert len(aliased) == 4, header[:400]
+    held = (rf"bf16\[{JAMBA_BLOCKS},(?:16,1|16),128\]",
+            r"f32\[1,128,16,5120\]", r"bf16\[1,3,128,5120\]")
+    copies = [ln.strip()[:160] for ln in text.split("\n")
+              if any(re.search(rf"= {shape}\S* copy\(", ln)
+                     for shape in held)]
+    assert not copies, copies
+    # decode: the state update and the paged attention; a prefill: the
+    # scan (its attention is the flash kernel on the chip, XLA's here)
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    one_pool = JAMBA_BLOCKS * 16 * 128 * 2
+    state = 128 * 16 * 5120 * 4 + 3 * 128 * 5120 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * one_pool + state
+    # the extend's dense attention over the whole 4096-token context
+    # holds two [512, 20, 4096] float32 arrays (168 MB each)
+    assert mem.temp_size_in_bytes < (6 if program == "extend" else 1) \
+        * one_pool

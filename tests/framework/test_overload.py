@@ -162,8 +162,9 @@ def test_one_stalled_step_sheds_nothing_a_lasting_slowdown_does(
     hs = [eng.submit(p, max_new_tokens=2)
           for p in _prompts(21, [5] * 64)]
     ov.observe_decode(4.5e6)
-    # a third is the depth of the queue itself, 64 of 0.75 x 256
-    assert ov.pressure(eng.scheduler) == pytest.approx(1 / 3)
+    # a third is the depth of the queue itself: the 62 that no free slot
+    # stands ready for, of 0.75 x 256
+    assert ov.pressure(eng.scheduler) == pytest.approx(62 / 192)
     ov.control(eng.scheduler)
     assert all(h.status == RequestStatus.QUEUED for h in hs)
     for _ in range(12):                  # every step that slow: overload
@@ -283,6 +284,34 @@ def test_priority_shed_order_under_oversubscription(model, flags_guard):
         if h.status == RequestStatus.SHED:
             assert h.retry_after_s is not None and h.retry_after_s > 0
             assert h.tokens() == []  # never admitted, never decoded
+    eng.close()
+
+
+def test_a_closed_loops_opening_burst_is_not_backlog(model, flags_guard):
+    """Twice the slots' requests queued at once on an idle engine (a
+    closed loop opening: 256 clients on 128 slots under the flag's
+    ``max_queue`` of 256) stand past the queue's watermark, and half of
+    them have a free slot waiting: what counts is the rest. The same
+    queue behind full slots is shed."""
+    eng = _engine(model, max_batch=4, max_queue=8)
+    _prime(eng)
+    ov = eng.scheduler.overload
+    ov.min_queue = 1                       # the watermark: 0.75 x 8 = 6
+    hs = [eng.submit(p, max_new_tokens=3)
+          for p in _prompts(31, [5, 6, 7, 5, 6, 7, 5, 6])]
+    assert ov.pressure(eng.scheduler) == pytest.approx((8 - 4) / 6)
+    eng.run_until_idle()
+    assert all(h.status == RequestStatus.DONE for h in hs)
+    # four running, then eight queued behind them: nothing stands free
+    hs = [eng.submit(p, max_new_tokens=8) for p in _prompts(32, [5] * 4)]
+    eng.step()
+    assert len(eng.scheduler.running) == 4
+    late = [eng.submit(p, max_new_tokens=3, priority=overload.LOW)
+            for p in _prompts(33, [5] * 8)]
+    assert ov.pressure(eng.scheduler) == pytest.approx(8 / 6)
+    eng.run_until_idle()
+    assert sum(h.status == RequestStatus.SHED for h in late) == 3
+    assert all(h.status == RequestStatus.DONE for h in hs)
     eng.close()
 
 

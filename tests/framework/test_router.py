@@ -329,6 +329,29 @@ def test_warmup_flips_ready_and_first_request_never_compiles(aot_dir):
     eng2.close()
 
 
+@pytest.mark.parametrize("cap, programs", [
+    (16, [8, 16]),                  # the ladder ends at the cap
+    (32, [8, 16, 32]),              # cap = max_seq_len: every bucket
+    (0, [8, 16, 24, 32]),           # no bucketing: every block multiple
+])
+def test_warmup_compiles_the_ladder_to_the_cap(cap, programs):
+    """Past the cap a prompt pads to its own block multiple, one program
+    a length (192 at the flag's cap of 1024 under a max_seq_len of
+    4096): warmup() leaves those to the prompt that needs one."""
+    eng = _engine(_fresh_model(), ready=False, bucket_cap=cap)
+    assert eng.warmup() == len(programs) + 1      # + the decode program
+    c0 = _compiles()
+    h = eng.submit(_prompts(3, [programs[-1] - 2])[0], max_new_tokens=2)
+    eng.run_until_idle()
+    assert h.status == "DONE" and _compiles() == c0
+    if cap == 16:
+        # served all the same, compiled when it comes
+        h = eng.submit(_prompts(4, [20])[0], max_new_tokens=2)
+        eng.run_until_idle()
+        assert h.status == "DONE" and _compiles() > c0
+    eng.close()
+
+
 def test_warmup_raises_past_draining(model):
     eng = _engine(model)
     eng.drain()

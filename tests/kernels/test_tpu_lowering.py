@@ -235,3 +235,43 @@ def test_paged_block_attention_lowers_at_the_served_widths(pages):
     n = _lower(lambda q, k, v, t, l: paged_block_attention(
         q, k, v, t, l, kernel_mode="pallas"), *avals)
     assert n == 1
+
+
+# -- the hybrid (state-space + attention) model's kernels, at AI21-Jamba2-3B's
+# widths: E 5120, N 16, 128 slots, 26 state layers, 20 query rows on 1 KV head
+
+@pytest.mark.parametrize("positions", [1024, 64],
+                         ids=["chunks-of-128", "one-chunk"])
+def test_ssm_scan_lowers_at_the_served_widths(positions):
+    from paddle_tpu.kernels.pallas.selective_scan import selective_scan
+
+    s, e, n = positions, 5120, 16
+    avals = [_aval((s, e), jnp.float32), _aval((s, e), jnp.bfloat16),
+             _aval((s, n), jnp.bfloat16), _aval((s, n), jnp.bfloat16),
+             _aval((n, e), jnp.float32), _aval((e,), jnp.bfloat16),
+             _aval((n, e), jnp.float32), _aval((), jnp.int32)]
+    assert _lower(lambda *a: selective_scan(*a), *avals) == 1
+
+
+def test_ssm_update_lowers_at_the_served_widths():
+    from paddle_tpu.kernels.pallas.selective_scan import state_update
+
+    layers, b, e, n = 26, 128, 5120, 16
+    avals = [_aval((layers, b, n, e), jnp.float32),
+             _aval((b, e), jnp.float32), _aval((b, e), jnp.bfloat16),
+             _aval((b, n), jnp.bfloat16), _aval((b, n), jnp.bfloat16),
+             _aval((n, e), jnp.float32), _aval((e,), jnp.bfloat16),
+             _aval((b,), jnp.bool_)]
+    assert _lower(lambda st, *a: state_update(st, 7, *a), *avals) == 1
+
+
+def test_paged_decode_lowers_at_twenty_rows_on_one_kv_head():
+    from paddle_tpu.inference.paged import paged_decode_attention
+
+    b, hq, hk, d, bs, pages = 128, 20, 1, 128, 16, 256
+    pool = _aval((1 + b * pages, bs, hk, d), jnp.bfloat16)
+    avals = [_aval((b, hq, d), jnp.bfloat16), pool, pool,
+             _aval((b, pages), jnp.int32), _aval((b,), jnp.int32)]
+    n = _lower(lambda q, k, v, t, l: paged_decode_attention(
+        q, k, v, t, l, kernel_mode="pallas"), *avals)
+    assert n == 1
