@@ -20,11 +20,13 @@ no capacity, no dropped token, no shared expert, no auxiliary loss.
 Generation is by blocks of ``L = block_length`` positions
 (``docs/SERVING.md`` "Block-diffusion decoding"): a block opens all
 masked, each denoising forward runs the block's L ids against the cache
-and each other and the most confident masked positions are unmasked;
-once none is masked one more forward (the commit) writes the block's
-keys and values for good and its L tokens are emitted together. The
-model declares ``tokens_per_block``; ``serving.Scheduler`` branches on
-that and on nothing else.
+and each other and the most confident masked positions are unmasked
+(``low_confidence_static``, in the same program: the open blocks are
+the block step's input and its output, and stay on the device); once
+none is masked one more forward (the commit) writes the block's keys
+and values for good and its L tokens are emitted together. The model
+declares ``tokens_per_block``; ``serving.Scheduler`` branches on that
+and on nothing else.
 """
 
 from __future__ import annotations
@@ -41,7 +43,8 @@ from ..nn import functional as F
 from ..profiler.tracing import phase as _phase
 from .llama import PagedServingModel, _normal_attr, apply_rope
 
-__all__ = ["SDAR", "SDARConfig", "block_causal_mask"]
+__all__ = ["SDAR", "SDARConfig", "block_causal_mask",
+           "low_confidence_static"]
 
 
 @dataclasses.dataclass
@@ -97,6 +100,55 @@ def block_causal_mask(q_pos, k_pos, block_length):
     ``j // L <= i // L`` (blocks aligned to position 0)."""
     return (k_pos[None, :] // block_length) <= (q_pos[:, None]
                                                 // block_length)
+
+
+def low_confidence_static(ids, masked, opened, denoised, tokens, probs,
+                          active, steps, mask_token_id):
+    """The unmasking rule on every open block at once, in ``jax.numpy``
+    (it runs inside ``jit_sdar_block_step``). Per slot: the block's
+    ``ids`` [B, L] int32, which positions are ``masked`` [B, L] bool, the
+    masked positions it ``opened`` with and the denoising forwards it has
+    had (``denoised``) [B]; of the forward just run, each position's
+    arg-max ``tokens`` and their probabilities ``probs`` [B, L].
+
+    An active slot with masked positions is denoised: forward ``t`` of
+    ``steps`` unmasks the ``opened // steps`` (one more while ``t <
+    opened % steps``) masked positions whose arg-max token is most
+    probable, ties to the earlier position, and puts that token there. A
+    position's rank is counted by comparing it with every other: L is
+    small, and no sort is needed. An active slot with none masked has
+    had its commit forward: its next block opens all masked. A slot not
+    active keeps its state. Returns the state after, (ids, masked,
+    opened, denoised), and the positions unmasked [B, L] bool."""
+    width = ids.shape[1]
+    steps = int(steps)
+    any_masked = jnp.any(masked, axis=1)
+    denoise = active & any_masked
+    commit = active & ~any_masked
+    n = jnp.where(denoise, opened // steps
+                  + (denoised < opened % steps).astype(opened.dtype), 0)
+    conf = jnp.where(masked, probs, -jnp.inf)
+    mine, other = conf[:, :, None], conf[:, None, :]
+    pos = jnp.arange(width)
+    ahead = (other > mine) | ((other == mine)
+                              & (pos[None, None, :] < pos[None, :, None]))
+    rank = jnp.sum(ahead, axis=-1)
+    picked = (rank < n[:, None]) & masked
+    fresh = commit[:, None]
+    ids_next = jnp.where(fresh, jnp.asarray(mask_token_id, ids.dtype),
+                         jnp.where(picked, tokens.astype(ids.dtype), ids))
+    masked_next = fresh | (masked & ~picked)
+    opened_next = jnp.where(commit, width, opened).astype(opened.dtype)
+    denoised_next = jnp.where(
+        commit, 0, denoised + denoise.astype(denoised.dtype))
+    return (ids_next, masked_next, opened_next, denoised_next), picked
+
+
+def _block_state(xp, ids, masked, opened, denoised):
+    """``SDAR.block_state``'s layout, in numpy or ``jax.numpy``."""
+    return xp.concatenate(
+        [ids, masked, opened[:, None], denoised[:, None]],
+        axis=1).astype(xp.int32)
 
 
 class SDARAttention(nn.Layer):
@@ -322,20 +374,36 @@ class SDAR(PagedServingModel):
             return new_k, new_v, k_scales, v_scales
         return self._as_program(body, "sdar.paged_extend", 6, mode=mode)
 
-    def paged_block_step(self, cache, block_ids, active, kernel_mode=None,
-                         moe_sink=None):
-        """One forward of every active slot's open block: ``block_ids``
-        [B, L] sit at positions ``[seq_len, seq_len + L)``, their keys
-        and values are written there (overwriting the last forward's)
-        and each row attends ``seq_len + L`` keys, the block's own among
-        them, with no mask inside the block. ``seq_lens`` do not move:
-        the caller advances a slot whose block it commits.
+    def block_state(self, ids, masked, opened, denoised):
+        """The open blocks as the block step takes and returns them: ONE
+        int32 array [B, 2 L + 2] of, per slot, the block's ids [L], which
+        of its positions are masked [L] (its own columns: an arg-max may
+        be ``mask_token_id``), the masked positions it opened with and
+        the denoising forwards it has had."""
+        return _block_state(np, *map(np.asarray,
+                                     (ids, masked, opened, denoised)))
 
-        Returns ONE float32 device array, so that one read brings
-        everything back: ``unpack_block_step`` splits it into, per
+    def paged_block_step(self, cache, state, active, kernel_mode=None,
+                         moe_sink=None):
+        """One forward of every active slot's open block and the
+        unmasking rule on what it gave. ``state`` (``block_state``; a
+        host array, or the array the step before returned, still on the
+        device) holds the open blocks: a slot's ids sit at positions
+        ``[seq_len, seq_len + L)``, their keys and values are written
+        there (overwriting the last forward's) and each row attends
+        ``seq_len + L`` keys, the block's own among them, with no mask
+        inside the block. A slot with masked positions is denoised
+        (``low_confidence_static``); a slot with none has had its commit
+        forward, and its next block opens all masked. ``seq_lens`` do not
+        move: the caller advances a slot whose block commits.
+
+        Returns two device arrays. The first is float32 and ONE read
+        brings everything back: ``unpack_block_step`` splits it into, per
         position, the arg-max token, its logit and its softmax
-        probability, and the rows routed to each expert of each layer.
-        A ``moe_sink`` list is appended what each layer's expert
+        probability, the id and the mask the forward was fed, whether the
+        rule unmasked it, and the rows routed to each expert of each
+        layer. The second is the state after the step, the next step's
+        input. A ``moe_sink`` list is appended what each layer's expert
         layer saw and gave in this very program over the ``B * L`` rows,
         two device arrays (each output of the program costs the host
         50 us a step, chip runs PR 28): (input, output) [2, layers,
@@ -346,8 +414,8 @@ class SDAR(PagedServingModel):
         self._check_cache(cache)
         mode = resolve_paged_kernel(kernel_mode)
         with self._paged_call(cache, "block_step", mode) as (call, _):
-            packed, moe = call(
-                (jnp.asarray(block_ids, jnp.int32),),
+            packed, state, moe = call(
+                (jnp.asarray(state, jnp.int32),),
                 (cache.block_tables, jnp.asarray(cache.seq_lens),
                  jnp.asarray(active)))
         # the read-back's transfer queues behind the program now, not
@@ -355,30 +423,37 @@ class SDAR(PagedServingModel):
         packed.copy_to_host_async()
         if moe_sink is not None:
             moe_sink.append(moe)
-        return packed
+        return packed, state
 
     def unpack_block_step(self, packed, batch):
-        """(tokens [B, L] int64, logits [B, L], probabilities [B, L],
-        expert rows [layers, experts] int64) of ``paged_block_step``'s
-        array, read to the host."""
+        """``paged_block_step``'s first array, read to the host: a dict
+        of ``tokens`` [B, L] int64, ``logits`` and ``probs`` [B, L], the
+        ``ids`` [B, L] int64 and ``masked`` [B, L] bool the forward was
+        fed, ``unmasked`` [B, L] bool (the positions the rule unmasked)
+        and ``expert_rows`` [layers, experts] int64."""
         cfg = self.config
         n = batch * cfg.block_length
         packed = np.asarray(packed)
-        shape = (batch, cfg.block_length)
-        return (packed[:n].astype(np.int64).reshape(shape),
-                packed[n:2 * n].reshape(shape),
-                packed[2 * n:3 * n].reshape(shape),
-                packed[3 * n:].astype(np.int64).reshape(
-                    cfg.num_layers, cfg.num_experts))
+        per_position = packed[:6 * n].reshape(6, batch, cfg.block_length)
+        return {"tokens": per_position[0].astype(np.int64),
+                "logits": per_position[1], "probs": per_position[2],
+                "ids": per_position[3].astype(np.int64),
+                "masked": per_position[4] > 0,
+                "unmasked": per_position[5] > 0,
+                "expert_rows": packed[6 * n:].astype(np.int64).reshape(
+                    cfg.num_layers, cfg.num_experts)}
 
     def _build_block_step(self, quantized, mode):
         cfg = self.config
         block_length = cfg.block_length
 
-        def body(ids, k_pools, v_pools, k_scales, v_scales, tables, lens,
+        def body(state, k_pools, v_pools, k_scales, v_scales, tables, lens,
                  active):
             from ..inference.paged import (paged_block_attention,
                                            paged_spec_write)
+            ids = state[:, :block_length]
+            masked = state[:, block_length:2 * block_length] > 0
+            opened, denoised = state[:, -2], state[:, -1]
             b = ids.shape[0]
             whole = jnp.full((b,), block_length, jnp.int32)
             seen = jnp.where(active, lens + block_length, lens)
@@ -406,18 +481,24 @@ class SDAR(PagedServingModel):
             logits = jnp.matmul(x._data, self.lm_head.weight._data,
                                 preferred_element_type=jnp.float32)
             top = jnp.max(logits, axis=-1)
-            tok = jnp.argmax(logits, axis=-1)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             prob = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+            # the rule ranks the very float32 probabilities the host is
+            # handed
+            after, picked = low_confidence_static(
+                ids, masked, opened, denoised, tok, prob, active,
+                cfg.denoise_steps, cfg.mask_token_id)
             f32 = jnp.float32
             packed = jnp.concatenate(
-                [tok.astype(f32).reshape(-1), top.reshape(-1),
-                 prob.reshape(-1)]
+                [a.astype(f32).reshape(-1)
+                 for a in (tok, top, prob, ids, masked, picked)]
                 + [c._data.astype(f32) for c in counts])
+            state = _block_state(jnp, *after)
             moe = (jnp.stack([jnp.stack(moe_in), jnp.stack(moe_out)]),
                    jnp.stack([jnp.stack([w._data for w, _ in routed]),
                               jnp.stack([e._data.astype(f32)
                                          for _, e in routed])]))
-            return (packed, moe, *new)
+            return (packed, state, moe, *new)
         return self._as_program(body, "sdar.block_step", 2, mode=mode)
 
     def apply_serving_mesh(self, mesh):

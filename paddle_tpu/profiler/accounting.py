@@ -338,7 +338,7 @@ class Accountant:
 
     def note_block(self, req, positions, emitted):
         """``req`` took part in this step's block forward
-        (block-diffusion decoding, scheduler ``_decode_block``): the
+        (block-diffusion decoding, scheduler ``_emit_blocks``): the
         device computed ``positions`` for it, a denoising forward's as a
         commit's (the apportionment weight), and ``emitted`` tokens
         streamed to the caller: none unless the forward committed the

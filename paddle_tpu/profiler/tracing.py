@@ -326,8 +326,8 @@ PHASE_NAMES = (
     "serving.decode", "serving.decode.prepare", "serving.decode.dispatch",
     "serving.decode.readback", "serving.decode.emit", "serving.step_end")
 # inside serving.decode.emit of a block-diffusion model's step only
-# (``Scheduler._decode_block``): no other model feeds them
-BLOCK_PHASE_NAMES = ("serving.block.unmask", "serving.block.commit")
+# (``Scheduler._emit_blocks``): no other model feeds it
+BLOCK_PHASE_NAMES = ("serving.block.commit",)
 
 
 def phase_histogram_name(name):

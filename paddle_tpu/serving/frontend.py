@@ -488,10 +488,14 @@ class ServingEngine:
                 """The batched decode program: a token a slot, or a
                 block-diffusion model's block step."""
                 if width > 1:
-                    sched.model.paged_block_step(
-                        cache, np.zeros((cache.max_batch, width),
-                                        np.int64), active,
-                        kernel_mode=kernel_mode)
+                    def step(blocks):
+                        return sched.model.paged_block_step(
+                            cache, blocks, active,
+                            kernel_mode=kernel_mode)[1]
+
+                    zeros = np.zeros((cache.max_batch, width), np.int64)
+                    host = sched.model.block_state(
+                        zeros, zeros, zeros[:, 0], zeros[:, 0])
                 else:
                     def step(toks):
                         return sched.model.paged_decode_step(
@@ -499,11 +503,11 @@ class ServingEngine:
                             temperature=sched.temperature,
                             kernel_mode=kernel_mode)
 
-                    # as the loop calls it: with tokens from the host,
-                    # with a step's own output still on the device, and
-                    # with that merged with a fresh slot's token
                     host = np.zeros((cache.max_batch,), np.int64)
-                    step(merge_tokens(step(step(host)), host, active))
+                # as the loop calls it: with tokens (or open blocks) from
+                # the host, with a step's own output still on the device,
+                # and with that merged with a fresh slot's from the host
+                step(merge_tokens(step(step(host)), host, active))
 
             # role-specialized warm sets (disaggregated serving):
             # prefill replicas run ONLY the bucket ladder (they never

@@ -33,9 +33,10 @@ around every entry point). Each ``step()`` is one scheduling iteration:
    speed tiers") — several tokens per request per step, still
    bit-identical, rejected rows rolled back. A model that declares
    ``tokens_per_block`` > 1 (block-diffusion decoding, ``models/sdar.py``)
-   runs ``_decode_block`` instead: the step is one forward of every
-   slot's open block of L positions, which either unmasks some of them
-   (nothing emitted) or commits the block (L tokens emitted at once).
+   runs a block step instead (``_launch_block``; it too one step
+   ahead): one forward of every slot's open block of L positions, which
+   either unmasks some of them (on the device; nothing emitted) or
+   commits the block (L tokens emitted at once, when the step is read).
 
 Every request terminates in exactly one of ``DONE`` / ``CANCELLED`` /
 ``TIMEOUT`` / ``SHED`` (or ``ERROR`` if the engine itself died). SLO
@@ -204,7 +205,7 @@ _m_cb_errors = _metrics.counter("serving.callback_errors")
 _m_steps = _metrics.counter("serving.steps")
 # decode dispatches made while the step before was still unread on the
 # device, and those made after reading it (the first step after idle or
-# a drain, the speculative and block paths)
+# a drain, the speculative path)
 _m_ahead = _metrics.counter("serving.decode.ahead")
 _m_in_order = _metrics.counter("serving.decode.in_order")
 # context tokens (of K and of V) every layer's decode attention read:
@@ -288,25 +289,36 @@ def _token_merge():
 
 
 def merge_tokens(prev, host, fresh):
-    """A decode step's token input made on the device: ``prev``, the
-    tokens the step before returned (a device array [max_batch], never
-    read here), with ``host`` put in at the slots ``fresh`` marks — the
-    slots admitted since, whose first token a prefill gave the host."""
+    """A decode step's token input made on the device: ``prev``, what
+    the step before returned for the next (a device array, never read
+    here: a token a slot [max_batch], or a block-diffusion model's open
+    blocks [max_batch, .]), with ``host`` put in at the slots ``fresh``
+    marks — the slots admitted since, whose first token (or open block)
+    the host has."""
+    fresh = np.asarray(fresh, bool)
     return _token_merge()(prev, np.asarray(host, np.int32),
-                          np.asarray(fresh, bool))
+                          fresh.reshape(fresh.shape + (1,) * (prev.ndim - 1)))
 
 
 class _Flight:
-    """A batched decode program dispatched and not read yet: its tokens
-    (still a device array), the request each slot ran for, and what
-    timing the read-back needs."""
+    """A batched decode program dispatched and not read yet: what it
+    returned (still device arrays), the request each slot ran for, and
+    what timing the read-back needs."""
 
-    __slots__ = ("toks", "reqs", "step_no", "t_ns", "comp_us", "built",
-                 "timed")
+    __slots__ = ("toks", "feed", "reqs", "owed", "block", "step_no",
+                 "t_ns", "comp_us", "built", "timed")
 
     def __init__(self, toks, step_no, t_ns, comp_us, built):
-        self.toks = toks
-        self.reqs = {}  # slot -> request, the plain path's
+        # what the host reads back, and what the next step is fed on the
+        # device: the same array of tokens on the plain path; a block
+        # step's packed results and the open blocks after it
+        self.toks = self.feed = toks
+        self.reqs = {}  # slot -> request it ran for
+        # [max_batch]: tokens the read-back will hand each slot by count
+        # (1 a live slot; a block step: what its commits hold)
+        self.owed = None
+        # a block step's own: ``_launch_block`` says what
+        self.block = None
         self.step_no = step_no
         self.t_ns = t_ns  # as the dispatch began
         self.comp_us = comp_us
@@ -490,16 +502,22 @@ class Scheduler:
         self._last_tok = np.zeros((max_batch,), np.int64)
         self._remaining = np.zeros((max_batch,), np.int64)
         self._step_no = 0  # ``serving.steps`` as the running step began
-        # the plain decode path runs one step ahead: the step dispatched
-        # and not read yet, when the host last saw a step's tokens arrive
-        # (perf_counter_ns), and the last step time it could see (us)
+        # the plain and the block path run one step ahead: the step
+        # dispatched and not read yet, when the host last saw a step's
+        # tokens arrive (perf_counter_ns), and the last step time it
+        # could see (us)
         self._flight = None
         self._seen_ns = 0
         self._dec_us = None
-        # block-diffusion decoding, per slot: the open block's ids, which
-        # of its positions are still masked (state, never read off the
-        # ids: a prompt may hold the mask id), how many leading positions
-        # the prompt gave, and how many denoising forwards it has had
+        # block-diffusion decoding, per slot. The open blocks live on the
+        # device (the block step takes and returns them); the host has
+        # the counts every launch is made from — how many leading
+        # positions the prompt gave the open block and how many denoising
+        # forwards have been launched on it — and the block's ids and
+        # which of its positions are still masked (state, never read off
+        # the ids: a prompt may hold the mask id) as of the last step
+        # read: what a slot admitted since opens with, and what a launch
+        # with nothing in flight is fed
         n_blk = self._block_len if self._block_len > 1 else 0
         self._blk_ids = np.zeros((max_batch, n_blk), np.int64)
         self._blk_masked = np.zeros((max_batch, n_blk), bool)
@@ -981,12 +999,12 @@ class Scheduler:
         return cands[0]
 
     def _runs_next(self, slot):
-        """Whether the slot is in the next decode step: not when the one
-        token it has left is the one in flight."""
+        """Whether the slot is in the next decode step: not when the
+        tokens it has left are those the step in flight will hand it."""
         flight = self._flight
-        owed = flight is not None \
-            and flight.reqs.get(slot) is self.running[slot]
-        return self._remaining[slot] > owed
+        if flight is None or flight.reqs.get(slot) is not self.running[slot]:
+            return self._remaining[slot] > 0
+        return self._remaining[slot] > flight.owed[slot]
 
     def _make_writable(self, grow, landed=None):
         """Make the next ``grow`` positions writable (1: a token; a
@@ -1096,26 +1114,25 @@ class Scheduler:
     def _timed_decode_dispatch(self, dispatch, batch, ctx_tokens,
                                **stats):
         """One decode program dispatched and read in order
-        (``_dispatch_decode`` + ``_await_tokens``): the speculative and
-        the block path, whose next input is made on the host. Returns
-        (tokens as numpy, wall us of both)."""
+        (``_dispatch_decode`` + ``_await_tokens``): the speculative
+        path, whose next input is made on the host. Returns (tokens as
+        numpy, wall us of both)."""
         return self._await_tokens(
             self._dispatch_decode(dispatch, batch, ctx_tokens, **stats))
 
     def _decode(self):
-        """The plain path runs one step ahead. Who is in step K+1 is
-        known from the counts before step K's tokens are read, and its
-        token input *is* step K's output: so K+1 is dispatched with that
-        array, still on the device (``_launch``), and only then are K's
-        tokens read, emitted and its finished requests freed
-        (``land``) — while the device runs K+1. In order is the same
-        code with the read before the next dispatch: whatever must see
-        every token on the host first calls ``land`` (a preemption, a
-        swept running request), and a step whose next input is made on
-        the host (speculation's drafts) or that built its program is
-        read as soon as it is dispatched."""
-        if self._block_len > 1:
-            return self._decode_block() if self.running else []
+        """The plain and the block path run one step ahead. Who is in
+        step K+1 is known from the counts before step K's tokens are
+        read, and its token input *is* step K's output (a block step
+        returns the open blocks for the next): so K+1 is dispatched with
+        that array, still on the device (``_launch``,
+        ``_launch_block``), and only then are K's tokens read, emitted
+        and its finished requests freed (``land``) — while the device
+        runs K+1. In order is the same code with the read before the
+        next dispatch: whatever must see every token on the host first
+        calls ``land`` (a preemption, a swept running request), and a
+        step whose next input is made on the host (speculation's drafts)
+        or that built its program is read as soon as it is dispatched."""
         if not self.running:
             return self.land()
         if self.spec:
@@ -1126,7 +1143,8 @@ class Scheduler:
             # this step runs the plain single-token path below —
             # bit-equivalent, just not multiplied
         out = []
-        flight = self._launch(out)
+        flight = self._launch_block(out) if self._block_len > 1 \
+            else self._launch(out)
         out += self.land()
         self._flight = flight
         if flight is not None and (self.spec or flight.built):
@@ -1155,34 +1173,39 @@ class Scheduler:
         # decode compiles split across the batch
         flight = self._dispatch_decode(
             lambda: self.model.paged_decode_step(
-                self.cache, self._token_input(live), active,
-                temperature=self.temperature,
+                self.cache,
+                self._token_input(live, lambda: np.asarray(self._last_tok)),
+                active, temperature=self.temperature,
                 kernel_mode=self.kernel_mode, **probe),
             batch, ctx_tokens, **stats)
         flight.reqs = {s: self.running[s] for s in live}
+        flight.owed = active  # one token a live slot
         return flight
 
-    def _token_input(self, live):
+    def _token_input(self, live, host):
         """The token input of the step over the slots ``live``: the
-        host's last tokens with nothing in flight; else the output of
-        the step in flight, still on the device, with the slots admitted
-        since it was dispatched (their first token is on the host, from
-        their prefill) put in there."""
+        host's (``host()``: the last tokens, or a block-diffusion
+        model's open blocks) with nothing in flight; else what the step
+        in flight returned for the next, still on the device, with the
+        slots admitted since it was dispatched (their first token, or
+        their open block, is on the host, from their admission) put in
+        there."""
         prev = self._flight
         if prev is None:
-            return np.asarray(self._last_tok)
+            return host()
         fresh = [s for s in live
                  if prev.reqs.get(s) is not self.running[s]]
         if not fresh:
-            return prev.toks
+            return prev.feed
         mask = np.zeros((self.cache.max_batch,), bool)
         mask[fresh] = True
-        return merge_tokens(prev.toks, self._last_tok, mask)
+        return merge_tokens(prev.feed, host(), mask)
 
     def land(self):
         """Read the decode step in flight, if there is one: its tokens
-        reach the host, are emitted, and the requests whose count ran
-        out (or that emitted EOS) finish. Returns the (rid, token) list.
+        reach the host, are emitted (a block step's: those of the blocks
+        it committed), and the requests whose count ran out (or that
+        emitted EOS) finish. Returns the (rid, token) list.
         The loop calls it after dispatching the next step; whoever needs
         every running request's tokens on the host calls it first (a
         preemption, a cancellation, a test's in-order reference)."""
@@ -1190,28 +1213,35 @@ class Scheduler:
         if flight is None:
             return []
         toks, dec_us = self._await_tokens(flight)
-        out = []
         with _phase("serving.decode.emit"):
-            for slot, req in flight.reqs.items():
-                if self.running.get(slot) is not req:
-                    # it emitted EOS in the step before, after this one
-                    # was dispatched: this token is dropped
-                    continue
-                t = int(toks[slot])
-                self._last_tok[slot] = t
-                self._remaining[slot] -= 1
-                # the decode dispatch is one batched program: each live
-                # request's trace gets a slice of that step's wall time
-                _tracing.record_span("serving.decode_step", req.span,
-                                     dec_us, token=len(req.generated),
-                                     batch=len(flight.reqs),
-                                     route=self.kernel_route,
-                                     step=flight.step_no)
-                self.accounting.note_decode(req)
-                self._emit(req, t)
-                out.append((req.rid, t))
-                self._maybe_finish(slot)
+            out = self._emit_tokens(flight, toks, dec_us) \
+                if flight.block is None \
+                else self._emit_blocks(flight, toks, dec_us)
         _m_decoded.inc(len(out))
+        return out
+
+    def _emit_tokens(self, flight, toks, dec_us):
+        """What ``land`` does with a plain decode step's tokens."""
+        out = []
+        for slot, req in flight.reqs.items():
+            if self.running.get(slot) is not req:
+                # it emitted EOS in the step before, after this one
+                # was dispatched: this token is dropped
+                continue
+            t = int(toks[slot])
+            self._last_tok[slot] = t
+            self._remaining[slot] -= 1
+            # the decode dispatch is one batched program: each live
+            # request's trace gets a slice of that step's wall time
+            _tracing.record_span("serving.decode_step", req.span,
+                                 dec_us, token=len(req.generated),
+                                 batch=len(flight.reqs),
+                                 route=self.kernel_route,
+                                 step=flight.step_no)
+            self.accounting.note_decode(req)
+            self._emit(req, t)
+            out.append((req.rid, t))
+            self._maybe_finish(slot)
         return out
 
     # -- block-diffusion decoding (docs/SERVING.md) ---------------------
@@ -1245,9 +1275,10 @@ class Scheduler:
         return pad_to
 
     def _open_block(self, slot, given):
-        """Open the slot's next block at ``seq_len``: ``given`` ids (what
-        a prompt leaves over past its last whole block) unmasked, the
-        rest masked."""
+        """Open an admitted slot's first block at ``seq_len``, on the
+        host: ``given`` ids (what a prompt leaves over past its last
+        whole block) unmasked, the rest masked. The next launch puts it
+        in on the device."""
         g = len(given)
         self._blk_ids[slot, :g] = given
         self._blk_ids[slot, g:] = self.model.config.mask_token_id
@@ -1255,123 +1286,151 @@ class Scheduler:
         self._blk_given[slot] = g
         self._blk_denoised[slot] = 0
 
-    def _unmask(self, denoise, toks, probs):
-        """``low_confidence_static`` on every denoising slot at once: of
-        a block that opened with M masked positions, denoising forward t
-        of S unmasks the ``M // S`` (one more while ``t < M % S``) masked
-        positions whose arg-max token is most probable (ties: the
-        earlier position) and puts that token there. Returns the
-        positions unmasked, [B, L] bool."""
-        steps = int(self.model.config.denoise_steps)
-        opened = self._block_len - self._blk_given
-        n = np.where(denoise, opened // steps
-                     + (self._blk_denoised < opened % steps), 0)
-        conf = np.where(self._blk_masked, probs, -np.inf)
-        order = np.argsort(-conf, axis=1, kind="stable")
-        rank = np.argsort(order, axis=1, kind="stable")
-        pick = (rank < n[:, None]) & self._blk_masked
-        self._blk_ids[pick] = toks[pick]
-        self._blk_masked &= ~pick
-        self._blk_denoised += denoise
-        return pick
-
-    def _decode_block(self):
-        """One block step (``models/sdar.py``): ONE forward over every
-        running slot's open block, whichever phase it is in. A slot with
-        masked positions is denoised: the rule unmasks some, nothing is
-        emitted, nothing committed. A slot with none is committed: this
-        forward wrote the block's final keys and values, so ``seq_len``
-        moves past it, its tokens are emitted together (those the
-        prompt gave, and those past ``max_new_tokens``, are not) and the
-        next block opens masked. A preempted request forgets its open
-        block and re-prefills prompt + committed tokens."""
+    def _launch_block(self, landed):
+        """Prepare and dispatch the next block step (``models/sdar.py``)
+        from host counts alone; None when no running slot has a token
+        left to make. ONE forward over every such slot's open block,
+        whichever phase it is in. With a static schedule the counts say
+        which: a block that opened with M masked positions takes min(M,
+        ``denoise_steps``) denoising forwards, in which the rule (inside
+        the program) unmasks some positions and nothing is emitted, and
+        then one commit forward, which writes the block's final keys and
+        values: ``seq_len`` moves past it here, at dispatch, and the next
+        block opens masked. Which positions were unmasked, and to which
+        tokens, only the device knows until ``land`` reads the step: the
+        open blocks are the program's own output fed back
+        (``_token_input``). Tokens of a step it had to read first go to
+        ``landed``."""
         width = self._block_len
-        nb = self.cache.max_batch
         with _phase("serving.decode.prepare"):
             # the open block's rows are rewritten every step
-            self._make_writable(width)
-            if not self.running:
-                return []
-            active = np.zeros((nb,), bool)
-            active[list(self.running)] = True
-            denoise = active & self._blk_masked.any(axis=1)
-            batch = len(self.running)
+            self._make_writable(width, landed)
+            live = [s for s in self.running if self._runs_next(s)]
+            if not live:
+                return None
+            active = np.zeros((self.cache.max_batch,), bool)
+            active[live] = True
+            opened = width - self._blk_given
+            denoise = active & (self._blk_denoised < np.minimum(
+                opened, int(self.model.config.denoise_steps)))
+            commit = active & ~denoise
+            batch = len(live)
             n_denoise = int(denoise.sum())
-            ctx_tokens = int(self.cache.seq_lens[active].sum()) \
-                + batch * width
-
+            lens = self.cache.seq_lens
+            ctx_tokens = int(lens[active].sum()) + batch * width
+            state = self._token_input(live, lambda: self.model.block_state(
+                self._blk_ids, self._blk_masked, opened,
+                self._blk_denoised))
         # read once: another thread may take the observer away mid-step.
         # It is also shown what each expert layer saw and gave
         observer = self.block_observer
         moe = [] if observer is not None else None
-        packed, dec_us = self._timed_decode_dispatch(
+        flight = self._dispatch_decode(
             lambda: self.model.paged_block_step(
-                self.cache, self._blk_ids, active,
-                kernel_mode=self.kernel_mode, moe_sink=moe),
+                self.cache, state, active, kernel_mode=self.kernel_mode,
+                moe_sink=moe),
             batch, ctx_tokens, rows=batch * width,
             denoise_slots=n_denoise, commit_slots=batch - n_denoise)
+        flight.toks, flight.feed = flight.toks
+        flight.reqs = {s: self.running[s] for s in live}
+        # a commit hands out the block's tokens past those the prompt
+        # gave, up to the request's count
+        flight.owed = np.where(
+            commit, np.minimum(opened, self._remaining), 0)
+        # what ``_emit_blocks`` needs of this step as it was launched:
+        # who commits, the positions each block opened with, the
+        # committed lengths the forward ran at (the array the program was
+        # handed: nothing writes it again), the observer and its arrays
+        flight.block = (commit, opened, lens, batch, observer,
+                        moe[0] if moe else None)
+        # the counts move on now: the next launch is made from them
+        self._blk_denoised = np.where(commit, 0,
+                                      self._blk_denoised + denoise)
+        self._blk_given = np.where(commit, 0, self._blk_given)
+        self.cache.seq_lens = np.where(commit, lens + width,
+                                       lens).astype(np.int32)
+        return flight
+
+    def _emit_blocks(self, flight, packed, dec_us):
+        """What ``land`` does with a block step: the counters, the
+        observer's records, the host's copy of the open blocks, and for
+        every slot whose block the step committed its tokens, emitted
+        together (those the prompt gave, and those past
+        ``max_new_tokens``, are not). Every id is the device's own: what
+        the forward was fed and what the rule did rides the read-back."""
+        width = self._block_len
+        commit, opened, lens, batch, observer, moe = flight.block
+        got = self.model.unpack_block_step(packed, self.cache.max_batch)
+        expert_rows, picked = got["expert_rows"], got["unmasked"]
+        _m_moe_rows.inc(int(expert_rows.sum()))
+        _m_moe_hit.inc(int((expert_rows > 0).sum()))
+        _m_moe_max.inc(int(expert_rows.max(axis=1).sum()))
+        # a slot that emitted EOS in the step before, after this one was
+        # dispatched, ran in it for nothing: its result is dropped
+        mine = [slot for slot, req in flight.reqs.items()
+                if self.running.get(slot) is req]
+        kept = np.zeros((self.cache.max_batch,), bool)
+        kept[mine] = True
+        commits = kept & commit
+        n_commit = int(commits.sum())
+        _m_blk_denoise.inc(len(mine) - n_commit)
+        _m_blk_commit.inc(n_commit)
+        _m_blk_unmasked.inc(int(picked[kept].sum()))
+        if observer is not None:
+            for slot in mine:
+                observer({
+                    "rid": flight.reqs[slot].rid, "step": flight.step_no,
+                    "seq_len": int(lens[slot]),
+                    "ids": got["ids"][slot], "masked": got["masked"][slot],
+                    "commit": bool(commit[slot]),
+                    "tokens": got["tokens"][slot],
+                    "logits": got["logits"][slot],
+                    "probs": got["probs"][slot], "unmasked": picked[slot],
+                    # of the whole step: the slots live in it, the
+                    # rows each expert of each layer got, and the
+                    # expert layers' (input, output) and (router's
+                    # weights, expert ids), two device arrays [2,
+                    # layers, rows, .] whose rows ``moe_rows`` are
+                    # this slot's
+                    "batch": batch, "expert_rows": expert_rows,
+                    "moe": moe,
+                    "moe_rows": slice(slot * width, (slot + 1) * width)})
+        # the open blocks after this step, for a launch with nothing in
+        # flight: the rule's picks filled in, a committed block's
+        # successor all masked
+        self._blk_ids[kept] = np.where(
+            commits[:, None], self.model.config.mask_token_id,
+            np.where(picked, got["tokens"], got["ids"]))[kept]
+        self._blk_masked[kept] = (
+            commits[:, None] | (got["masked"] & ~picked))[kept]
         out = []
-        with _phase("serving.decode.emit"):
-            toks, logits, probs, expert_rows = \
-                self.model.unpack_block_step(packed, nb)
-            _m_moe_rows.inc(int(expert_rows.sum()))
-            _m_moe_hit.inc(int((expert_rows > 0).sum()))
-            _m_moe_max.inc(int(expert_rows.max(axis=1).sum()))
-            _m_blk_denoise.inc(n_denoise)
-            _m_blk_commit.inc(batch - n_denoise)
-            before = (self._blk_ids.copy(), self._blk_masked.copy()) \
-                if observer is not None else None
-            with _phase("serving.block.unmask"):
-                picked = self._unmask(denoise, toks, probs)
-                _m_blk_unmasked.inc(int(picked.sum()))
-            if before is not None:
-                for slot, req in self.running.items():
-                    observer({
-                        "rid": req.rid, "step": self._step_no,
-                        "seq_len": int(self.cache.seq_lens[slot]),
-                        "ids": before[0][slot], "masked": before[1][slot],
-                        "commit": not denoise[slot],
-                        "tokens": toks[slot], "logits": logits[slot],
-                        "probs": probs[slot], "unmasked": picked[slot],
-                        # of the whole step: the slots live in it, the
-                        # rows each expert of each layer got, and the
-                        # expert layers' (input, output) and (router's
-                        # weights, expert ids), two device arrays [2,
-                        # layers, rows, .] whose rows ``moe_rows`` are
-                        # this slot's
-                        "batch": batch, "expert_rows": expert_rows,
-                        "moe": moe[0] if moe else None,
-                        "moe_rows": slice(slot * width,
-                                             (slot + 1) * width)})
-            with _phase("serving.block.commit"):
-                # plain lists: this loop runs for every slot every step
-                commits = (~denoise).tolist()
-                given = self._blk_given.tolist()
-                remaining = self._remaining.tolist()
-                for slot, req in list(self.running.items()):
-                    _tracing.record_span(
-                        "serving.decode_step", req.span, dec_us,
-                        token=len(req.generated), batch=batch,
-                        route=self.kernel_route, step=self._step_no,
-                        commit=commits[slot])
-                    if not commits[slot]:
-                        self.accounting.note_block(req, width, 0)
-                        continue
-                    self.cache.seq_lens[slot] += width
-                    new = self._blk_ids[slot, given[slot]:].tolist()
-                    emitted = 0
-                    for t in new[:remaining[slot]]:
-                        emitted += 1
-                        self._emit(req, t)
-                        out.append((req.rid, t))
-                        if t == self.eos_token_id:
-                            break
-                    self._remaining[slot] -= emitted
-                    self.accounting.note_block(req, width, emitted)
-                    self._open_block(slot, ())
-                    self._maybe_finish(slot)
-                _m_blk_blocks.inc(batch - n_denoise)
-        _m_decoded.inc(len(out))
+        with _phase("serving.block.commit"):
+            # plain lists: this loop runs for every slot every step
+            commits = commits.tolist()
+            given = (width - opened).tolist()
+            remaining = self._remaining.tolist()
+            for slot in mine:
+                req = flight.reqs[slot]
+                _tracing.record_span(
+                    "serving.decode_step", req.span, dec_us,
+                    token=len(req.generated), batch=batch,
+                    route=self.kernel_route, step=flight.step_no,
+                    commit=commits[slot])
+                if not commits[slot]:
+                    self.accounting.note_block(req, width, 0)
+                    continue
+                new = got["ids"][slot, given[slot]:].tolist()
+                emitted = 0
+                for t in new[:remaining[slot]]:
+                    emitted += 1
+                    self._emit(req, t)
+                    out.append((req.rid, t))
+                    if t == self.eos_token_id:
+                        break
+                self._remaining[slot] -= emitted
+                self.accounting.note_block(req, width, emitted)
+                self._maybe_finish(slot)
+            _m_blk_blocks.inc(n_commit)
         return out
 
     def _decode_spec(self):

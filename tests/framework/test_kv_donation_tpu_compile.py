@@ -138,8 +138,10 @@ def _lower_sdar(model, program, one_chip):
     try:
         jitted = model.serving_program(program, mode="pallas")._jitted
         if program == "block_step":
+            # the open blocks: ids, mask, opened with, forwards done
             return jitted.lower(
-                params, s((SDAR_SLOTS, 4), jnp.int32), pools, pools, [],
+                params, s((SDAR_SLOTS, 2 * 4 + 2), jnp.int32), pools,
+                pools, [],
                 [], s((SDAR_SLOTS, PAGES), jnp.int32),
                 s((SDAR_SLOTS,), jnp.int32), s((SDAR_SLOTS,), jnp.bool_))
         if program == "prefill":
@@ -161,7 +163,16 @@ def test_the_block_diffusion_programs_alias_every_pool_and_copy_none(
     device operation of the cell's first traced run (PERF.md, PR 28):
     the prefill writes row by row, as the extend and the step do."""
     monkeypatch.setenv("PADDLE_PALLAS_FORCE_COMPILE", "1")
-    compiled = _lower_sdar(sdar, program, one_chip).compile()
+    lowered = _lower_sdar(sdar, program, one_chip)
+    if program == "block_step":
+        # beside the pools: the packed read-back, the two arrays of the
+        # expert layers, and ONE array more since the rule runs in the
+        # program (the open blocks after the step), not four: every
+        # output costs the host ~50 us a step (PERF.md, PR 28)
+        outs = [o.shape for o in jax.tree.leaves(lowered.out_info)]
+        assert len(outs) == 4 + 2 * SDAR_LAYERS, outs
+        assert outs[1] == (SDAR_SLOTS, 2 * 4 + 2)
+    compiled = lowered.compile()
     text = compiled.as_text()
     header = text[:text.index("\n")]
     aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",

@@ -237,6 +237,29 @@ def test_paged_block_attention_lowers_at_the_served_widths(pages):
     assert n == 1
 
 
+def test_the_unmasking_rule_lowers_without_a_sort():
+    """``low_confidence_static`` runs inside ``jit_sdar_block_step``
+    (64 slots, blocks of 4, 2 denoising steps): it lowers for the TPU as
+    plain comparisons, no sort, and gives back the state's own dtypes
+    under 64-bit mode (the state is one int32 array fed back)."""
+    from paddle_tpu.models.sdar import low_confidence_static
+
+    b, width = 64, 4
+    avals = [_aval((b, width), jnp.int32), _aval((b, width), jnp.bool_),
+             _aval((b,), jnp.int32), _aval((b,), jnp.int32),
+             _aval((b, width), jnp.int32), _aval((b, width), jnp.float32),
+             _aval((b,), jnp.bool_)]
+
+    def rule(*a):
+        return low_confidence_static(*a, 2, 151669)
+    exp = export.export(jax.jit(rule), platforms=["tpu"])(*avals)
+    assert "stablehlo.sort" not in exp.mlir_module()
+    assert "stablehlo.compare" in exp.mlir_module()
+    (ids, masked, opened, denoised), picked = jax.eval_shape(rule, *avals)
+    assert [a.dtype for a in (ids, masked, opened, denoised, picked)] \
+        == [jnp.int32, jnp.bool_, jnp.int32, jnp.int32, jnp.bool_]
+
+
 # -- the hybrid (state-space + attention) model's kernels, at AI21-Jamba2-3B's
 # widths: E 5120, N 16, 128 slots, 26 state layers, 20 query rows on 1 KV head
 
