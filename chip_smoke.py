@@ -598,6 +598,101 @@ def hybrid_phase(config, prompt_len, steps, *, dtype, platform,
 
 
 # ---------------------------------------------------------------------------
+# a latent-attention model: one row a token in the paged cache
+# ---------------------------------------------------------------------------
+
+def latent_phase(config, lengths, new_tokens, *, dtype, platform,
+                 paged_kernel, tol):
+    """A small Xing (``models/xing.py``: latent attention, sigmoid-routed
+    experts beside a shared one, hyper-connection streams) through
+    ``ServingEngine`` on the kernels' route (``paged_kernel``: the
+    absorbed decode kernel over the latent pools, the grouped expert
+    matmuls): prompts of ``lengths``, the second sharing the first's
+    first two pages (a prefix hit through the tail-extend program).
+    Then the same prompts on the plain route, fed the served tokens: at
+    every decode step the served token must lie within ``tol`` of the
+    plain route's maximum logit, as a share of the logits' scale."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.inference.paged import PagedKVCache
+    from paddle_tpu.models import Xing
+    from paddle_tpu.profiler import metrics
+    from paddle_tpu.serving import ServingEngine
+
+    model = _build(Xing, config, dtype)
+    _require_on(platform, model, "latent")
+    rng = np.random.default_rng(0)
+    block = 16
+    prompts = [rng.integers(3, config.vocab_size, size=n) for n in lengths]
+    prompts[1][:2 * block] = prompts[0][:2 * block]
+    longest = max(lengths) + new_tokens
+    pages = -(-longest // block)
+    before = metrics.snapshot("serving.")
+    with ServingEngine(model, temperature=0.0, dtype=jnp.dtype(dtype),
+                       max_batch=4, block_size=block,
+                       max_seq_len=pages * block, bucket_cap=pages * block,
+                       paged_kernel=paged_kernel) as engine:
+        served = [list(engine.submit(prompts[0], max_new_tokens=new_tokens)
+                       .result(timeout=600))]
+        handles = [engine.submit(p, max_new_tokens=new_tokens)
+                   for p in prompts[1:]]
+        served += [list(h.result(timeout=600)) for h in handles]
+    moved = {k: v - before.get(k, 0)
+             for k, v in metrics.snapshot("serving.").items()
+             if isinstance(v, (int, float))}
+    _require(moved.get("serving.kernel.mla_decode.pallas", 0) > 0
+             and moved.get("serving.kernel.mla_decode.plain", 0) == 0,
+             "latent: the absorbed decode attention did not take the "
+             "kernels' route")
+    _require(moved.get("serving.prefix.hit_blocks", 0) >= 2,
+             "latent: the shared pages were not a prefix hit")
+    _require(moved.get("serving.moe.rows", 0) > 0,
+             "latent: the decode steps counted no expert rows")
+    vocab = config.vocab_size
+    _require(all(len(t) == new_tokens and all(0 <= x < vocab for x in t)
+                 for t in served), "latent: a request ended short")
+    # the plain route, fed the served tokens
+    cache = PagedKVCache(
+        model.kv_cache_layers, config.num_kv_heads, config.head_dim,
+        num_blocks=4 * pages + 1, block_size=block,
+        max_blocks_per_seq=pages, max_batch=4, dtype=jnp.dtype(dtype),
+        latent_rows=model.latent_rows)
+    worst, first_same = 0.0, 0
+    for prompt, toks in zip(prompts, served):
+        slot = cache.alloc_slot(len(prompt))
+        first_same += model.paged_prefill(
+            cache, slot, prompt, kernel_mode="dense") == toks[0]
+        active = np.zeros((4,), bool)
+        active[slot] = True
+        last = np.zeros((4,), np.int64)
+        taps = []
+        for tok in toks[:-1]:
+            last[slot] = tok
+            _require(cache.ensure_capacity(
+                slot, int(cache.seq_lens[slot]) + 1), "latent: no block")
+            model.paged_decode_step(cache, last, active,
+                                    kernel_mode="dense",
+                                    state_observer=lambda: (slot, taps))
+        for (_, tap), tok in zip(taps, toks[1:]):
+            logits = model.unpack_tap(tap)[1]["logits"]
+            worst = max(worst, float(logits.max() - logits[tok])
+                        / float(np.abs(logits).max()))
+        cache.free_slot(slot)
+    _require(worst <= tol, f"latent: a served token lies {worst:.3e} of "
+             f"the logits' scale under the plain route's choice "
+             f"(tolerance {tol:.3e})")
+    _require(first_same >= len(prompts) - 1, "latent: the prefills' first "
+             "tokens differ between the two routes")
+    return {"margin": float(f"{worst:.3e}"),
+            "tokens": sum(map(len, served)),
+            "prefix_hit_blocks": int(moved["serving.prefix.hit_blocks"]),
+            "moe_rows": int(moved["serving.moe.rows"]),
+            "latent_bytes_per_token":
+                cache.pool_bytes() // (cache.num_blocks * block)}
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 
@@ -727,7 +822,8 @@ def main():
               file=sys.stderr)
         return 2
 
-    from paddle_tpu.models import GPTConfig, JambaConfig, LlamaConfig
+    from paddle_tpu.models import (GPTConfig, JambaConfig, LlamaConfig,
+                                   XingConfig)
     from paddle_tpu.utils import configure_compile_cache
 
     cache_dir = configure_compile_cache()
@@ -779,6 +875,18 @@ def main():
         num_layers=4, num_heads=2, num_kv_heads=1, attn_layer_period=4,
         attn_layer_offset=2, mamba_dt_rank=16), 100, 8, dtype=dtype,
         platform="tpu", paged_kernel=None, tol=BF16_TOL)
+
+    # a latent row of 128 + 64 (whole lane tiles, and half of one) under
+    # 4 heads, 8 experts beside a shared one, 4 streams: the absorbed
+    # decode kernel's lowering and the engine's path, every call
+    _timed("latent", latent_phase, XingConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_layers=3, num_heads=4,
+        num_kv_heads=4, q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=8, num_experts_per_tok=2,
+        first_k_dense_replace=1), (70, 50, 120), 12, dtype=dtype,
+        platform="tpu", paged_kernel=None, tol=2 * BF16_TOL)
 
     if device["count"] >= 4:
         _timed("four_chip_serve", four_chip_serve, model, prompt, logits,

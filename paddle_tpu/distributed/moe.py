@@ -40,7 +40,8 @@ from .placement import Replicate, Shard
 _GMM_PALLAS = _metrics.counter("serving.kernel.moe_gmm.pallas")
 _GMM_PLAIN = _metrics.counter("serving.kernel.moe_gmm.plain")
 
-__all__ = ["MoELayer", "TopKGate", "DroplessMoE", "dropless_moe"]
+__all__ = ["MoELayer", "TopKGate", "DroplessMoE", "dropless_moe",
+           "route_topk", "route_sigmoid_topk"]
 
 
 def _one_hot(idx, n):
@@ -204,24 +205,47 @@ def gmm_route(kernel_mode=None):
     return "plain" if route == "dense" else route
 
 
+def _router_logits(x, router_w):
+    """x [T, d] times the router [d, E] in float32 at ``highest``
+    precision (the TPU's default would round the float32 operands to
+    bfloat16)."""
+    return jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                      precision=_HIGHEST)
+
+
 def route_topk(x, router_w, top_k, norm_topk_prob=True):
     """The router: softmax over ALL experts in float32 (the product at
     ``highest`` precision: the TPU's default would round the float32
     operands to bfloat16), the ``top_k`` largest, renormalised over the
     chosen ones when ``norm_topk_prob``. x [T, d] -> (weights [T, k]
     float32, expert ids [T, k] int32)."""
-    logits = jnp.matmul(x.astype(jnp.float32),
-                        router_w.astype(jnp.float32), precision=_HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = jax.nn.softmax(_router_logits(x, router_w), axis=-1)
     w, idx = jax.lax.top_k(probs, top_k)
     if norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w, idx.astype(jnp.int32)
 
 
+def route_sigmoid_topk(x, router_w, bias, top_k, norm_topk_prob=True,
+                       scaling=1.0):
+    """The ``noaux_tc`` router with one group: sigmoid scores over ALL
+    experts in float32 (the product at ``highest`` precision, as
+    :func:`route_topk`), the ``top_k`` largest of ``scores + bias`` (the
+    correction ``bias`` [E] picks and never weighs), their own scores
+    renormalised over the chosen ones when ``norm_topk_prob``, times
+    ``scaling``. x [T, d] -> (weights [T, k] float32, expert ids [T, k]
+    int32)."""
+    scores = jax.nn.sigmoid(_router_logits(x, router_w))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * jnp.float32(scaling), idx.astype(jnp.int32)
+
+
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k,
                  norm_topk_prob=True, expert_lo=0, route="plain",
-                 valid=None, kernel_tag=""):
+                 valid=None, kernel_tag="", router=None, shared=None):
     """The part of a SwiGLU expert layer's output that the experts
     ``[expert_lo, expert_lo + E_held)`` give, with nothing dropped.
 
@@ -236,12 +260,17 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k,
     (weights [T, k] float32, expert ids [T, k])). ``route``:
     ``gmm_route``; ``kernel_tag`` ends the Pallas calls' names
     (``moe_gmm_swiglu<tag>``, ``moe_gmm<tag>``), so that a trace tells
-    one program's calls from another's."""
+    one program's calls from another's. ``router(x, router_w) ->
+    (weights [T, k], expert ids [T, k])`` stands in for the softmax
+    top-k of :func:`route_topk`; ``shared`` = (gate [d, f], up [d, f],
+    down [f, d]) is an expert every row passes, unweighted, added where
+    it is handed in (one holder of a split layer hands it in)."""
     from ..kernels.pallas import moe_gmm as K
     t, d = x.shape
     n_experts = router_w.shape[1]
     held = w_gate.shape[0]
-    weights, idx = route_topk(x, router_w, top_k, norm_topk_prob)
+    weights, idx = route_topk(x, router_w, top_k, norm_topk_prob) \
+        if router is None else router(x, router_w)
     flat = idx.reshape(-1)                              # [A], A = T * k
     a = flat.shape[0]
     real = jnp.ones((a,), bool) if valid is None \
@@ -279,13 +308,26 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k,
         jnp.minimum(dest_sorted, m - 1))
     picked = jnp.where(mine[:, None], y[dest].astype(jnp.float32), 0.0)
     out = jnp.sum(picked.reshape(t, top_k, d) * weights[..., None], axis=1)
+    if shared is not None:
+        s_gate, s_up, s_down = shared
+        f32 = jnp.float32
+        h = jax.nn.silu(jnp.matmul(x, s_gate, preferred_element_type=f32)) \
+            * jnp.matmul(x, s_up, preferred_element_type=f32)
+        out = out + jnp.matmul(h.astype(x.dtype), s_down,
+                               preferred_element_type=f32)
     return out.astype(x.dtype), counts, (weights, idx)
 
 
 class DroplessMoE(nn.Layer):
     """SwiGLU expert layer with dropless top-k routing (no capacity, no
-    shared expert, no auxiliary loss): what a served sparse decoder
-    runs. The experts' matrices are created stacked, ``[E_held, ...]``,
+    auxiliary loss): what a served sparse decoder runs. ``scoring``
+    ``softmax`` routes by :func:`route_topk`; ``sigmoid`` by
+    :func:`route_sigmoid_topk`, with a correction bias [E] of its own
+    (``e_score_correction_bias``) and ``routed_scaling_factor``.
+    ``shared_width`` > 0 adds one shared expert of that width that every
+    row passes; where the layer is split by ``expert_range`` the holder
+    of expert 0 computes it, so that the parts still sum to the layer.
+    The experts' matrices are created stacked, ``[E_held, ...]``,
     one parameter a projection. ``expert_range=(lo, hi)`` is the range
     of experts this holder computes (all by default): the router still
     scores all ``num_experts``, which is what expert parallelism needs,
@@ -297,8 +339,13 @@ class DroplessMoE(nn.Layer):
     [T, k] float32, expert ids [T, k]) as this forward computed them."""
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
-                 norm_topk_prob=True, expert_range=None, weight_attr=None):
+                 norm_topk_prob=True, expert_range=None, weight_attr=None,
+                 scoring="softmax", routed_scaling_factor=1.0,
+                 shared_width=0, bias_attr=None):
         super().__init__()
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"DroplessMoE: scoring {scoring!r} is "
+                             "neither 'softmax' nor 'sigmoid'")
         lo, hi = expert_range or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
             raise ValueError(f"DroplessMoE: expert_range {(lo, hi)} is "
@@ -316,6 +363,19 @@ class DroplessMoE(nn.Layer):
             shape=[held, d_model, d_expert], attr=weight_attr)
         self.down_proj = self.create_parameter(
             shape=[held, d_expert, d_model], attr=weight_attr)
+        self.scoring = scoring
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.e_score_correction_bias = self.create_parameter(
+            shape=[num_experts], attr=bias_attr, is_bias=True) \
+            if scoring == "sigmoid" else None
+        self.holds_shared = bool(shared_width) and lo == 0
+        if self.holds_shared:
+            self.shared_gate_proj = self.create_parameter(
+                shape=[d_model, shared_width], attr=weight_attr)
+            self.shared_up_proj = self.create_parameter(
+                shape=[d_model, shared_width], attr=weight_attr)
+            self.shared_down_proj = self.create_parameter(
+                shape=[shared_width, d_model], attr=weight_attr)
 
     def forward(self, x, kernel_mode=None, counts_sink=None, valid=None,
                 kernel_tag="", route_sink=None):
@@ -325,19 +385,31 @@ class DroplessMoE(nn.Layer):
         # plain values in the closure: they are part of the dispatch
         # cache's key
         top_k, norm, lo = self.top_k, self.norm_topk_prob, self.expert_lo
+        sigmoid, scaling = self.scoring == "sigmoid", \
+            self.routed_scaling_factor
+        shared, masked = self.holds_shared, valid is not None
+        extras = ([valid] if masked else []) \
+            + ([self.e_score_correction_bias] if sigmoid else []) \
+            + ([self.shared_gate_proj, self.shared_up_proj,
+                self.shared_down_proj] if shared else [])
 
-        def pure(xa, router, wg, wu, wd, *rows):
+        def pure(xa, router, wg, wu, wd, *rest):
+            rest = list(rest)
+            rows = rest.pop(0) if masked else None
+            bias = rest.pop(0) if sigmoid else None
             y, counts, (weights, idx) = dropless_moe(
                 xa.reshape(-1, xa.shape[-1]), router, wg, wu, wd,
                 top_k=top_k, norm_topk_prob=norm, expert_lo=lo,
-                route=route, valid=rows[0] if rows else None,
-                kernel_tag=kernel_tag)
+                route=route, valid=rows, kernel_tag=kernel_tag,
+                router=(lambda m, rw: route_sigmoid_topk(
+                    m, rw, bias, top_k, norm, scaling)) if sigmoid
+                else None,
+                shared=tuple(rest) if shared else None)
             return y.reshape(xa.shape), counts, weights, idx
 
         out, counts, weights, idx = apply(
             pure, x, self.router, self.gate_proj, self.up_proj,
-            self.down_proj, *([] if valid is None else [valid]),
-            name="dropless_moe")
+            self.down_proj, *extras, name="dropless_moe")
         if counts_sink is not None:
             counts_sink.append(counts)
         if route_sink is not None:

@@ -74,6 +74,9 @@ _KV_COPIED = _metrics.counter("serving.kv.copied_calls")
 # bytes of recurrent state a cache holds beside its pools (0 where every
 # layer of the model is attention)
 _STATE_BYTES = _metrics.gauge("serving.ssm.state_bytes")
+# bytes of the pools of a latent cache (one row a token a layer shared by
+# every head; silent for a cache of K and V a head)
+_LATENT_BYTES = _metrics.gauge("serving.mla.latent_bytes")
 
 __all__ = ["PagedKVCache", "paged_prefill_write",
            "paged_prefill_write_masked", "paged_decode_attention",
@@ -83,7 +86,7 @@ __all__ = ["PagedKVCache", "paged_prefill_write",
            "paged_spec_write", "paged_spec_attention_dense",
            "ContinuousBatchingEngine", "validate_request",
            "chunk_digests", "PrefixPlan", "CapacityError",
-           "RecurrentStateSpec",
+           "RecurrentStateSpec", "LatentRowSpec",
            "resolve_kv_dtype", "quant_block_ratio",
            "resolve_paged_kernel", "kernel_route"]
 
@@ -234,6 +237,25 @@ class RecurrentStateSpec:
     conv_tail: int
 
 
+@dataclass(frozen=True)
+class LatentRowSpec:
+    """What a layer of latent attention (MLA) caches of a token: ONE row
+    shared by all heads, its first ``latent`` values the compressed keys
+    *and* values (a head's keys and values are rebuilt from them, or
+    the projection is folded into the query and the output) and its last
+    ``rope`` values the rotary keys. The rotary keys lie in a pool of
+    their own whose rows are ``rope_lanes`` wide, whole 128-lane tiles
+    with zeros behind the keys: the chip lays a narrower row out so
+    anyway, and a kernel's DMA moves whole tiles."""
+
+    latent: int
+    rope: int
+
+    @property
+    def rope_lanes(self):
+        return -(-self.rope // 128) * 128
+
+
 @dataclass
 class PrefixPlan:
     """Host-side admission plan from ``PagedKVCache.plan_prefix``: which
@@ -300,6 +322,18 @@ class PagedKVCache:
     from zero. Such a cache plans no prefix hit and registers no chunk:
     the state at a prefix's end exists nowhere (docs/SERVING.md).
 
+    **Latent rows** (``latent_rows``, a :class:`LatentRowSpec`;
+    ``models/xing.py``): another pool geometry under the same tables,
+    refcounts, prefix index, copy-on-write and donation. A layer's
+    ``k_pools[i]`` is ``[blocks, page, 1, latent]`` and holds a token's
+    compressed keys-and-values, ``v_pools[i]`` is ``[blocks, page, 1,
+    rope_lanes]`` and holds its rotary keys: there is no separate V, and no
+    head axis to shard. The two lists keep their names because every
+    block-level mechanism moves them together and asks nothing of their
+    widths. Prefix sharing works as for K and V (a block's rows are all
+    a position's state). Refused at construction, with the reason: int8
+    pools and a serving mesh (docs/SERVING.md).
+
     **Prefix sharing** (vLLM shared-block / SGLang RadixAttention
     style): a block registered in the prefix index is immutable in its
     registered rows and may back several slots at once (refcount > 1).
@@ -317,7 +351,23 @@ class PagedKVCache:
     def __init__(self, num_layers, num_kv_heads, head_dim, *, num_blocks,
                  block_size=16, max_blocks_per_seq, max_batch,
                  dtype=jnp.bfloat16, kv_dtype=None, pool_sharding=None,
-                 scale_sharding=None, num_slices=1, recurrent_state=None):
+                 scale_sharding=None, num_slices=1, recurrent_state=None,
+                 latent_rows=None):
+        self.latent_spec = latent_rows
+        if latent_rows is not None:
+            if resolve_kv_dtype(kv_dtype) == "int8":
+                raise ValueError(
+                    "PagedKVCache: a latent cache holds bfloat16/float32 "
+                    "rows only: int8 KV (FLAGS_kv_cache_dtype=int8) "
+                    "scales a row a KV head, and a latent row's rotary "
+                    "part and compressed part need scales of their own, "
+                    "which no kernel reads yet.")
+            if pool_sharding is not None or int(num_slices) > 1:
+                raise ValueError(
+                    "PagedKVCache: a latent cache is held on one device: "
+                    "a serving mesh shards the pools by KV head, and a "
+                    "latent row has none.")
+            num_kv_heads, head_dim = 1, int(latent_rows.latent)
         self.num_layers = num_layers
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -366,9 +416,12 @@ class PagedKVCache:
             z = jnp.zeros(sh, dt)
             return z if sharding is None else jax.device_put(z, sharding)
 
+        # a latent cache's second list holds the rotary keys, narrower
+        v_shape = shape if latent_rows is None \
+            else shape[:3] + (latent_rows.rope_lanes,)
         self.k_pools = [_pool(shape, store_dt, pool_sharding)
                         for _ in range(num_layers)]
-        self.v_pools = [_pool(shape, store_dt, pool_sharding)
+        self.v_pools = [_pool(v_shape, store_dt, pool_sharding)
                         for _ in range(num_layers)]
         if self.quantized:
             sshape = (num_blocks, block_size, num_kv_heads)
@@ -396,6 +449,8 @@ class PagedKVCache:
             self.conv_state = jnp.zeros(
                 (st.layers, st.conv_tail, max_batch, st.channels), dtype)
             _STATE_BYTES.set(self.state_bytes())
+        if latent_rows is not None:
+            _LATENT_BYTES.set(self.pool_bytes())
         # held from the dispatch of a pool-writing program until its
         # pools are rebound, and by any reader off the engine's thread
         self.pool_lock = threading.RLock()
@@ -539,12 +594,13 @@ class PagedKVCache:
         usable-block count; the reserved null block rides the
         aggregate only)."""
         item = 1 if self.quantized else jnp.dtype(self.dtype).itemsize
-        per_pool = (self.num_blocks * self.block_size *
-                    self.num_kv_heads * self.head_dim * item)
-        total = 2 * self.num_layers * per_pool
+        rows = self.num_blocks * self.block_size * self.num_kv_heads
+        # a latent cache's second pool holds the rotary keys, narrower
+        v_dim = self.head_dim if self.latent_spec is None \
+            else self.latent_spec.rope_lanes
+        total = self.num_layers * rows * (self.head_dim + v_dim) * item
         if self.quantized:
-            total += (2 * self.num_layers * self.num_blocks *
-                      self.block_size * self.num_kv_heads * 4)
+            total += 2 * self.num_layers * rows * 4
         if slice is not None and self.num_slices > 1:
             usable = int((self._block_owner == slice).sum())
             return int(total * usable / max(self.num_blocks - 1, 1))
@@ -1414,7 +1470,8 @@ class ContinuousBatchingEngine:
             cfg.num_kv_heads, hd, num_blocks=num_blocks,
             block_size=block_size, max_blocks_per_seq=mbps,
             max_batch=max_batch, dtype=dtype, kv_dtype=kv_dtype,
-            recurrent_state=getattr(model, "recurrent_state", None))
+            recurrent_state=getattr(model, "recurrent_state", None),
+            latent_rows=getattr(model, "latent_rows", None))
         self.waiting: list[_Request] = []
         self.running: dict[int, _Request] = {}  # slot -> request
         self.finished: dict[int, _Request] = {}

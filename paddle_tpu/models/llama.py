@@ -434,22 +434,25 @@ class PagedServingModel(nn.Layer):
         instead: the ``j``-th such hands its ``mixer`` and its normed
         input to the job's ``mix(mixer, h, state, j) -> (out, state)``.
         ``pools`` are the cache's four lists, ``state`` its recurrent
-        state; ``mlp(blk, m)`` stands in for ``blk.mlp(m)``. Returns the
-        final norm's output, the four lists written and the state."""
+        state; ``mlp(blk, m)`` stands in for ``blk.mlp(m)``. How a
+        sublayer reads and writes the residual is the model's
+        (``residual_read``, ``residual_write``, ``residual_close``: ``x``
+        may be more than one stream). Returns the final norm's output,
+        the four lists written and the state."""
         k_pools, v_pools, k_scales, v_scales = pools
-        b, s, _ = x.shape
+        b, s = x.shape[:2]
         new = ([], [], [], [])
         carried = 0
         for blk in self.layers:
             attn = blk.self_attn
+            u, mixed = self.residual_read(blk, 0, x)
             if attn is None:
-                out, state = mix(blk.mixer, blk.input_layernorm(x), state,
+                out, state = mix(blk.mixer, blk.input_layernorm(u), state,
                                  carried)
                 carried += 1
-                x = x + out
             else:
                 i = len(new[0])
-                q, k, v = attn.qkv(blk.input_layernorm(x),
+                q, k, v = attn.qkv(blk.input_layernorm(u),
                                    position_offset)
                 scales = _layer_scales(k_scales, v_scales, i)
                 layer = write(k_pools[i], v_pools[i], k._data, v._data,
@@ -458,10 +461,31 @@ class PagedServingModel(nn.Layer):
                     pool_list.append(pool)
                 out = attend(q._data, *layer[:2],
                              **dict(zip(scales, layer[2:])))
-                x = x + attn.o_proj(Tensor(out.reshape(b, s, -1)))
-            m = blk.post_attention_layernorm(x)
-            x = x + (blk.mlp(m) if mlp is None else mlp(blk, m))
-        return self.norm(x), new, state
+                out = attn.o_proj(Tensor(out.reshape(b, s, -1)))
+            x = self.residual_write(blk, 0, x, out, mixed)
+            u, mixed = self.residual_read(blk, 1, x)
+            m = blk.post_attention_layernorm(u)
+            x = self.residual_write(
+                blk, 1, x, blk.mlp(m) if mlp is None else mlp(blk, m),
+                mixed)
+        return self.norm(self.residual_close(x)), new, state
+
+    # -- the residual path: one stream, each sublayer's output added ------
+    # (a model with another path overrides the three: models/xing.py)
+
+    def residual_read(self, blk, sublayer, x):
+        """What sublayer ``sublayer`` (0: attention or mixer, 1: the
+        feed-forward part) of ``blk`` reads of the residual ``x``, and
+        whatever ``residual_write`` needs of ``x`` as it stood."""
+        return x, None
+
+    def residual_write(self, blk, sublayer, x, out, mixed):
+        """The residual after the sublayer gave ``out``."""
+        return x + out
+
+    def residual_close(self, x):
+        """The residual as the final norm reads it."""
+        return x
 
     def _logits(self, hidden):
         if self.lm_head is not None:

@@ -220,16 +220,24 @@ _geometry = geometry  # pre-PR-20 internal name
 
 # -- export ----------------------------------------------------------------
 
-def _refuse_recurrent_state(cache, what):
-    """A frame carries K and V blocks. A cache that also holds recurrent
-    state (``PagedKVCache.state_spec``) cannot be handed over in one:
-    the state at the prefix's end is in no block."""
+def _refuse_what_no_frame_carries(cache, what):
+    """A frame carries blocks of K and V of one width a KV head. A
+    cache that also holds recurrent state (``PagedKVCache.state_spec``)
+    cannot be handed over in one: the state at the prefix's end is in no
+    block. Nor can a latent cache (``latent_spec``): its two pools have
+    other widths."""
     if cache.state_spec is not None:
         raise ValueError(
             f"{what}: this cache holds recurrent state beside its KV "
             "pools, and a transfer frame carries K and V blocks only: "
             "the state at the prefix's end would be missing on the "
             "other side.")
+    if cache.latent_spec is not None:
+        raise ValueError(
+            f"{what}: this cache holds latent rows (one row a token "
+            "shared by all heads, its rotary keys in a pool of another "
+            "width), and a transfer frame's geometry is K and V of one "
+            "width a KV head: no frame carries such rows yet.")
 
 
 def export_prefix(cache, token_ids):
@@ -246,7 +254,7 @@ def export_prefix(cache, token_ids):
     Returns ``(frame_bytes, ExportedPrefix)``. Pure read — refcounts,
     indices, and pools are untouched.
     """
-    _refuse_recurrent_state(cache, "export_prefix")
+    _refuse_what_no_frame_carries(cache, "export_prefix")
     ids = np.ascontiguousarray(np.asarray(token_ids).reshape(-1),
                                dtype=np.int64)
     plan = cache.plan_prefix(ids)
@@ -335,7 +343,7 @@ def import_prefix(cache, frame):
     exactly as it was. Digests already resident are deduped (their
     local block wins). Returns :class:`ImportResult`.
     """
-    _refuse_recurrent_state(cache, "import_prefix")
+    _refuse_what_no_frame_carries(cache, "import_prefix")
     payload = unpack_frame(frame)
     try:
         obj = pickle.loads(payload)

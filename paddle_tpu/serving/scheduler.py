@@ -296,8 +296,21 @@ def merge_tokens(prev, host, fresh):
     marks — the slots admitted since, whose first token (or open block)
     the host has."""
     fresh = np.asarray(fresh, bool)
-    return _token_merge()(prev, np.asarray(host, np.int32),
+    host = np.asarray(host, np.int32)
+    behind = prev.shape[0] - host.shape[0]
+    if behind > 0 and prev.ndim == 1:
+        # what a model packs behind its tokens (``decode_extras``) is
+        # the step's own: only the tokens are merged
+        host, fresh = np.pad(host, (0, behind)), np.pad(fresh, (0, behind))
+    return _token_merge()(prev, host,
                           fresh.reshape(fresh.shape + (1,) * (prev.ndim - 1)))
+
+
+def _count_expert_rows(expert_rows):
+    """``serving.moe.*`` from a step's [layers, experts] rows."""
+    _m_moe_rows.inc(int(expert_rows.sum()))
+    _m_moe_hit.inc(int((expert_rows > 0).sum()))
+    _m_moe_max.inc(int(expert_rows.max(axis=1).sum()))
 
 
 class _Flight:
@@ -398,15 +411,19 @@ class Scheduler:
         # what cannot be served with it is refused here, by what the
         # model declares
         state_spec = getattr(model, "recurrent_state", None)
+        # one row a token a layer shared by all heads (latent attention,
+        # models/xing.py): another pool geometry in the same cache
+        latent_rows = getattr(model, "latent_rows", None)
         # what a prefill call takes beside its prompt: the kernel route,
         # where the model's prefill has a kernel to route (a scan over
         # the state, block attention), and nothing where it has none
         self.prefill_route = {"kernel_mode": self.kernel_mode} \
-            if state_spec is not None or self._block_len > 1 else {}
+            if state_spec is not None or latent_rows is not None \
+            or self._block_len > 1 else {}
         self.cache = PagedKVCache(
             getattr(model, "kv_cache_layers", cfg.num_layers),
             cfg.num_kv_heads, hd, recurrent_state=state_spec,
-            num_blocks=num_blocks,
+            latent_rows=latent_rows, num_blocks=num_blocks,
             block_size=block_size, max_blocks_per_seq=mbps,
             max_batch=max_batch, dtype=compute_dt, kv_dtype=kv_dtype,
             pool_sharding=(self.mesh.kv_pool_sharding()
@@ -454,6 +471,12 @@ class Scheduler:
                 "with a model that carries recurrent state: rejected "
                 "drafts roll K and V back by truncating blocks, and a "
                 "recurrence has no such rewind.")
+        if latent_rows is not None and armed_spec:
+            raise ValueError(
+                "serving: speculation (FLAGS_serving_spec) is not served "
+                "with a latent cache: the verify sweep attends several "
+                "positions a slot over K and V a head, and no such "
+                "program reads latent rows yet.")
         self.spec = armed_spec and temperature == 0.0
         self.prefill_token_budget = (
             flags_mod.flag("FLAGS_serving_prefill_budget")
@@ -529,8 +552,14 @@ class Scheduler:
         # (slot, list) when set, for a model with recurrent state: every
         # plain decode step appends what the slot's recurrence was fed
         # (``Jamba.paged_decode_step`` reads it under the cache's lock;
-        # the benchmark's check replays the recurrence from it)
+        # the benchmark's check replays the recurrence from it). A model
+        # that declares ``decode_tap`` takes it too
         self.state_observer = None
+        self._observed = state_spec is not None \
+            or bool(getattr(model, "decode_tap", False))
+        # [layers, experts] rows routed in a plain decode step, from the
+        # array read back, where the model packs them behind its tokens
+        self._expert_rows = getattr(model, "decode_expert_rows", None)
 
     # -- submission / cancellation ------------------------------------
 
@@ -575,6 +604,11 @@ class Scheduler:
                 "blocks to another replica; this model also carries "
                 "recurrent state, which no block holds and no transfer "
                 "frame carries.")
+        if prefill_only and self.cache.latent_spec is not None:
+            raise ValueError(
+                "serving.submit: prefill_only hands a prompt's blocks to "
+                "another replica in a transfer frame, and no frame "
+                "carries latent rows yet.")
         if prefill_only and not self.prefix_cache:
             raise ValueError(
                 "serving.submit: prefill_only requires the prefix "
@@ -646,6 +680,10 @@ class Scheduler:
                 "serving.admit_handoff: imported blocks hold K and V "
                 "only; this model's recurrent state at the prompt's end "
                 "exists on no replica but the one that prefilled it")
+        if self.cache.latent_spec is not None:
+            raise HandoffError(
+                "serving.admit_handoff: no transfer frame carries latent "
+                "rows, so none can have been imported")
         prompt = validate_request(prompt_ids, max_new_tokens,
                                   self.max_seq_len, self.cache,
                                   who="serving.admit_handoff")
@@ -1169,6 +1207,7 @@ class Scheduler:
             # the live slots are the ones whose state the step updates
             stats["state_slots"] = batch
             _m_state_slot_steps.inc(batch)
+        if self._observed:
             probe["state_observer"] = lambda: self.state_observer
         # decode compiles split across the batch
         flight = self._dispatch_decode(
@@ -1223,6 +1262,8 @@ class Scheduler:
     def _emit_tokens(self, flight, toks, dec_us):
         """What ``land`` does with a plain decode step's tokens."""
         out = []
+        if self._expert_rows is not None:
+            _count_expert_rows(self._expert_rows(toks))
         for slot, req in flight.reqs.items():
             if self.running.get(slot) is not req:
                 # it emitted EOS in the step before, after this one
@@ -1362,9 +1403,7 @@ class Scheduler:
         commit, opened, lens, batch, observer, moe = flight.block
         got = self.model.unpack_block_step(packed, self.cache.max_batch)
         expert_rows, picked = got["expert_rows"], got["unmasked"]
-        _m_moe_rows.inc(int(expert_rows.sum()))
-        _m_moe_hit.inc(int((expert_rows > 0).sum()))
-        _m_moe_max.inc(int(expert_rows.max(axis=1).sum()))
+        _count_expert_rows(expert_rows)
         # a slot that emitted EOS in the step before, after this one was
         # dispatched, ran in it for nothing: its result is dropped
         mine = [slot for slot, req in flight.reqs.items()

@@ -13,7 +13,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 
 import chip_smoke  # noqa: E402
 from paddle_tpu.models import (GPTConfig, JambaConfig,  # noqa: E402
-                               LlamaConfig)
+                               LlamaConfig, XingConfig)
 
 
 def test_main_refuses_without_tpu(capsys):
@@ -87,3 +87,18 @@ def test_hybrid_phase_tiny():
     assert facts["tokens"] == facts["tokens_same"] == 6
     assert facts["state_rel_err"] <= 1e-4
     assert facts["state_bytes_per_slot"] == 3 * (16 * 64 * 4 + 3 * 64 * 4)
+
+
+def test_latent_phase_tiny():
+    """A tiny Xing through the engine on the interpreted kernels (a
+    prefix hit among its prompts), then the plain route fed the served
+    tokens: every one is the plain route's choice."""
+    facts = chip_smoke.latent_phase(
+        XingConfig.tiny(), (40, 36, 20), 6, dtype="float32",
+        platform="cpu", paged_kernel="pallas", tol=1e-4)
+    assert facts["tokens"] == 18 and facts["margin"] <= 1e-4
+    assert facts["prefix_hit_blocks"] >= 2
+    # 5 decode steps of each request x 2 sparse layers x 2 experts a token
+    assert facts["moe_rows"] == 3 * 5 * 2 * 2
+    # 3 layers x (32 + 128 lanes) x 4 bytes
+    assert facts["latent_bytes_per_token"] == 3 * 160 * 4

@@ -298,3 +298,44 @@ def test_paged_decode_lowers_at_twenty_rows_on_one_kv_head():
     n = _lower(lambda q, k, v, t, l: paged_decode_attention(
         q, k, v, t, l, kernel_mode="pallas"), *avals)
     assert n == 1
+
+
+def test_mla_decode_lowers_at_the_served_widths():
+    """32 query rows of 512 + 64 a slot over a latent pool of 128 slots x
+    256 pages: the rotary pool's rows are 128 lanes wide (a 64-lane row
+    cannot be sliced for a DMA)."""
+    from paddle_tpu.kernels.pallas.mla_decode import mla_decode_routed
+
+    b, h, latent, rope, bs, pages = 128, 32, 512, 64, 16, 256
+    nb = 1 + b * pages
+    avals = [_aval((b, h, latent), jnp.bfloat16),
+             _aval((b, h, rope), jnp.bfloat16),
+             _aval((nb, bs, 1, latent), jnp.bfloat16),
+             _aval((nb, bs, 1, 128), jnp.bfloat16),
+             _aval((b, pages), jnp.int32), _aval((b,), jnp.int32)]
+    n = _lower(lambda *a: mla_decode_routed(*a, scale=0.14468,
+                                            kernel_mode="pallas"), *avals)
+    assert n == 1
+
+
+def test_sigmoid_routed_experts_with_a_shared_one_lower_at_the_served_widths():
+    """128 rows, 4 of 64 experts of 3584 x 1024 a row and a shared
+    expert: the tagged pair of a decode step."""
+    from paddle_tpu.distributed import moe
+
+    t, d, f, e, k = 128, 3584, 1024, 64, 4
+    avals = [_aval((t, d), jnp.bfloat16), _aval((d, e), jnp.bfloat16),
+             _aval((e, d, f), jnp.bfloat16), _aval((e, d, f), jnp.bfloat16),
+             _aval((e, f, d), jnp.bfloat16), _aval((e,), jnp.bfloat16),
+             _aval((d, f), jnp.bfloat16), _aval((d, f), jnp.bfloat16),
+             _aval((f, d), jnp.bfloat16)]
+
+    def layer(x, router, wg, wu, wd, bias, sg, su, sd):
+        return moe.dropless_moe(
+            x, router, wg, wu, wd, top_k=k, route="pallas",
+            kernel_tag="_decode",
+            router=lambda m, rw: moe.route_sigmoid_topk(m, rw, bias, k,
+                                                        True, 2.0),
+            shared=(sg, su, sd))[0]
+
+    assert _lower(layer, *avals) == 2
