@@ -87,6 +87,7 @@ __all__ = ["PagedKVCache", "paged_prefill_write",
            "ContinuousBatchingEngine", "validate_request",
            "chunk_digests", "PrefixPlan", "CapacityError",
            "RecurrentStateSpec", "LatentRowSpec",
+           "latent_prefill_write_masked", "latent_decode_write",
            "resolve_kv_dtype", "quant_block_ratio",
            "resolve_paged_kernel", "kernel_route"]
 
@@ -242,18 +243,44 @@ class LatentRowSpec:
     """What a layer of latent attention (MLA) caches of a token: ONE row
     shared by all heads, its first ``latent`` values the compressed keys
     *and* values (a head's keys and values are rebuilt from them, or
-    the projection is folded into the query and the output) and its last
-    ``rope`` values the rotary keys. The rotary keys lie in a pool of
-    their own whose rows are ``rope_lanes`` wide, whole 128-lane tiles
-    with zeros behind the keys: the chip lays a narrower row out so
-    anyway, and a kernel's DMA moves whole tiles."""
+    the projection is folded into the query and the output), the next
+    ``rope`` values the rotary keys. The row lies in ONE pool, ``lanes``
+    wide: the compressed part, then the rotary keys in whole 128-lane
+    tiles with zeros behind them (the chip lays a narrower row out so
+    anyway, and a kernel's DMA moves whole tiles: 512 + 64 is 640
+    lanes). One pool and not one a part, because the two parts are
+    always read together and what a copy costs the kernel that starts it
+    is scalar work, the same whatever the copy's size (PERF.md 6, PR
+    35): a page is one copy."""
 
     latent: int
     rope: int
 
     @property
-    def rope_lanes(self):
-        return -(-self.rope // 128) * 128
+    def lanes(self):
+        return self.latent + -(-self.rope // 128) * 128
+
+
+class _RowLanes:
+    """``k_pools[i]`` / ``v_pools[i]`` of a latent cache: lanes ``lo`` to
+    ``hi`` of layer ``i``'s row pool as it stands when asked, indexed by
+    blocks. ``view[table]`` gathers the pages asked for and slices their
+    lanes; nothing a pool wide is made (the pools are most of the
+    device's memory). For readers written against a pair of pools
+    (``benchmarks/drivers/serve_latent._held_rows``), under
+    ``pool_lock`` as ever; the programs take ``row_pools``."""
+
+    def __init__(self, cache, layer, lo, hi):
+        self._cache, self._layer, self._lo, self._hi = cache, layer, lo, hi
+
+    @property
+    def shape(self):
+        return self._cache.row_pools[self._layer].shape[:-1] \
+            + (self._hi - self._lo,)
+
+    def __getitem__(self, blocks):
+        return self._cache.row_pools[self._layer][blocks][
+            ..., self._lo:self._hi]
 
 
 @dataclass
@@ -324,15 +351,21 @@ class PagedKVCache:
 
     **Latent rows** (``latent_rows``, a :class:`LatentRowSpec`;
     ``models/xing.py``): another pool geometry under the same tables,
-    refcounts, prefix index, copy-on-write and donation. A layer's
-    ``k_pools[i]`` is ``[blocks, page, 1, latent]`` and holds a token's
-    compressed keys-and-values, ``v_pools[i]`` is ``[blocks, page, 1,
-    rope_lanes]`` and holds its rotary keys: there is no separate V, and no
-    head axis to shard. The two lists keep their names because every
-    block-level mechanism moves them together and asks nothing of their
-    widths. Prefix sharing works as for K and V (a block's rows are all
-    a position's state). Refused at construction, with the reason: int8
-    pools and a serving mesh (docs/SERVING.md).
+    refcounts, prefix index, copy-on-write and donation. A layer holds
+    ONE pool, ``row_pools[i]`` ``[blocks, page, 1, lanes]``: a token's
+    compressed keys-and-values in the first ``latent`` lanes, its rotary
+    keys behind them, zeros to the tile's end. There is no separate V,
+    no head axis to shard, and no second pool of any size: a page is
+    one copy for the decode kernel, which pays for a copy in scalar
+    work whatever the copy's size (PERF.md 6, PR 35). Every
+    block-level mechanism acts on the lists :meth:`pool_lists` gives and
+    asks nothing of their widths or their number. ``k_pools[i][table]``
+    and ``v_pools[i][table]`` still answer, with the compressed part and
+    the lanes behind it of the pages asked for (:class:`_RowLanes`: a
+    gather and a lane slice, never a pool), for readers written against
+    the pair of pools. Prefix sharing works as for K and V (a block's
+    rows are all a position's state). Refused at construction, with the
+    reason: int8 pools and a serving mesh (docs/SERVING.md).
 
     **Prefix sharing** (vLLM shared-block / SGLang RadixAttention
     style): a block registered in the prefix index is immutable in its
@@ -416,13 +449,21 @@ class PagedKVCache:
             z = jnp.zeros(sh, dt)
             return z if sharding is None else jax.device_put(z, sharding)
 
-        # a latent cache's second list holds the rotary keys, narrower
-        v_shape = shape if latent_rows is None \
-            else shape[:3] + (latent_rows.rope_lanes,)
-        self.k_pools = [_pool(shape, store_dt, pool_sharding)
-                        for _ in range(num_layers)]
-        self.v_pools = [_pool(v_shape, store_dt, pool_sharding)
-                        for _ in range(num_layers)]
+        if latent_rows is not None:
+            # one pool a layer; the pair's names read it (``_RowLanes``)
+            lanes = latent_rows.lanes
+            self.row_pools = [_pool(shape[:3] + (lanes,), store_dt, None)
+                              for _ in range(num_layers)]
+            self.k_pools = [_RowLanes(self, i, 0, head_dim)
+                            for i in range(num_layers)]
+            self.v_pools = [_RowLanes(self, i, head_dim, lanes)
+                            for i in range(num_layers)]
+        else:
+            self.row_pools = None
+            self.k_pools = [_pool(shape, store_dt, pool_sharding)
+                            for _ in range(num_layers)]
+            self.v_pools = [_pool(shape, store_dt, pool_sharding)
+                            for _ in range(num_layers)]
         if self.quantized:
             sshape = (num_blocks, block_size, num_kv_heads)
             self.k_scales = [_pool(sshape, jnp.float32, scale_sharding)
@@ -595,10 +636,10 @@ class PagedKVCache:
         aggregate only)."""
         item = 1 if self.quantized else jnp.dtype(self.dtype).itemsize
         rows = self.num_blocks * self.block_size * self.num_kv_heads
-        # a latent cache's second pool holds the rotary keys, narrower
-        v_dim = self.head_dim if self.latent_spec is None \
-            else self.latent_spec.rope_lanes
-        total = self.num_layers * rows * (self.head_dim + v_dim) * item
+        # K and V of a head, or a latent cache's one row
+        width = 2 * self.head_dim if self.latent_spec is None \
+            else self.latent_spec.lanes
+        total = self.num_layers * rows * width * item
         if self.quantized:
             total += 2 * self.num_layers * rows * 4
         if slice is not None and self.num_slices > 1:
@@ -670,25 +711,38 @@ class PagedKVCache:
             self._refcount[b] = 0
             self._release_block(b)
 
+    def pool_lists(self):
+        """The four lists a serving program takes donated and returns,
+        a layer an entry: the K pools, the V pools and an int8 cache's
+        two lists of scale arrays (empty for full precision). A latent
+        cache has one pool a layer: its rows come first and the other
+        three lists are empty."""
+        if self.latent_spec is not None:
+            return self.row_pools, [], [], []
+        return (self.k_pools, self.v_pools, self.k_scales or [],
+                self.v_scales or [])
+
     def pool_arrays(self):
-        """Every array a pool-writing program takes donated: the K and V
-        pools, then an int8 cache's scale arrays."""
-        out = self.k_pools + self.v_pools
-        return out + self.k_scales + self.v_scales if self.quantized \
-            else out
+        """Every array a pool-writing program takes donated, list after
+        list of :meth:`pool_lists`."""
+        return [pool for held in self.pool_lists() for pool in held]
 
     def rebind_pools(self, k_pools, v_pools, k_scales=None,
                      v_scales=None, state=None):
-        """Take the pools (and the recurrent state, where the cache
-        holds one) a pool-writing program returned (the caller
+        """Take the lists of :meth:`pool_lists` (and the recurrent
+        state, where the cache holds one) as a pool-writing program
+        returned them (the caller
         holds ``pool_lock`` since before the dispatch). The pools handed
         in are still bound here, so one look at the first says whether
         the program consumed them (``serving.kv.donated_calls``) or
         wrote copies (``serving.kv.copied_calls``)."""
-        (_KV_DONATED if self.k_pools[0].is_deleted()
+        (_KV_DONATED if self.pool_lists()[0][0].is_deleted()
          else _KV_COPIED).inc()
-        self.k_pools = list(k_pools)
-        self.v_pools = list(v_pools)
+        if self.latent_spec is not None:
+            self.row_pools = list(k_pools)
+        else:
+            self.k_pools = list(k_pools)
+            self.v_pools = list(v_pools)
         if self.quantized:
             self.k_scales = list(k_scales)
             self.v_scales = list(v_scales)
@@ -707,10 +761,9 @@ class PagedKVCache:
         else:
             src = jnp.asarray(src, jnp.int32)
         with self.pool_lock:
-            out = _kv_block_copy(self.pool_arrays(), dst, src)
-            n = self.num_layers
-            self.rebind_pools(out[:n], out[n:2 * n], out[2 * n:3 * n],
-                              out[3 * n:])
+            out = iter(_kv_block_copy(self.pool_arrays(), dst, src))
+            self.rebind_pools(*([next(out) for _ in held]
+                                for held in self.pool_lists()))
 
     def _copy_block_rows(self, src, dst):
         """Copy-on-write body: duplicate one pool block across every
@@ -1051,22 +1104,36 @@ def paged_prefill_write(k_pool, v_pool, block_row, k_new, v_new,
     return tuple(p.at[blocks].set(pg) for p, pg in zip(pools, pages))
 
 
+def _put_rows(pool, new, blocks, offs, valid):
+    """The masked row scatter behind every row-by-row write: row ``i``
+    of ``new`` [N, ...] lands at ``pool[blocks[i], offs[i]]`` where
+    ``valid[i]``; the caller pointed every other row at the null block's
+    row 0, which keeps what it holds."""
+    keep = valid[(slice(None),) + (None,) * (new.ndim - 1)]
+    return pool.at[blocks, offs].set(
+        jnp.where(keep, new.astype(pool.dtype), pool[blocks, offs]))
+
+
 def _write_rows(k_pool, v_pool, blocks, offs, valid, k_new, v_new,
                 k_scale, v_scale):
-    """The masked row scatter behind every row-by-row write: row ``i``
-    of ``k_new``/``v_new`` [N, Hk, D] lands at ``[blocks[i], offs[i]]``
-    where ``valid[i]``; the caller pointed every other row at the null
-    block's row 0, which keeps what it holds. Returns the pools, then
-    the scale arrays of an int8 cache."""
+    """:func:`_put_rows` of ``k_new``/``v_new`` [N, Hk, D] into the two
+    pools. Returns the pools, then the scale arrays of an int8 cache."""
     pools, rows = _pools_and_rows(k_pool, v_pool, k_new, v_new, k_scale,
                                   v_scale)
+    return tuple(_put_rows(p, r, blocks, offs, valid)
+                 for p, r in zip(pools, rows))
 
-    def put(pool, new):
-        keep = valid[(slice(None),) + (None,) * (new.ndim - 1)]
-        return pool.at[blocks, offs].set(
-            jnp.where(keep, new.astype(pool.dtype), pool[blocks, offs]))
 
-    return tuple(put(p, r) for p, r in zip(pools, rows))
+def _tail_targets(bs, block_row, n, start, write_start, total_len):
+    """(blocks, offs, valid) of the ``n`` positions from ``start``: those
+    in ``[write_start, total_len)`` land in the slot's pages, the rest
+    (shared prefix rows, bucket padding) are pointed at the null block."""
+    pos = start + jnp.arange(n, dtype=jnp.int32)
+    valid = (pos >= write_start) & (pos < total_len)
+    b_idx = jnp.where(valid, pos // bs, 0)
+    blocks = jnp.where(valid, block_row[b_idx], 0)
+    offs = jnp.where(valid, pos % bs, 0)
+    return blocks, offs, valid
 
 
 def paged_prefill_write_masked(k_pool, v_pool, block_row, k_new, v_new,
@@ -1079,14 +1146,20 @@ def paged_prefill_write_masked(k_pool, v_pool, block_row, k_new, v_new,
     poison cached content). All operands static-shaped; start/
     write_start/total_len are traced scalars. An int8 cache passes its
     scale arrays and gets them back behind the pools."""
-    bs = k_pool.shape[1]
-    pos = start + jnp.arange(k_new.shape[0], dtype=jnp.int32)
-    valid = (pos >= write_start) & (pos < total_len)
-    b_idx = jnp.where(valid, pos // bs, 0)
-    blocks = jnp.where(valid, block_row[b_idx], 0)
-    offs = jnp.where(valid, pos % bs, 0)
+    blocks, offs, valid = _tail_targets(
+        k_pool.shape[1], block_row, k_new.shape[0], start, write_start,
+        total_len)
     return _write_rows(k_pool, v_pool, blocks, offs, valid, k_new, v_new,
                        k_scale, v_scale)
+
+
+def latent_prefill_write_masked(row_pool, block_row, rows, start,
+                                write_start, total_len):
+    """:func:`paged_prefill_write_masked` for a latent cache's one pool
+    a layer: ``rows`` [S, 1, lanes], row by row. Returns the pool."""
+    return _put_rows(row_pool, rows, *_tail_targets(
+        row_pool.shape[1], block_row, rows.shape[0], start, write_start,
+        total_len))
 
 
 def _gather_kv(pool, index, scale, dtype):
@@ -1142,20 +1215,33 @@ def paged_prefix_attention_dense(q, k_pool, v_pool, block_row, q_start,
     return out.reshape(s, hq, d).astype(q.dtype)
 
 
+def _step_targets(bs, block_tables, positions, active):
+    """(blocks, offs) of one token a slot at ``positions``: an inactive
+    slot's is pointed at the null block's row 0."""
+    b_idx = positions // bs
+    offs = positions % bs
+    rows = jnp.arange(block_tables.shape[0], dtype=jnp.int32)
+    blocks = jnp.where(active, block_tables[rows, b_idx], 0)
+    return blocks, jnp.where(active, offs, 0)
+
+
 def paged_decode_write(k_pool, v_pool, block_tables, positions, k_new,
                        v_new, active, k_scale=None, v_scale=None):
     """Scatter one new token's KV per slot: k_new/v_new [B, Hk, D] at
     `positions` [B] (the token's index). Inactive slots write to the null
     block 0 slot 0 — harmless, masked everywhere. An int8 cache passes
     its scale arrays and gets them back behind the pools."""
-    bs = k_pool.shape[1]
-    b_idx = positions // bs
-    offs = positions % bs
-    rows = jnp.arange(block_tables.shape[0], dtype=jnp.int32)
-    blocks = jnp.where(active, block_tables[rows, b_idx], 0)
-    offs = jnp.where(active, offs, 0)
+    blocks, offs = _step_targets(k_pool.shape[1], block_tables, positions,
+                                 active)
     return _write_rows(k_pool, v_pool, blocks, offs, active, k_new, v_new,
                        k_scale, v_scale)
+
+
+def latent_decode_write(row_pool, block_tables, positions, rows, active):
+    """:func:`paged_decode_write` for a latent cache's one pool a layer:
+    ``rows`` [B, 1, lanes], one token a slot. Returns the pool."""
+    return _put_rows(row_pool, rows, *_step_targets(
+        row_pool.shape[1], block_tables, positions, active), active)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,

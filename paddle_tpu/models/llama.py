@@ -279,8 +279,9 @@ class PagedServingModel(nn.Layer):
     A serving program is ``program(param_arrays, *head, k_pools,
     v_pools, k_scales, v_scales[, state], *tail) -> (*outputs, k_pools,
     v_pools, k_scales, v_scales[, state])``: the cache's device state in
-    the middle as the cache holds it (the scale lists empty for a
-    full-precision cache; ``state``, the stacked recurrent state, only
+    the middle as the cache holds it (``PagedKVCache.pool_lists``: the
+    scale lists empty for a full-precision cache, the second list too
+    for a latent cache's one pool a layer; ``state``, the stacked recurrent state, only
     where the model has layers that carry one), donated together and
     returned written in place. ``_build_<job>(quantized, mode)`` builds
     the program of a job."""
@@ -355,8 +356,7 @@ class PagedServingModel(nn.Layer):
         its recurrent state, where it holds one), ``tail``. For whoever lowers or runs the program beside the
         entry point; ``_paged_call`` is this plus the call."""
         return self.serving_program(job, cache.quantized, mode), (
-            self._param_arrays(), *head, cache.k_pools, cache.v_pools,
-            cache.k_scales or [], cache.v_scales or [],
+            self._param_arrays(), *head, *cache.pool_lists(),
             *cache.state_args(), *tail)
 
     @contextlib.contextmanager
@@ -430,7 +430,10 @@ class PagedServingModel(nn.Layer):
         job's ``write(k_pool, v_pool, k, v, **scales) -> (k_pool,
         v_pool, *scales)`` and its queries to ``attend(q, k_pool,
         v_pool, **scales)`` over what was written (any shape of b x s
-        rows). A layer whose ``self_attn`` is None carries state
+        rows). Where the cache holds one pool a layer (its second list
+        is empty: a latent cache), ``attn.qkv`` gives the queries and
+        the one row, and the pair is ``write(pool, row) -> (pool,)`` and
+        ``attend(q, pool)``. A layer whose ``self_attn`` is None carries state
         instead: the ``j``-th such hands its ``mixer`` and its normed
         input to the job's ``mix(mixer, h, state, j) -> (out, state)``.
         ``pools`` are the cache's four lists, ``state`` its recurrent
@@ -452,15 +455,16 @@ class PagedServingModel(nn.Layer):
                 carried += 1
             else:
                 i = len(new[0])
-                q, k, v = attn.qkv(blk.input_layernorm(u),
-                                   position_offset)
+                q, *rows = attn.qkv(blk.input_layernorm(u),
+                                    position_offset)
+                held = [pools_of[i] for pools_of in (k_pools, v_pools)
+                        if pools_of]
                 scales = _layer_scales(k_scales, v_scales, i)
-                layer = write(k_pools[i], v_pools[i], k._data, v._data,
-                              **scales)
+                layer = write(*held, *(r._data for r in rows), **scales)
                 for pool_list, pool in zip(new, layer):
                     pool_list.append(pool)
-                out = attend(q._data, *layer[:2],
-                             **dict(zip(scales, layer[2:])))
+                out = attend(q._data, *layer[:len(held)],
+                             **dict(zip(scales, layer[len(held):])))
                 out = attn.o_proj(Tensor(out.reshape(b, s, -1)))
             x = self.residual_write(blk, 0, x, out, mixed)
             u, mixed = self.residual_read(blk, 1, x)

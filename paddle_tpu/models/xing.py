@@ -66,7 +66,7 @@ from ..core.random import next_key
 from ..core.tensor import Tensor
 from ..distributed.moe import DroplessMoE
 from ..inference.paged import LatentRowSpec
-from ..kernels.pallas.mla_decode import mla_decode_routed
+from ..kernels.pallas.mla_decode import mla_decode_routed, in_lanes
 from ..nn.initializer import Constant, Initializer
 from ..profiler.tracing import phase as _phase
 from .llama import LlamaMLP, PagedServingModel, _normal_attr
@@ -282,7 +282,7 @@ class XingAttention(nn.Layer):
         self.nope, self.rope = (config.qk_nope_head_dim,
                                 config.qk_rope_head_dim)
         self.v_dim, self.latent = config.v_head_dim, config.kv_lora_rank
-        self.rope_lanes = LatentRowSpec(self.latent, self.rope).rope_lanes
+        self.lanes = LatentRowSpec(self.latent, self.rope).lanes
         self.scale = config.softmax_scale
         rs = config.rope_scaling
         self.inv_freq = yarn_inv_freq(self.rope, config.rope_theta, rs)
@@ -309,9 +309,9 @@ class XingAttention(nn.Layer):
         """Of the normed input ``u`` [b, s, d] at the positions from
         ``position_offset`` (a scalar, or one offset a row of the
         batch): the queries [b, s, H, nope + rope] (the rotary part
-        rotated) and the row the cache holds, in its two pools' shapes:
-        ``c`` [b, s, 1, latent] and the rotary keys [b, s, 1, rope
-        lanes] (zeros behind them)."""
+        rotated) and the row the cache holds, in its pool's shape [b, s,
+        1, lanes]: ``c`` [latent], the rotary keys [rope], zeros to the
+        tile's end."""
         b, s, _ = u.shape
         off = jnp.asarray(position_offset, jnp.int32)
         pos = (off[:, None] if off.ndim else off) \
@@ -327,21 +327,20 @@ class XingAttention(nn.Layer):
         c = self.kv_a_layernorm(kv[..., :self.latent])._data
         k_r = _rotary(kv._data[..., self.latent:], pos, self.inv_freq,
                       self.magnitude)
-        k_r = jnp.pad(k_r, ((0, 0), (0, 0),
-                            (0, self.rope_lanes - self.rope)))
-        return Tensor(q), Tensor(c[:, :, None]), Tensor(k_r[:, :, None])
+        return Tensor(q), Tensor(in_lanes(c, k_r, self.lanes)[:, :, None])
 
     def _w_ukv(self):
         """``kv_b_proj`` as [latent, H, nope + v]."""
         return self.kv_b_proj.weight._data.reshape(
             self.latent, self.num_heads, self.nope + self.v_dim)
 
-    def expanded(self, q, c, k_r, mask):
+    def expanded(self, q, rows, mask):
         """The expanded form: queries ``q`` [b, s, H, nope + rope] over
-        the rows ``c`` [b, t, latent], ``k_r`` [b, t, >= rope], keys and
+        the ``rows`` [b, t, lanes] as the cache holds them, keys and
         values rebuilt a head through ``kv_b_proj``; ``mask`` [s, t]
         bool. Returns [b, s, H, v] (arrays)."""
-        b, t = c.shape[:2]
+        b, t = rows.shape[:2]
+        c, k_r = rows[..., :self.latent], rows[..., self.latent:]
         kv = jnp.matmul(c, self.kv_b_proj.weight._data).reshape(
             b, t, self.num_heads, self.nope + self.v_dim)
         logits = (jnp.einsum("bshn,bthn->bhst", q[..., :self.nope],
@@ -359,14 +358,14 @@ class XingAttention(nn.Layer):
 
     def absorbed(self, q, attend):
         """The absorbed form for one query row a slot: ``q`` [B, H, nope
-        + rope]; ``attend(q_lat [B, H, latent], q_rope [B, H, rope]) ->
-        o_lat [B, H, latent]`` is the attention over the rows as the
-        cache holds them. Returns [B, H, v]."""
+        + rope]; ``attend(q_row [B, H, lanes]) -> o_lat [B, H, latent]``
+        is the attention over the rows as the cache holds them, the
+        query laid out as they are. Returns [B, H, v]."""
         w = self._w_ukv()
         q_lat = jnp.einsum("bhn,chn->bhc", q[..., :self.nope],
                            w[..., :self.nope],
                            preferred_element_type=_F32).astype(q.dtype)
-        o_lat = attend(q_lat, q[..., self.nope:])
+        o_lat = attend(in_lanes(q_lat, q[..., self.nope:], self.lanes))
         return jnp.einsum("bhc,chv->bhv", o_lat, w[..., self.nope:],
                           preferred_element_type=_F32).astype(q.dtype)
 
@@ -374,9 +373,9 @@ class XingAttention(nn.Layer):
         """Whole sequences from position 0, causal: [b, s, d] -> [b, s,
         d] (the expanded form)."""
         b, s, _ = u.shape
-        q, c, k_r = self.qkv(u)
+        q, rows = self.qkv(u)
         pos = jnp.arange(s, dtype=jnp.int32)
-        out = self.expanded(q._data, c._data[:, :, 0], k_r._data[:, :, 0],
+        out = self.expanded(q._data, rows._data[:, :, 0],
                             pos[None, :] <= pos[:, None])
         return self.o_proj(Tensor(out.reshape(b, s, -1)))
 
@@ -560,29 +559,29 @@ class Xing(PagedServingModel):
         """The stack over one sequence ``ids`` [1, S] at the positions
         from ``t_start`` (true positions end at ``t_total``; those from
         ``w_start`` are written, row by row through the slot's table row
-        ``row``), expanded attention over ``keys_of(c, k_r, c_pool,
-        r_pool) -> (c [t, latent], k_r [t, .], positions [t])``."""
-        from ..inference.paged import paged_prefill_write_masked
+        ``row``), expanded attention over ``keys_of(rows, pool) ->
+        (rows [t, lanes], positions [t])`` of the layer's rows just
+        computed and its pool just written."""
+        from ..inference.paged import latent_prefill_write_masked
         s = ids.shape[1]
         pos_q = t_start + jnp.arange(s, dtype=jnp.int32)
         valid = Tensor(pos_q < t_total)
         fresh = {}
 
-        def write(kp, vp, c, k_r):
-            fresh["row"] = (c[0, :, 0], k_r[0, :, 0])
-            return paged_prefill_write_masked(
-                kp, vp, row, c[0], k_r[0], t_start, w_start, t_total)
+        def write(pool, rows):
+            fresh["rows"] = rows[0, :, 0]
+            return (latent_prefill_write_masked(
+                pool, row, rows[0], t_start, w_start, t_total),)
 
         # ``expanded`` reads its layer's ``kv_b_proj``: the stack asks a
         # layer at a time, in order
         layer_of = iter(self.layers)
 
-        def attend(q, kp, vp):
-            c, k_r, pos_k = keys_of(*fresh["row"], kp, vp)
+        def attend(q, pool):
+            rows, pos_k = keys_of(fresh["rows"], pool)
             mask = (pos_k[None, :] <= pos_q[:, None]) \
                 & (pos_k[None, :] < t_total)
-            return next(layer_of).self_attn.expanded(
-                q, c[None], k_r[None], mask)
+            return next(layer_of).self_attn.expanded(q, rows[None], mask)
 
         return self._paged_stack(
             self._streams(self.embed_tokens(Tensor(ids))), t_start, pools,
@@ -614,15 +613,15 @@ class Xing(PagedServingModel):
             return int(tok)
 
     def _build_prefill(self, quantized, mode):
-        def body(ids_arr, true_len, row, k_pools, v_pools, k_scales,
-                 v_scales, key, temp):
+        # the protocol's four lists: a latent cache's rows, then nothing
+        def body(ids_arr, true_len, row, row_pools, no_v, no_ks, no_vs,
+                 key, temp):
             zero = jnp.int32(0)
             s = ids_arr.shape[1]
             hidden, new, _ = self._sequence_stack(
                 ids_arr, zero, zero, true_len, row,
-                (k_pools, v_pools, k_scales, v_scales), mode,
-                lambda c, k_r, kp, vp: (c, k_r,
-                                        jnp.arange(s, dtype=jnp.int32)))
+                (row_pools, no_v, no_ks, no_vs), mode,
+                lambda rows, pool: (rows, jnp.arange(s, dtype=jnp.int32)))
             return (self._first_token(hidden, true_len - 1, key, temp),
                     *new)
         return self._as_program(body, "xing.paged_prefill", 4, mode=mode)
@@ -654,16 +653,15 @@ class Xing(PagedServingModel):
             return int(tok)
 
     def _build_extend(self, quantized, mode):
-        def body(tail_ids, t_start, w_start, t_total, row, k_pools,
-                 v_pools, k_scales, v_scales, key, temp):
-            def paged_rows(c, k_r, kp, vp):
-                t = row.shape[0] * kp.shape[1]
-                return (kp[row].reshape(t, kp.shape[-1]),
-                        vp[row].reshape(t, vp.shape[-1]),
+        def body(tail_ids, t_start, w_start, t_total, row, row_pools,
+                 no_v, no_ks, no_vs, key, temp):
+            def paged_rows(rows, pool):
+                t = row.shape[0] * pool.shape[1]
+                return (pool[row].reshape(t, pool.shape[-1]),
                         jnp.arange(t, dtype=jnp.int32))
             hidden, new, _ = self._sequence_stack(
                 tail_ids, t_start, w_start, t_total, row,
-                (k_pools, v_pools, k_scales, v_scales), mode, paged_rows)
+                (row_pools, no_v, no_ks, no_vs), mode, paged_rows)
             return (self._first_token(hidden, t_total - 1 - t_start, key,
                                       temp), *new)
         return self._as_program(body, "xing.paged_extend", 6, mode=mode)
@@ -751,20 +749,21 @@ class Xing(PagedServingModel):
         cfg = self.config
         scale = cfg.softmax_scale
 
-        def body(toks, k_pools, v_pools, k_scales, v_scales, tables, lens,
+        def body(toks, row_pools, no_v, no_ks, no_vs, tables, lens,
                  active, probe, key, temp):
-            from ..inference.paged import paged_decode_write
+            from ..inference.paged import latent_decode_write
             b = tables.shape[0]
             seen = jnp.where(active, lens + 1, lens)
             valid = Tensor(active)
             counts, taps, logit_rows = [], [], []
             layer_of = iter(self.layers)
 
-            def attend(q, kp, vp):
-                return next(layer_of).self_attn.absorbed(
-                    q[:, 0], lambda q_lat, q_rope: mla_decode_routed(
-                        q_lat, q_rope, kp, vp, tables, seen, scale=scale,
-                        kernel_mode=mode))
+            def attend(q, pool):
+                attn = next(layer_of).self_attn
+                return attn.absorbed(
+                    q[:, 0], lambda q_row: mla_decode_routed(
+                        q_row, pool, tables, seen, latent=attn.latent,
+                        scale=scale, kernel_mode=mode))
 
             def ffn(blk, m):
                 routed = []
@@ -775,9 +774,9 @@ class Xing(PagedServingModel):
             try:
                 hidden, new, _ = self._paged_stack(
                     self._streams(self.embed_tokens(Tensor(toks[:b, None]))),
-                    lens, (k_pools, v_pools, k_scales, v_scales),
-                    lambda kp, vp, c, k_r: paged_decode_write(
-                        kp, vp, tables, lens, c[:, 0], k_r[:, 0], active),
+                    lens, (row_pools, no_v, no_ks, no_vs),
+                    lambda pool, rows: (latent_decode_write(
+                        pool, tables, lens, rows[:, 0], active),),
                     attend, mlp=ffn)
             finally:
                 self.__dict__["_tap"] = None

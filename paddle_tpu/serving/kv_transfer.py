@@ -224,8 +224,8 @@ def _refuse_what_no_frame_carries(cache, what):
     """A frame carries blocks of K and V of one width a KV head. A
     cache that also holds recurrent state (``PagedKVCache.state_spec``)
     cannot be handed over in one: the state at the prefix's end is in no
-    block. Nor can a latent cache (``latent_spec``): its two pools have
-    other widths."""
+    block. Nor can a latent cache (``latent_spec``): it holds one pool a
+    layer, of another width."""
     if cache.state_spec is not None:
         raise ValueError(
             f"{what}: this cache holds recurrent state beside its KV "
@@ -235,9 +235,9 @@ def _refuse_what_no_frame_carries(cache, what):
     if cache.latent_spec is not None:
         raise ValueError(
             f"{what}: this cache holds latent rows (one row a token "
-            "shared by all heads, its rotary keys in a pool of another "
-            "width), and a transfer frame's geometry is K and V of one "
-            "width a KV head: no frame carries such rows yet.")
+            "shared by all heads, in one pool a layer), and a transfer "
+            "frame's geometry is K and V of one width a KV head: no "
+            "frame carries such rows yet.")
 
 
 def export_prefix(cache, token_ids):

@@ -127,7 +127,10 @@ def test_yarn_frequencies_blend_between_the_two_bounds():
 
 def test_the_model_declares_its_cache_and_its_initialisation(model):
     assert model.latent_rows == LatentRowSpec(latent=32, rope=8)
-    assert model.latent_rows.rope_lanes == 128
+    # the rotary keys in whole 128-lane tiles behind the compressed
+    # part: the lanes the two pools of PR 34 held, in one row
+    assert model.latent_rows.lanes == 32 + 128
+    assert LatentRowSpec(latent=512, rope=64).lanes == 640
     assert model.kv_cache_layers == 3 and model.recurrent_state is None
     assert model.decode_extras == 2 * 8 and model.decode_tap
     hc = model.layers[1].hc_mlp
@@ -347,19 +350,21 @@ def test_the_token_path_counts_experts_and_runs_a_step_ahead(model, weights,
                       dtype=jnp.float32, paged_kernel="pallas")
     cache = sched.cache
     assert cache.latent_spec == model.latent_rows
-    assert cache.k_pools[0].shape == (17, 8, 1, 32)
-    assert cache.v_pools[0].shape == (17, 8, 1, 128)
-    pools = 3 * 17 * 8 * (32 + 128) * 4
+    # one pool a layer and nothing beside it
+    assert [p.shape for p in cache.row_pools] == [(17, 8, 1, 160)] * 3
+    assert cache.pool_lists() == (cache.row_pools, [], [], [])
+    assert cache.pool_arrays() == cache.row_pools
+    pools = 3 * 17 * 8 * (32 + 128) * 4         # as with two pools
     assert cache.pool_bytes() == pools
     assert metrics.snapshot("serving.mla.latent_bytes")[
         "serving.mla.latent_bytes"] == pools
     sched.submit(_ids(5, seed=10), max_new_tokens=3)   # warms the step
     sched.run_to_completion()
-    handed = cache.k_pools[0]
+    handed = cache.row_pools[0]
     reqs = [sched.submit(_ids(n, seed=n), max_new_tokens=9)
             for n in (10, 7)]
     sched.run_to_completion()
-    assert handed.is_deleted() and not cache.k_pools[0].is_deleted()
+    assert handed.is_deleted() and not cache.row_pools[0].is_deleted()
     delta = {k: v - before.get(k, 0) for k, v in
              metrics.snapshot("serving.").items()
              if isinstance(v, (int, float))}
@@ -376,6 +381,58 @@ def test_the_token_path_counts_experts_and_runs_a_step_ahead(model, weights,
         assert req.status == "DONE"
         assert _served_right(weights, fields, req.prompt,
                              np.asarray(req.generated))
+
+
+def test_the_pair_of_names_reads_the_row_pool_without_making_a_pool(model):
+    """``cache.k_pools[i][table]`` / ``cache.v_pools[i][table]`` of a
+    latent cache answer as ``benchmarks/drivers/serve_latent._held_rows``
+    asks them: the compressed part exactly ``latent`` wide, the lanes
+    behind it at least ``rope`` wide, the values those written: a gather
+    of the pages asked for and a lane slice, never an array a pool
+    wide."""
+    cache = _cache(model)
+    spec = model.latent_rows
+    slot = cache.alloc_slot(21)
+    model.paged_prefill(cache, slot, _ids(21, seed=4), pad_to=32)
+    assert len(cache.k_pools) == len(cache.v_pools) == cache.num_layers
+    table = jnp.asarray(cache.block_tables[slot, :3].copy())
+    for i in range(cache.num_layers):
+        c = cache.k_pools[i][table]
+        k_r = cache.v_pools[i][table]
+        assert c.shape == (3, 8, 1, spec.latent)
+        assert k_r.shape[:3] == (3, 8, 1) and k_r.shape[-1] >= spec.rope
+        assert cache.k_pools[i].shape == (25, 8, 1, spec.latent)
+        assert cache.v_pools[i].shape[-1] == spec.lanes - spec.latent
+        for got in (c, k_r):     # pages, not pools
+            assert got.size <= 3 * 8 * spec.lanes
+        # what the prefill wrote (held to the reference by the tests
+        # above, which read through these names)
+        rows = np.asarray(cache.row_pools[i][table])
+        np.testing.assert_array_equal(c, rows[..., :spec.latent])
+        np.testing.assert_array_equal(k_r, rows[..., spec.latent:])
+        assert np.asarray(c).reshape(24, -1)[:21].any(axis=1).all()
+        assert np.asarray(k_r)[..., :spec.rope].any()
+        assert not np.asarray(k_r)[..., spec.rope:].any()
+    # after a step the names read the pools the step returned
+    handed = cache.row_pools[0]
+    assert cache.ensure_capacity(slot, 22)
+    model.paged_decode_step(cache, np.array([5, 0, 0]),
+                            np.array([True, False, False]))
+    assert handed.is_deleted()
+    assert np.asarray(cache.k_pools[0][table]).shape == (3, 8, 1,
+                                                         spec.latent)
+
+
+def test_the_row_pool_holds_the_bytes_the_two_pools_held():
+    """At the published widths (512 + 64) a row is 640 lanes: the 512 +
+    128 of PR 34's two pools, in one. ``pool_bytes()`` does not move."""
+    spec = LatentRowSpec(latent=512, rope=64)
+    cache = PagedKVCache(2, 1, 576, num_blocks=3, block_size=16,
+                         max_blocks_per_seq=2, max_batch=1,
+                         dtype=jnp.bfloat16, latent_rows=spec)
+    assert [p.shape for p in cache.pool_arrays()] == [(3, 16, 1, 640)] * 2
+    assert cache.pool_bytes() == 2 * 3 * 16 * (512 + 128) * 2
+    assert cache.head_dim == 512 and cache.num_kv_heads == 1
 
 
 # -- what such a cache cannot do yet ---------------------------------------------------

@@ -301,20 +301,17 @@ def test_paged_decode_lowers_at_twenty_rows_on_one_kv_head():
 
 
 def test_mla_decode_lowers_at_the_served_widths():
-    """32 query rows of 512 + 64 a slot over a latent pool of 128 slots x
-    256 pages: the rotary pool's rows are 128 lanes wide (a 64-lane row
-    cannot be sliced for a DMA)."""
+    """32 query rows a slot over the one latent pool of 128 slots x 256
+    pages: a row of 512 + 64 in 640 lanes (whole tiles: a DMA cannot
+    slice half of one), a page one copy."""
     from paddle_tpu.kernels.pallas.mla_decode import mla_decode_routed
 
-    b, h, latent, rope, bs, pages = 128, 32, 512, 64, 16, 256
-    nb = 1 + b * pages
-    avals = [_aval((b, h, latent), jnp.bfloat16),
-             _aval((b, h, rope), jnp.bfloat16),
-             _aval((nb, bs, 1, latent), jnp.bfloat16),
-             _aval((nb, bs, 1, 128), jnp.bfloat16),
+    b, h, latent, lanes, bs, pages = 128, 32, 512, 640, 16, 256
+    avals = [_aval((b, h, lanes), jnp.bfloat16),
+             _aval((1 + b * pages, bs, 1, lanes), jnp.bfloat16),
              _aval((b, pages), jnp.int32), _aval((b,), jnp.int32)]
-    n = _lower(lambda *a: mla_decode_routed(*a, scale=0.14468,
-                                            kernel_mode="pallas"), *avals)
+    n = _lower(lambda *a: mla_decode_routed(
+        *a, latent=latent, scale=0.14468, kernel_mode="pallas"), *avals)
     assert n == 1
 
 

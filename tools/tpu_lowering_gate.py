@@ -211,6 +211,23 @@ def gate_paged() -> bool:
     return ok
 
 
+def gate_mla() -> bool:
+    """The absorbed latent-attention decode kernel at the widths
+    ``xing29b-reasoning-saturated`` serves: 128 slots of 32 query rows
+    over ONE pool of rows 640 lanes wide (512 + 64, to whole tiles), a
+    256-page table; a page is one DMA the body starts itself."""
+    from paddle_tpu.kernels.pallas.mla_decode import mla_decode_attention
+
+    b, h, latent, lanes, bs, pages = 128, 32, 512, 640, 16, 256
+    return gate(
+        "mla_decode_block16x256",
+        lambda q, pool, t, l: mla_decode_attention(
+            q, pool, t, l, latent=latent, scale=0.14468, interpret=False),
+        abstract((b, h, lanes), jnp.bfloat16),
+        abstract((1 + b * pages, bs, 1, lanes), jnp.bfloat16),
+        abstract((b, pages), jnp.int32), abstract((b,), jnp.int32))
+
+
 def gate_quant_matmul() -> bool:
     from paddle_tpu.kernels.pallas.quant_matmul import quant_matmul
 
@@ -507,6 +524,7 @@ def main():
     ok = True
     ok &= gate_flash()
     ok &= gate_paged()
+    ok &= gate_mla()
     ok &= gate_quant_matmul()
     ok &= gate_on_mesh()
     ok &= gate_train_step()
