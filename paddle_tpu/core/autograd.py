@@ -16,6 +16,9 @@ from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
+from jax._src.source_info_util import (
+    current_name_stack as _current_name_stack,
+    set_name_stack as _set_name_stack)
 
 from . import dtype as dtype_mod
 
@@ -87,7 +90,7 @@ class Node:
     """
 
     __slots__ = ("vjp_fn", "inputs", "parents", "out_meta", "name",
-                 "fwd_fn", "tensor_vjp", "primals", "__weakref__")
+                 "fwd_fn", "tensor_vjp", "primals", "scope", "__weakref__")
 
     def __init__(
         self,
@@ -121,6 +124,12 @@ class Node:
         # snapshot above. No extra memory: fwd_fn's closure already
         # references these arrays.
         self.primals = tuple(primals) if primals is not None else None
+        # inside a traced program: the named scopes the op was recorded
+        # under (``layers.3/pt.attn/self_attn/o_proj``), which the
+        # backward re-enters so that the pullback's operations say which
+        # part of the model they are the gradient of. Eager: None.
+        stack = _current_name_stack()
+        self.scope = stack if stack.stack else None
 
     def __repr__(self):
         return f"<Node {self.name} n_in={len(self.inputs)} n_out={len(self.out_meta)}>"
@@ -231,13 +240,7 @@ def backward(tensors, grad_tensors=None, retain_graph=False, _into=None,
 
     order = _topo_order(root_nodes)
 
-    for node in order:
-        nid = id(node)
-        cts = pending.get(nid)
-        if cts is None:
-            # Reachable from roots topologically but received no cotangent
-            # (all consumers were grad-pruned); its inputs get zeros — skip.
-            continue
+    def _pull(node, cts):
         full = tuple(
             ct if ct is not None else _zero_cotangent(shape, dt)
             for ct, (shape, dt) in zip(cts, node.out_meta)
@@ -259,6 +262,19 @@ def backward(tensors, grad_tensors=None, retain_graph=False, _into=None,
                     node_by_id[pid] = prod
                 slot = pending[pid]
                 slot[idx] = g if slot[idx] is None else slot[idx] + g
+
+    for node in order:
+        nid = id(node)
+        cts = pending.get(nid)
+        if cts is None:
+            # Reachable from roots topologically but received no cotangent
+            # (all consumers were grad-pruned); its inputs get zeros — skip.
+            continue
+        if node.scope is None:
+            _pull(node, cts)
+        else:  # a traced program: under the scopes of the node's forward
+            with _set_name_stack(node.scope):
+                _pull(node, cts)
         pending[nid] = None  # free cotangents early
 
     # Accumulate into .grad (GradNodeAccumulation analogue), or into the

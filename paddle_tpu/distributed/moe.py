@@ -269,52 +269,63 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k,
     t, d = x.shape
     n_experts = router_w.shape[1]
     held = w_gate.shape[0]
-    weights, idx = route_topk(x, router_w, top_k, norm_topk_prob) \
-        if router is None else router(x, router_w)
-    flat = idx.reshape(-1)                              # [A], A = T * k
-    a = flat.shape[0]
-    real = jnp.ones((a,), bool) if valid is None \
-        else jnp.repeat(valid, top_k)
-    counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(
-        real.astype(jnp.int32))
-    local = flat - jnp.int32(expert_lo)
-    mine = (local >= 0) & (local < held) & real
-    # assignments held elsewhere sort past the last group
-    key = jnp.where(mine, local, held)
-    order = jnp.argsort(key, stable=True)
-    sorted_key = key[order]
-    sizes = jax.lax.dynamic_slice(counts, (jnp.int32(expert_lo),),
-                                  (held,))
-    tm = K.tile_rows(a, held)
-    m, offsets, padded, tile_expert, num_tiles = K.tile_layout(
-        sizes, tm, a)
-    starts = jnp.cumsum(sizes) - sizes
-    safe = jnp.minimum(sorted_key, held - 1)
-    rank = jnp.arange(a, dtype=jnp.int32) - starts[safe]
-    # out of range for the rows held elsewhere: the scatter drops them
-    dest_sorted = jnp.where(sorted_key < held, offsets[safe] + rank, m)
-    rows = jnp.zeros((m, d), x.dtype).at[dest_sorted].set(
-        x[order // top_k], mode="drop")
-    if route == "plain":
-        h = K.moe_gmm_swiglu_plain(rows, w_gate, w_up, padded)
-        y = K.moe_gmm_plain(h, w_down, padded)
-    else:
-        interpret = route == "interpret"
-        h = K.moe_gmm_swiglu(rows, w_gate, w_up, tile_expert, num_tiles,
-                             tm=tm, interpret=interpret, tag=kernel_tag)
-        y = K.moe_gmm(h, w_down, tile_expert, num_tiles, tm=tm,
-                      interpret=interpret, tag=kernel_tag)
-    dest = jnp.zeros((a,), jnp.int32).at[order].set(
-        jnp.minimum(dest_sorted, m - 1))
-    picked = jnp.where(mine[:, None], y[dest].astype(jnp.float32), 0.0)
-    out = jnp.sum(picked.reshape(t, top_k, d) * weights[..., None], axis=1)
+    # the stages' names in a traced program (``.../pt.ffn/mlp/dispatch/
+    # sort``): what a profile splits the layer's time by
+    with jax.named_scope("router"):
+        weights, idx = route_topk(x, router_w, top_k, norm_topk_prob) \
+            if router is None else router(x, router_w)
+    with jax.named_scope("dispatch"):
+        flat = idx.reshape(-1)                          # [A], A = T * k
+        a = flat.shape[0]
+        real = jnp.ones((a,), bool) if valid is None \
+            else jnp.repeat(valid, top_k)
+        counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(
+            real.astype(jnp.int32))
+        local = flat - jnp.int32(expert_lo)
+        mine = (local >= 0) & (local < held) & real
+        # assignments held elsewhere sort past the last group
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)
+        sorted_key = key[order]
+        sizes = jax.lax.dynamic_slice(counts, (jnp.int32(expert_lo),),
+                                      (held,))
+        tm = K.tile_rows(a, held)
+        m, offsets, padded, tile_expert, num_tiles = K.tile_layout(
+            sizes, tm, a)
+        starts = jnp.cumsum(sizes) - sizes
+        safe = jnp.minimum(sorted_key, held - 1)
+        rank = jnp.arange(a, dtype=jnp.int32) - starts[safe]
+        # out of range for the rows held elsewhere: the scatter drops
+        # them
+        dest_sorted = jnp.where(sorted_key < held, offsets[safe] + rank, m)
+        rows = jnp.zeros((m, d), x.dtype).at[dest_sorted].set(
+            x[order // top_k], mode="drop")
+    with jax.named_scope("experts"):
+        if route == "plain":
+            h = K.moe_gmm_swiglu_plain(rows, w_gate, w_up, padded)
+            y = K.moe_gmm_plain(h, w_down, padded)
+        else:
+            interpret = route == "interpret"
+            h = K.moe_gmm_swiglu(rows, w_gate, w_up, tile_expert,
+                                 num_tiles, tm=tm, interpret=interpret,
+                                 tag=kernel_tag)
+            y = K.moe_gmm(h, w_down, tile_expert, num_tiles, tm=tm,
+                          interpret=interpret, tag=kernel_tag)
+    with jax.named_scope("combine"):
+        dest = jnp.zeros((a,), jnp.int32).at[order].set(
+            jnp.minimum(dest_sorted, m - 1))
+        picked = jnp.where(mine[:, None], y[dest].astype(jnp.float32), 0.0)
+        out = jnp.sum(picked.reshape(t, top_k, d) * weights[..., None],
+                      axis=1)
     if shared is not None:
-        s_gate, s_up, s_down = shared
-        f32 = jnp.float32
-        h = jax.nn.silu(jnp.matmul(x, s_gate, preferred_element_type=f32)) \
-            * jnp.matmul(x, s_up, preferred_element_type=f32)
-        out = out + jnp.matmul(h.astype(x.dtype), s_down,
-                               preferred_element_type=f32)
+        with jax.named_scope("shared"):
+            s_gate, s_up, s_down = shared
+            f32 = jnp.float32
+            h = jax.nn.silu(
+                jnp.matmul(x, s_gate, preferred_element_type=f32)) \
+                * jnp.matmul(x, s_up, preferred_element_type=f32)
+            out = out + jnp.matmul(h.astype(x.dtype), s_down,
+                                   preferred_element_type=f32)
     return out.astype(x.dtype), counts, (weights, idx)
 
 

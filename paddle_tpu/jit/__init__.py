@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from ..core import random as random_mod
 from ..core.autograd import backward as tape_backward
 from ..core.tensor import Parameter, Tensor
+from ..profiler.tracing import scope as _scope
 
 __all__ = ["to_static", "TrainStep", "save", "load", "no_retrace",
            "TranslatedLayer", "enable_to_static", "ignore_module",
@@ -400,37 +401,9 @@ class TrainStep:
                               _into=grad_store)
 
                 grads = [grad_store.get(id(p)) for p in param_objs]
-                # grad clip (pure form)
-                if opt._grad_clip is not None:
-                    have = [i for i, g in enumerate(grads) if g is not None]
-                    clipped = opt._grad_clip._clip_arrays(
-                        [grads[i] for i in have],
-                        [getattr(param_objs[i], "need_clip", True)
-                         for i in have])
-                    for i, g in zip(have, clipped):
-                        grads[i] = g
-
-                from ..optimizer.optimizer import _lr_mult
-
-                opt._t = t
-                new_params = []
-                new_slots = []
-                for p, g, st, group in zip(param_objs, grads, slot_states,
-                                           groups):
-                    if g is None or group is None:
-                        new_params.append(p._data)
-                        new_slots.append(st)
-                        continue
-                    lr_p = lr * group["lr_mult"] * _lr_mult(p)
-                    p32 = st["master"] if st.get("master") is not None \
-                        else p._data.astype(jnp.float32)
-                    g32 = g.astype(jnp.float32)
-                    np_, nst = opt._apply_param(p32, g32, st, lr_p, group,
-                                                param=p)
-                    if st.get("master") is not None:
-                        nst["master"] = np_
-                    new_params.append(np_.astype(p._data.dtype))
-                    new_slots.append(nst)
+                with _scope("optimizer"):
+                    new_params, new_slots = self._update(
+                        param_objs, grads, slot_states, groups, t, lr)
                 new_buffers = [b._data for b in buffer_objs]
                 aux_arrays = jax.tree.map(
                     _tree_unwrap, tuple(aux),
@@ -454,6 +427,40 @@ class TrainStep:
         self._pure = train_step
         self._jitted = jax.jit(train_step, donate_argnums=donate,
                                out_shardings=self._out_shardings())
+
+    def _update(self, param_objs, grads, slot_states, groups, t, lr):
+        """The optimizer's part of the traced step: the gradient clip
+        and the update of every parameter that has a gradient. Returns
+        (new parameter arrays, new slot states)."""
+        opt = self._opt
+        if opt._grad_clip is not None:  # grad clip (pure form)
+            have = [i for i, g in enumerate(grads) if g is not None]
+            clipped = opt._grad_clip._clip_arrays(
+                [grads[i] for i in have],
+                [getattr(param_objs[i], "need_clip", True) for i in have])
+            for i, g in zip(have, clipped):
+                grads[i] = g
+
+        from ..optimizer.optimizer import _lr_mult
+
+        opt._t = t
+        new_params = []
+        new_slots = []
+        for p, g, st, group in zip(param_objs, grads, slot_states, groups):
+            if g is None or group is None:
+                new_params.append(p._data)
+                new_slots.append(st)
+                continue
+            lr_p = lr * group["lr_mult"] * _lr_mult(p)
+            p32 = st["master"] if st.get("master") is not None \
+                else p._data.astype(jnp.float32)
+            g32 = g.astype(jnp.float32)
+            np_, nst = opt._apply_param(p32, g32, st, lr_p, group, param=p)
+            if st.get("master") is not None:
+                nst["master"] = np_
+            new_params.append(np_.astype(p._data.dtype))
+            new_slots.append(nst)
+        return new_params, new_slots
 
     def _out_shardings(self):
         """None everywhere (XLA's choice); ShardedTrainStep pins params."""

@@ -19,6 +19,7 @@ import math
 
 from .. import nn
 from ..nn import functional as F
+from ..profiler.tracing import scope as _scope
 
 
 @dataclasses.dataclass
@@ -117,9 +118,17 @@ class GPTBlock(nn.Layer):
         self.dropout = nn.Dropout(config.dropout)
 
     def forward(self, x):
-        x = x + self.dropout(self.attn(self.ln_1(x)))
-        x = x + self.dropout(self.mlp(self.ln_2(x)))
-        return x
+        with _scope("residual"):
+            h = self.ln_1(x)
+        with _scope("attn"):
+            out = self.attn(h)
+        with _scope("residual"):
+            x = x + self.dropout(out)
+            h = self.ln_2(x)
+        with _scope("ffn"):
+            out = self.mlp(h)
+        with _scope("residual"):
+            return x + self.dropout(out)
 
 
 class GPT(nn.Layer):
@@ -153,35 +162,40 @@ class GPT(nn.Layer):
         """Transformer stack output (post ln_f), before the LM head."""
         from .. import ops
         b, s = input_ids.shape
-        pos = ops.arange(0, s, dtype="int64")
-        x = self.wte(input_ids) + self.wpe(pos)
-        x = self.drop(x)
+        with _scope("residual"):
+            pos = ops.arange(0, s, dtype="int64")
+            x = self.wte(input_ids) + self.wpe(pos)
+            x = self.drop(x)
         for block in self.h:
             x = block(x)
-        return self.ln_f(x)
+        with _scope("residual"):
+            return self.ln_f(x)
 
     def forward(self, input_ids):
         from .. import ops
         x = self.forward_hidden(input_ids)
-        if self.lm_head is not None:
-            return self.lm_head(x)
-        # weight-tied head: [b,s,d] @ [d,vocab]
-        return ops.matmul(x, self.wte.weight, transpose_y=True)
+        with _scope("head"):
+            if self.lm_head is not None:
+                return self.lm_head(x)
+            # weight-tied head: [b,s,d] @ [d,vocab]
+            return ops.matmul(x, self.wte.weight, transpose_y=True)
 
     def loss(self, input_ids, labels):
         """Next-token cross entropy; labels already shifted or equal to
         input_ids (we shift internally)."""
         if self.config.fused_head_ce:
             # blockwise head+CE: the [b,s,V] logits never materialize
-            x = self.forward_hidden(input_ids)[:, :-1, :]
-            tied = self.lm_head is None
-            w = self.wte.weight if tied else self.lm_head.weight
-            return F.fused_linear_cross_entropy(x, w, labels[:, 1:],
-                                                transpose_weight=tied)
+            x = self.forward_hidden(input_ids)
+            with _scope("head"):
+                tied = self.lm_head is None
+                w = self.wte.weight if tied else self.lm_head.weight
+                return F.fused_linear_cross_entropy(
+                    x[:, :-1, :], w, labels[:, 1:], transpose_weight=tied)
         logits = self(input_ids)
-        shift_logits = logits[:, :-1, :]
-        shift_labels = labels[:, 1:]
-        return F.cross_entropy(shift_logits, shift_labels)
+        with _scope("head"):
+            shift_logits = logits[:, :-1, :]
+            shift_labels = labels[:, 1:]
+            return F.cross_entropy(shift_logits, shift_labels)
 
     def num_params(self, non_embedding=True):
         n = sum(p.size for p in self.parameters())

@@ -60,6 +60,7 @@ from ..nn import functional as F
 from ..nn.initializer import Constant, Initializer
 from ..profiler import metrics as _metrics
 from ..profiler.tracing import phase as _phase
+from ..profiler.tracing import scope as _scope
 from .llama import LlamaMLP, PagedServingModel, _normal_attr
 
 __all__ = ["Jamba", "JambaConfig"]
@@ -334,10 +335,21 @@ class JambaBlock(nn.Layer):
         return self.pre_ff_layernorm
 
     def forward(self, x):
-        h = self.input_layernorm(x)
-        x = x + (self.mixer(h) if self.self_attn is None
-                 else self.self_attn(h))
-        return x + self.mlp(self.pre_ff_layernorm(x))
+        with _scope("residual"):
+            h = self.input_layernorm(x)
+        if self.self_attn is None:
+            with _scope("mixer"):
+                out = self.mixer(h)
+        else:
+            with _scope("attn"):
+                out = self.self_attn(h)
+        with _scope("residual"):
+            x = x + out
+            h = self.pre_ff_layernorm(x)
+        with _scope("ffn"):
+            out = self.mlp(h)
+        with _scope("residual"):
+            return x + out
 
 
 class Jamba(PagedServingModel):
@@ -376,10 +388,12 @@ class Jamba(PagedServingModel):
     def forward(self, input_ids):
         """Logits [b, s, vocab] of ``input_ids`` [b, s] from zero
         state."""
-        x = self.embed_tokens(input_ids)
+        x = self._embed(input_ids)
         for blk in self.layers:
             x = blk(x)
-        return self._logits(self.norm(x))
+        with _scope("residual"):
+            x = self.norm(x)
+        return self._logits(x)
 
     # -- served path: programs over the paged cache and its state --------
 
@@ -480,7 +494,7 @@ class Jamba(PagedServingModel):
                 return F.scaled_dot_product_attention(
                     Tensor(q), Tensor(k), Tensor(v), is_causal=True)._data
             hidden, new, state = self._sequence_stack(
-                self.embed_tokens(Tensor(ids_arr)), jnp.int32(0), true_len,
+                self._embed(Tensor(ids_arr)), jnp.int32(0), true_len,
                 True, row, slot, (k_pools, v_pools, k_scales, v_scales),
                 state, mode, attend)
             return (self._first_token(hidden, true_len - 1, key, temp),
@@ -533,7 +547,7 @@ class Jamba(PagedServingModel):
                  v_pools, k_scales, v_scales, state, key, temp):
             from ..inference.paged import paged_prefix_attention_dense
             hidden, new, state = self._sequence_stack(
-                self.embed_tokens(Tensor(tail_ids)), t_start, t_total,
+                self._embed(Tensor(tail_ids)), t_start, t_total,
                 from_zero, row, slot,
                 (k_pools, v_pools, k_scales, v_scales), state, mode,
                 lambda q, k, v, kp, vp: paged_prefix_attention_dense(
@@ -591,7 +605,7 @@ class Jamba(PagedServingModel):
                 return Tensor(out[:, None]), (ssm, conv)
 
             hidden, new, state = self._paged_stack(
-                self.embed_tokens(Tensor(toks[:, None])), lens,
+                self._embed(Tensor(toks[:, None])), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v: paged_decode_write(
                     kp, vp, tables, lens, k[:, 0], v[:, 0], active),

@@ -24,6 +24,7 @@ from ..core.dispatch import apply
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..profiler.tracing import phase as _phase
+from ..profiler.tracing import scope as _scope
 
 # guards lazy creation of each model's paged-call lock (Llama._paged_lock)
 _PAGED_LOCK_INIT = threading.Lock()
@@ -256,17 +257,23 @@ class LlamaBlock(nn.Layer):
         self.mlp = LlamaMLP(config)
 
     def forward(self, x, cache=None, position_offset=0, kv_sink=None):
-        if cache is None:
-            x = x + self.self_attn(self.input_layernorm(x),
-                                   kv_sink=kv_sink)
-            x = x + self.mlp(self.post_attention_layernorm(x))
-            return x
-        attn_out, new_cache = self.self_attn(
-            self.input_layernorm(x), cache=cache,
-            position_offset=position_offset)
-        x = x + attn_out
-        x = x + self.mlp(self.post_attention_layernorm(x))
-        return x, new_cache
+        with _scope("residual"):
+            h = self.input_layernorm(x)
+        new_cache = None
+        with _scope("attn"):
+            if cache is None:
+                attn_out = self.self_attn(h, kv_sink=kv_sink)
+            else:
+                attn_out, new_cache = self.self_attn(
+                    h, cache=cache, position_offset=position_offset)
+        with _scope("residual"):
+            x = x + attn_out
+            h = self.post_attention_layernorm(x)
+        with _scope("ffn"):
+            out = self.mlp(h)
+        with _scope("residual"):
+            x = x + out
+        return x if cache is None else (x, new_cache)
 
 
 class PagedServingModel(nn.Layer):
@@ -446,33 +453,45 @@ class PagedServingModel(nn.Layer):
         b, s = x.shape[:2]
         new = ([], [], [], [])
         carried = 0
-        for blk in self.layers:
-            attn = blk.self_attn
-            u, mixed = self.residual_read(blk, 0, x)
-            if attn is None:
-                out, state = mix(blk.mixer, blk.input_layernorm(u), state,
-                                 carried)
-                carried += 1
-            else:
-                i = len(new[0])
-                q, *rows = attn.qkv(blk.input_layernorm(u),
-                                    position_offset)
-                held = [pools_of[i] for pools_of in (k_pools, v_pools)
-                        if pools_of]
-                scales = _layer_scales(k_scales, v_scales, i)
-                layer = write(*held, *(r._data for r in rows), **scales)
-                for pool_list, pool in zip(new, layer):
-                    pool_list.append(pool)
-                out = attend(q._data, *layer[:len(held)],
-                             **dict(zip(scales, layer[len(held):])))
-                out = attn.o_proj(Tensor(out.reshape(b, s, -1)))
-            x = self.residual_write(blk, 0, x, out, mixed)
-            u, mixed = self.residual_read(blk, 1, x)
-            m = blk.post_attention_layernorm(u)
-            x = self.residual_write(
-                blk, 1, x, blk.mlp(m) if mlp is None else mlp(blk, m),
-                mixed)
-        return self.norm(self.residual_close(x)), new, state
+        for n, blk in enumerate(self.layers):
+            # what a trace shows of an operation in here:
+            # ``layers.<n>/pt.<component>/<sublayer>/../<operation>``
+            with jax.named_scope(f"layers.{n}"):
+                attn = blk.self_attn
+                with _scope("residual"):
+                    u, mixed = self.residual_read(blk, 0, x)
+                    h = blk.input_layernorm(u)
+                if attn is None:
+                    mixer = blk.mixer
+                    with _scope("mixer"), jax.named_scope(mixer._name_scope):
+                        out, state = mix(mixer, h, state, carried)
+                    carried += 1
+                else:
+                    with _scope("attn"), jax.named_scope(attn._name_scope):
+                        i = len(new[0])
+                        q, *rows = attn.qkv(h, position_offset)
+                        held = [pools_of[i]
+                                for pools_of in (k_pools, v_pools)
+                                if pools_of]
+                        scales = _layer_scales(k_scales, v_scales, i)
+                        layer = write(*held, *(r._data for r in rows),
+                                      **scales)
+                        for pool_list, pool in zip(new, layer):
+                            pool_list.append(pool)
+                        out = attend(q._data, *layer[:len(held)],
+                                     **dict(zip(scales,
+                                                layer[len(held):])))
+                        out = attn.o_proj(Tensor(out.reshape(b, s, -1)))
+                with _scope("residual"):
+                    x = self.residual_write(blk, 0, x, out, mixed)
+                    u, mixed = self.residual_read(blk, 1, x)
+                    m = blk.post_attention_layernorm(u)
+                with _scope("ffn"):
+                    out = blk.mlp(m) if mlp is None else mlp(blk, m)
+                with _scope("residual"):
+                    x = self.residual_write(blk, 1, x, out, mixed)
+        with _scope("residual"):
+            return self.norm(self.residual_close(x)), new, state
 
     # -- the residual path: one stream, each sublayer's output added ------
     # (a model with another path overrides the three: models/xing.py)
@@ -491,12 +510,18 @@ class PagedServingModel(nn.Layer):
         """The residual as the final norm reads it."""
         return x
 
+    def _embed(self, ids):
+        """The embedding of ``ids``, which starts the residual path."""
+        with _scope("residual"):
+            return self.embed_tokens(ids)
+
     def _logits(self, hidden):
-        if self.lm_head is not None:
-            return self.lm_head(hidden)
-        from .. import ops
-        return ops.matmul(hidden, self.embed_tokens.weight,
-                          transpose_y=True)
+        with _scope("head"):
+            if self.lm_head is not None:
+                return self.lm_head(hidden)
+            from .. import ops
+            return ops.matmul(hidden, self.embed_tokens.weight,
+                              transpose_y=True)
 
     def _next_token(self, hidden, pick, key=None, temp=None):
         """The head over the stack's normed output, at the positions
@@ -504,18 +529,20 @@ class PagedServingModel(nn.Layer):
         (``key`` given) a sample at temperature ``temp`` where that is
         positive."""
         from .generation import sample_token
-        last = pick(self._logits(hidden)._data)
+        logits = self._logits(hidden)._data
+        with _scope("head"):
+            last = pick(logits)
 
-        def greedy():
-            return jnp.argmax(last, axis=-1).astype(jnp.int32)
+            def greedy():
+                return jnp.argmax(last, axis=-1).astype(jnp.int32)
 
-        if key is None:
-            return greedy()
-        return jax.lax.cond(
-            temp > 0,
-            lambda: sample_token(last / jnp.maximum(temp, 1e-6),
-                                 temperature=1.0, key=key),
-            greedy)
+            if key is None:
+                return greedy()
+            return jax.lax.cond(
+                temp > 0,
+                lambda: sample_token(last / jnp.maximum(temp, 1e-6),
+                                     temperature=1.0, key=key),
+                greedy)
 
     def _param_rebind(self):
         if not hasattr(self, "_pb_names"):
@@ -601,13 +628,14 @@ class Llama(PagedServingModel):
         if caches is None:
             x = self.forward_hidden(input_ids, kv_sink=kv_sink)
         else:
-            x = self.embed_tokens(input_ids)
+            x = self._embed(input_ids)
             new_caches = []
             for i, block in enumerate(self.layers):
                 x, c = block(x, cache=caches[i],
                              position_offset=position_offset)
                 new_caches.append(c)
-            x = self.norm(x)
+            with _scope("residual"):
+                x = self.norm(x)
         logits = self._logits(x)
         if caches is None:
             return logits
@@ -705,9 +733,11 @@ class Llama(PagedServingModel):
                     axis=1)[:, 0], key, temp)
             new = ([], [], [], [])
             for i, (k, v) in enumerate(sink):
-                layer = paged_prefill_write(
-                    k_pools[i], v_pools[i], row, k._data[0], v._data[0],
-                    **_layer_scales(k_scales, v_scales, i))
+                # the cache write is the attention sublayer's
+                with jax.named_scope(f"layers.{i}"), _scope("attn"):
+                    layer = paged_prefill_write(
+                        k_pools[i], v_pools[i], row, k._data[0],
+                        v._data[0], **_layer_scales(k_scales, v_scales, i))
                 for pool_list, pool in zip(new, layer):
                     pool_list.append(pool)
             return (tok[0], *new)
@@ -757,7 +787,7 @@ class Llama(PagedServingModel):
             from ..inference.paged import (paged_prefill_write_masked,
                                            paged_prefix_attention_dense)
             hidden, new, _ = self._paged_stack(
-                self.embed_tokens(Tensor(tail_ids)), t_start,
+                self._embed(Tensor(tail_ids)), t_start,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v, **scales: paged_prefill_write_masked(
                     kp, vp, row, k[0], v[0], t_start, w_start, t_total,
@@ -821,7 +851,7 @@ class Llama(PagedServingModel):
                 paged_decode_attention_tp, mesh=mesh) if use_tp \
                 else paged_decode_attention
             hidden, new, _ = self._paged_stack(
-                self.embed_tokens(Tensor(toks[:, None])), lens,
+                self._embed(Tensor(toks[:, None])), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v, **scales: paged_decode_write(
                     kp, vp, tables, lens, k[:, 0], v[:, 0], active,
@@ -853,7 +883,7 @@ class Llama(PagedServingModel):
             from ..inference.paged import (paged_spec_attention_dense,
                                            paged_spec_write)
             hidden, new, _ = self._paged_stack(
-                self.embed_tokens(Tensor(toks)), lens,
+                self._embed(Tensor(toks)), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v, **scales: paged_spec_write(
                     kp, vp, tables, lens, k, v, n_inputs, active,
@@ -892,20 +922,24 @@ class Llama(PagedServingModel):
         mesh = self.serving_mesh()
         # a mesh-served model's attention kernel runs per head shard
         with kernel_mesh(mesh.jax_mesh if mesh is not None else None):
-            x = self.embed_tokens(input_ids)
+            x = self._embed(input_ids)
             for block in self.layers:
                 x = block(x, kv_sink=kv_sink)
-            return self.norm(x)
+            with _scope("residual"):
+                return self.norm(x)
 
     def loss(self, input_ids, labels):
         if self.config.fused_head_ce:
-            x = self.forward_hidden(input_ids)[:, :-1, :]
-            tied = self.lm_head is None
-            w = self.embed_tokens.weight if tied else self.lm_head.weight
-            return F.fused_linear_cross_entropy(x, w, labels[:, 1:],
-                                                transpose_weight=tied)
+            x = self.forward_hidden(input_ids)
+            with _scope("head"):
+                tied = self.lm_head is None
+                w = self.embed_tokens.weight if tied \
+                    else self.lm_head.weight
+                return F.fused_linear_cross_entropy(
+                    x[:, :-1, :], w, labels[:, 1:], transpose_weight=tied)
         logits = self(input_ids)
-        return F.cross_entropy(logits[:, :-1, :], labels[:, 1:])
+        with _scope("head"):
+            return F.cross_entropy(logits[:, :-1, :], labels[:, 1:])
 
     def flops_per_token(self, seq_len):
         n = self.num_params()

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,6 +42,7 @@ from ..core.tensor import Tensor
 from ..distributed.moe import DroplessMoE
 from ..nn import functional as F
 from ..profiler.tracing import phase as _phase
+from ..profiler.tracing import scope as _scope
 from .llama import PagedServingModel, _normal_attr, apply_rope
 
 __all__ = ["SDAR", "SDARConfig", "block_causal_mask",
@@ -237,16 +239,25 @@ class SDAR(PagedServingModel):
         """One layer on x [1, s, d] under ``mask`` [s, s]. With
         ``attention`` False the layer stops at its keys and values (the
         last layer of a prefill, whose output nobody reads)."""
-        q, k, v = blk.self_attn.qkv(blk.input_layernorm(x))
-        if kv_sink is not None:
-            kv_sink.append((k, v))
-        if not attention:
-            return x
-        h = x + blk.self_attn.out(F.scaled_dot_product_attention(
-            q, k, v, attn_mask=mask))
-        return h + blk.mlp(blk.post_attention_layernorm(h),
-                           kernel_mode=kernel_mode,
-                           counts_sink=counts_sink)
+        attn = blk.self_attn
+        with _scope("residual"):
+            u = blk.input_layernorm(x)
+        with _scope("attn"), jax.named_scope(attn._name_scope):
+            q, k, v = attn.qkv(u)
+            if kv_sink is not None:
+                kv_sink.append((k, v))
+            if not attention:
+                return x
+            out = attn.out(F.scaled_dot_product_attention(
+                q, k, v, attn_mask=mask))
+        with _scope("residual"):
+            h = x + out
+            m = blk.post_attention_layernorm(h)
+        with _scope("ffn"):
+            out = blk.mlp(m, kernel_mode=kernel_mode,
+                          counts_sink=counts_sink)
+        with _scope("residual"):
+            return h + out
 
     def forward(self, input_ids, kernel_mode=None, counts_sink=None):
         """Logits [b, s, vocab] of ``input_ids`` [b, s] under the
@@ -254,11 +265,14 @@ class SDAR(PagedServingModel):
         s = input_ids.shape[1]
         pos = jnp.arange(s, dtype=jnp.int32)
         mask = block_causal_mask(pos, pos, self.config.block_length)
-        x = self.embed_tokens(input_ids)
-        for blk in self.layers:
-            x = self._layer(blk, x, mask, kernel_mode=kernel_mode,
-                            counts_sink=counts_sink)
-        return self.lm_head(self.norm(x))
+        x = self._embed(input_ids)
+        for n, blk in enumerate(self.layers):
+            with jax.named_scope(f"layers.{n}"):
+                x = self._layer(blk, x, mask, kernel_mode=kernel_mode,
+                                counts_sink=counts_sink)
+        with _scope("residual"):
+            x = self.norm(x)
+        return self._logits(x)
 
     # -- served path: programs over the paged cache ----------------------
 
@@ -303,20 +317,23 @@ class SDAR(PagedServingModel):
             pos = jnp.arange(s, dtype=jnp.int32)
             mask = block_causal_mask(pos, pos, block_length)
             sink = []
-            x = self.embed_tokens(Tensor(ids_arr))
+            x = self._embed(Tensor(ids_arr))
             last = len(self.layers) - 1
             for i, blk in enumerate(self.layers):
-                x = self._layer(blk, x, mask, kernel_mode=mode,
-                                kv_sink=sink, attention=i < last)
+                with jax.named_scope(f"layers.{i}"):
+                    x = self._layer(blk, x, mask, kernel_mode=mode,
+                                    kv_sink=sink, attention=i < last)
             new_k, new_v = [], []
             # row by row, the padding to the null block: a scatter of
             # whole [16, 4, 128] pages makes the v5e compiler re-lay the
             # pool out and copy it twice a layer (PERF.md, PR 28)
             zero = jnp.int32(0)
             for i, (k, v) in enumerate(sink):
-                kp, vp = paged_prefill_write_masked(
-                    k_pools[i], v_pools[i], row, k._data[0], v._data[0],
-                    zero, zero, n)
+                # the cache write is the attention sublayer's
+                with jax.named_scope(f"layers.{i}"), _scope("attn"):
+                    kp, vp = paged_prefill_write_masked(
+                        k_pools[i], v_pools[i], row, k._data[0],
+                        v._data[0], zero, zero, n)
                 new_k.append(kp)
                 new_v.append(vp)
             return new_k, new_v, k_scales, v_scales
@@ -342,35 +359,50 @@ class SDAR(PagedServingModel):
                       self._table_row(cache, slot)))
             cache.seq_lens[slot] = total
 
-    def _build_extend(self, quantized, mode):
-        block_length = self.config.block_length
+    def _extend_layer(self, blk, x, i, last, new_k, new_v, k_pool, v_pool,
+                      row, t_start, w_start, t_total, mode):
+        """Layer ``i`` of the tail-extend program on the tail ``x``: its
+        keys and values written row by row, then (unless ``last``) the
+        tail attended over the slot's paged context and the expert
+        layer."""
+        from ..inference.paged import (paged_prefill_write_masked,
+                                       paged_prefix_attention_dense)
+        attn = blk.self_attn
+        with jax.named_scope(f"layers.{i}"):
+            with _scope("residual"):
+                u = blk.input_layernorm(x)
+            with _scope("attn"), jax.named_scope(attn._name_scope):
+                q, k, v = attn.qkv(u, position_offset=t_start)
+                kp, vp = paged_prefill_write_masked(
+                    k_pool, v_pool, row, k._data[0], v._data[0], t_start,
+                    w_start, t_total)
+                new_k.append(kp)
+                new_v.append(vp)
+                if last:
+                    return x
+                out = attn.out(Tensor(paged_prefix_attention_dense(
+                    q._data[0], kp, vp, row, t_start, t_total,
+                    block_len=self.config.block_length)[None]))
+            with _scope("residual"):
+                h = x + out
+                m = blk.post_attention_layernorm(h)
+            with _scope("ffn"):
+                out = blk.mlp(m, kernel_mode=mode)
+            with _scope("residual"):
+                return h + out
 
+    def _build_extend(self, quantized, mode):
         def body(tail_ids, t_start, w_start, t_total, row, k_pools,
                  v_pools, k_scales, v_scales):
-            from ..inference.paged import (paged_prefill_write_masked,
-                                           paged_prefix_attention_dense)
             new_k, new_v = [], []
-            x = self.embed_tokens(Tensor(tail_ids))
+            x = self._embed(Tensor(tail_ids))
             last = len(self.layers) - 1
             # not ``_paged_stack``: the last layer stops at its keys and
             # values, as the prefill's does
             for i, blk in enumerate(self.layers):
-                attn = blk.self_attn
-                q, k, v = attn.qkv(blk.input_layernorm(x),
-                                   position_offset=t_start)
-                kp, vp = paged_prefill_write_masked(
-                    k_pools[i], v_pools[i], row, k._data[0],
-                    v._data[0], t_start, w_start, t_total)
-                new_k.append(kp)
-                new_v.append(vp)
-                if i == last:
-                    break
-                out = paged_prefix_attention_dense(
-                    q._data[0], kp, vp, row, t_start, t_total,
-                    block_len=block_length)
-                h = x + attn.out(Tensor(out[None]))
-                x = h + blk.mlp(blk.post_attention_layernorm(h),
-                                kernel_mode=mode)
+                x = self._extend_layer(
+                    blk, x, i, i == last, new_k, new_v, k_pools[i],
+                    v_pools[i], row, t_start, w_start, t_total, mode)
             return new_k, new_v, k_scales, v_scales
         return self._as_program(body, "sdar.paged_extend", 6, mode=mode)
 
@@ -469,25 +501,27 @@ class SDAR(PagedServingModel):
                 return y
 
             x, new, _ = self._paged_stack(
-                self.embed_tokens(Tensor(ids)), lens,
+                self._embed(Tensor(ids)), lens,
                 (k_pools, v_pools, k_scales, v_scales),
                 lambda kp, vp, k, v: paged_spec_write(
                     kp, vp, tables, lens, k, v, whole, active),
                 lambda q, kp, vp: paged_block_attention(
                     q, kp, vp, tables, seen, kernel_mode=mode),
                 mlp=experts)
-            # float32 logits straight off the MXU's accumulator: a
-            # bfloat16 round of them would move a probability by 2 %
-            logits = jnp.matmul(x._data, self.lm_head.weight._data,
-                                preferred_element_type=jnp.float32)
-            top = jnp.max(logits, axis=-1)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            prob = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
-            # the rule ranks the very float32 probabilities the host is
-            # handed
-            after, picked = low_confidence_static(
-                ids, masked, opened, denoised, tok, prob, active,
-                cfg.denoise_steps, cfg.mask_token_id)
+            with _scope("head"):
+                # float32 logits straight off the MXU's accumulator: a
+                # bfloat16 round of them would move a probability by 2 %
+                logits = jnp.matmul(x._data, self.lm_head.weight._data,
+                                    preferred_element_type=jnp.float32)
+                top = jnp.max(logits, axis=-1)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                prob = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]),
+                                     axis=-1)
+                # the rule ranks the very float32 probabilities the host
+                # is handed
+                after, picked = low_confidence_static(
+                    ids, masked, opened, denoised, tok, prob, active,
+                    cfg.denoise_steps, cfg.mask_token_id)
             f32 = jnp.float32
             packed = jnp.concatenate(
                 [a.astype(f32).reshape(-1)

@@ -69,6 +69,7 @@ from ..inference.paged import LatentRowSpec
 from ..kernels.pallas.mla_decode import mla_decode_routed, in_lanes
 from ..nn.initializer import Constant, Initializer
 from ..profiler.tracing import phase as _phase
+from ..profiler.tracing import scope as _scope
 from .llama import LlamaMLP, PagedServingModel, _normal_attr
 
 __all__ = ["Xing", "XingConfig", "yarn_inv_freq", "sinkhorn"]
@@ -457,8 +458,9 @@ class Xing(PagedServingModel):
 
     def _streams(self, x):
         """``X_0``: the embedding [b, s, d] repeated a stream."""
-        return Tensor(jnp.repeat(x._data[:, :, None],
-                                 self.config.hc_mult, axis=2))
+        with _scope("residual"):
+            return Tensor(jnp.repeat(x._data[:, :, None],
+                                     self.config.hc_mult, axis=2))
 
     def residual_read(self, blk, sublayer, x):
         hc = blk.hc_mlp if sublayer else blk.hc_attn
@@ -511,17 +513,25 @@ class Xing(PagedServingModel):
     def forward(self, input_ids, kernel_mode=None):
         """Logits [b, s, vocab] of ``input_ids`` [b, s], positions from
         0."""
-        x = self._streams(self.embed_tokens(input_ids))
-        for blk in self.layers:
-            u, mixed = self.residual_read(blk, 0, x)
-            x = self.residual_write(
-                blk, 0, x, blk.self_attn(blk.input_layernorm(u)), mixed)
-            u, mixed = self.residual_read(blk, 1, x)
-            x = self.residual_write(
-                blk, 1, x, self._ffn(
-                    blk, blk.post_attention_layernorm(u), kernel_mode),
-                mixed)
-        return self.lm_head(self.norm(self.residual_close(x)))
+        x = self._streams(self._embed(input_ids))
+        for n, blk in enumerate(self.layers):
+            with jax.named_scope(f"layers.{n}"):
+                with _scope("residual"):
+                    u, mixed = self.residual_read(blk, 0, x)
+                    h = blk.input_layernorm(u)
+                with _scope("attn"):
+                    out = blk.self_attn(h)
+                with _scope("residual"):
+                    x = self.residual_write(blk, 0, x, out, mixed)
+                    u, mixed = self.residual_read(blk, 1, x)
+                    h = blk.post_attention_layernorm(u)
+                with _scope("ffn"):
+                    out = self._ffn(blk, h, kernel_mode)
+                with _scope("residual"):
+                    x = self.residual_write(blk, 1, x, out, mixed)
+        with _scope("residual"):
+            x = self.norm(self.residual_close(x))
+        return self._logits(x)
 
     def generate(self, input_ids, max_new_tokens=32):
         """Greedy continuation of ``input_ids`` [1, s] by the whole
@@ -584,7 +594,7 @@ class Xing(PagedServingModel):
             return next(layer_of).self_attn.expanded(q, rows[None], mask)
 
         return self._paged_stack(
-            self._streams(self.embed_tokens(Tensor(ids))), t_start, pools,
+            self._streams(self._embed(Tensor(ids))), t_start, pools,
             write, attend,
             mlp=lambda blk, m: self._ffn(blk, m, mode, valid=valid))
 
@@ -773,7 +783,7 @@ class Xing(PagedServingModel):
             self.__dict__["_tap"] = taps
             try:
                 hidden, new, _ = self._paged_stack(
-                    self._streams(self.embed_tokens(Tensor(toks[:b, None]))),
+                    self._streams(self._embed(Tensor(toks[:b, None]))),
                     lens, (row_pools, no_v, no_ks, no_vs),
                     lambda pool, rows: (latent_decode_write(
                         pool, tables, lens, rows[:, 0], active),),

@@ -52,6 +52,24 @@ class LayerList(Layer):
             for i, layer in enumerate(sublayers):
                 self.add_sublayer(str(i), layer)
 
+    def _named(self, name):
+        """A list is iterated, never called, so its own name would be
+        in no operation's path: its children carry it with their index
+        (``layers.3``)."""
+        super()._named(name)
+        for i, layer in self._sub_layers.items():
+            if layer is not None:
+                layer._named(self._child_name(i))
+
+    def _child_name(self, i):
+        return f"{self._name_scope}.{i}" if self._name_scope else str(i)
+
+    def add_sublayer(self, name, sublayer):
+        super().add_sublayer(name, sublayer)
+        if sublayer is not None:
+            sublayer._named(self._child_name(name))
+        return sublayer
+
     def __getitem__(self, idx):
         if isinstance(idx, slice):
             return LayerList(list(self._sub_layers.values())[idx])
@@ -72,6 +90,7 @@ class LayerList(Layer):
         self._sub_layers.clear()
         for i, layer in enumerate(layers):
             self._sub_layers[str(i)] = layer
+        self._named(self._name_scope)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -89,6 +108,7 @@ class LayerList(Layer):
         self._sub_layers.clear()
         for i, l in enumerate(layers):
             self._sub_layers[str(i)] = l
+        self._named(self._name_scope)
 
     def extend(self, layers):
         for layer in layers:

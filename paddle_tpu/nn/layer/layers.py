@@ -9,7 +9,9 @@ from __future__ import annotations
 import collections
 from typing import Callable, Iterator
 
+import jax
 import numpy as np
+from jax._src.core import trace_state_clean as _trace_state_clean
 
 from ...core import dtype as dtype_mod
 from ...core.tensor import Parameter, Tensor
@@ -35,7 +37,10 @@ class Layer:
         self._forward_pre_hooks = collections.OrderedDict()
         self._forward_post_hooks = collections.OrderedDict()
         self._hook_id = 0
-        self._name_scope = name_scope or type(self).__name__.lower()
+        # the name this layer is registered under in its parent (none
+        # for a root): the segment ``__call__`` adds to the path of the
+        # operations traced inside it
+        self._name_scope = name_scope
 
     # -- registration ------------------------------------------------------
     def __setattr__(self, name, value):
@@ -60,6 +65,7 @@ class Layer:
             if name not in layers:
                 _strip(self, name)
             layers[name] = value
+            value._named(name)
         elif params is not None and name in params:
             if value is None:
                 params.pop(name)
@@ -99,7 +105,15 @@ class Layer:
         if not isinstance(sublayer, Layer) and sublayer is not None:
             raise TypeError("sublayer must be a Layer")
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            sublayer._named(str(name))
         return sublayer
+
+    def _named(self, name):
+        """This layer was registered under ``name`` in a parent: what
+        ``__call__`` names its operations by in a traced program. A layer
+        shared by two parents keeps the name given last."""
+        object.__setattr__(self, "_name_scope", name)
 
     def add_parameter(self, name, parameter):
         if parameter is not None and not isinstance(parameter, Parameter):
@@ -241,6 +255,15 @@ class Layer:
         raise NotImplementedError
 
     def __call__(self, *inputs, **kwargs):
+        # a traced program's operations carry the layer's name in their
+        # path (``.../self_attn/o_proj/dot_general``); an eager call has
+        # no path to carry, and does not pay for the scope
+        if self._name_scope is None or _trace_state_clean():
+            return self._run(inputs, kwargs)
+        with jax.named_scope(self._name_scope):
+            return self._run(inputs, kwargs)
+
+    def _run(self, inputs, kwargs):
         for hook in list(self._forward_pre_hooks.values()):
             result = hook(self, inputs)
             if result is not None:

@@ -53,6 +53,17 @@ device's operations, on the same clock (``benchmarks/span_reduce.py``
 reads idle-gap owners from it), and on exit it adds its elapsed time to
 the registry histogram ``serving.phase.<name>_us``. The request spans
 above carry the ``step`` of the ``serving.step`` phase that ran them.
+
+**Scopes** name the inside of a compiled program. ``scope(component)``
+enters ``jax.named_scope("pt.<component>")`` for one of ``SCOPE_NAMES``:
+every operation traced inside carries the path in its ``op_name``
+(``jit(llama_paged_decode)/layers.3/pt.attn/self_attn/o_proj/
+dot_general``), which a profiler trace shows beside the operation and
+``benchmarks/scope_reduce.py`` splits the device's busy time by. A
+scope is metadata of the traced operations: it exists while a program
+is traced, and the compiled program is the same with or without it.
+The detail beneath a component is ``Layer.__call__``'s, which enters
+the name a layer was registered under in its parent.
 """
 
 from __future__ import annotations
@@ -64,6 +75,7 @@ import random
 import threading
 import time
 
+from jax import named_scope as _named_scope
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from ..core import flags as flags_mod
@@ -71,7 +83,7 @@ from . import metrics as _metrics
 
 __all__ = ["Span", "start_trace", "span", "record_span", "phase",
            "PHASE_NAMES", "BLOCK_PHASE_NAMES", "phase_histogram_name",
-           "attach",
+           "scope", "SCOPE_NAMES", "SCOPE_MARK", "attach",
            "current_context", "current_trace_id", "get_trace",
            "trace_ids", "export_trace", "export_ring", "records",
            "enabled", "reset"]
@@ -378,6 +390,26 @@ class phase:  # noqa: N801 — used as a function: ``with phase(name):``
         if self._hist is not None:
             self._hist.observe(dur_us)
         return False
+
+
+# -- scopes: the parts of a model inside a compiled program -------------------
+
+# the names are the contract (docs/OBSERVABILITY.md "Scopes inside a
+# program"; benchmarks/scope_reduce.py splits busy time by them)
+SCOPE_NAMES = ("attn", "ffn", "mixer", "residual", "head", "optimizer")
+# what a component's segment of an ``op_name`` starts with: no layer is
+# registered under a name with a dot in it, so a reader cannot mistake
+# an attribute for a component
+SCOPE_MARK = "pt."
+_SCOPE_SEGMENT = {n: SCOPE_MARK + n for n in SCOPE_NAMES}
+
+
+def scope(component):
+    """Name what is traced inside the block as part of ``component``
+    (one of ``SCOPE_NAMES``): the name-stack entry ``pt.<component>``
+    and nothing else. Where scopes nest, the innermost component owns
+    the operation."""
+    return _named_scope(_SCOPE_SEGMENT[component])  # KeyError: not catalogued
 
 
 @contextlib.contextmanager

@@ -104,13 +104,18 @@ def test_bench_parent_touches_no_backend(monkeypatch, capsys):
                                    if n != "head"}
 
 
-def test_compile_cache_env_set_writes_no_config(monkeypatch):
+# the named scopes of a program live in its operations' metadata, which
+# jax leaves out of the cache's key unless told otherwise
+_METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
+def test_compile_cache_env_set_writes_no_directory(monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
     wrote = []
     monkeypatch.setattr(jax.config, "update",
                         lambda *a: wrote.append(a))
     assert compile_cache.configure_compile_cache() == "/somewhere/else"
-    assert not wrote
+    assert wrote == [_METADATA_IN_KEY]
 
 
 def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
@@ -121,4 +126,5 @@ def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
     want = os.path.join(REPO, ".jax_compile_cache")
     assert compile_cache.configure_compile_cache() == want
     assert compile_cache.configure_compile_cache() == want  # no pid, no time
-    assert wrote == [("jax_compilation_cache_dir", want)] * 2
+    assert wrote == [_METADATA_IN_KEY,
+                     ("jax_compilation_cache_dir", want)] * 2
