@@ -49,10 +49,11 @@ window's: every slot live, a step ahead. Then, against
     bfloat16 activations move the logits by more than a row kept in 8
     bits, a bfloat16 router or five Sinkhorn rounds would.
 
-``reference.LIMITS`` says which readings each limit lies between. A
-traced run and the rehearsal also read the same records against the
-reference made to get something wrong (``reference.FAULTS``): notes
-only; PERF.md section 6 (PR 34) has the readings.
+``reference.LIMITS`` says which readings each limit lies between. The
+rehearsal also reads the same records against the reference made to get
+something wrong (``reference.FAULTS``): notes only, and not in a run on
+the chip, where they took a traced run past its time (PERF.md, section
+6, has the chip's readings of them).
 """
 
 from __future__ import annotations
@@ -90,6 +91,16 @@ def xing_config(fields):
     known = {f.name for f in dataclasses.fields(XingConfig)}
     named = {_RENAMED.get(k, k): v for k, v in fields.items()}
     return XingConfig(**{k: v for k, v in named.items() if k in known})
+
+
+def weights_seed(workload, seed):
+    """The key the weights are drawn from: the workload's ``weights_seed``
+    where it names one, else the run's ``seed``. Random weights decide
+    which experts the served tokens are routed to, and so how many
+    experts' weights a decode step reads: a workload that fixes the key
+    serves the same work on every seed, which then draws the prompts, the
+    warm-up and the check's sample (PERF.md, section 4)."""
+    return int(workload.get("weights_seed", seed))
 
 
 class _Sampled(list):
@@ -297,7 +308,8 @@ def run(run):
     # the route counters move when a program is traced
     kernels_before = metrics.snapshot("serving.kernel.")
     degrade_before = metrics.snapshot("resilience.degrade.")
-    model = build_model(Xing, xing_config(fields), dtype, run.seed)
+    model = build_model(Xing, xing_config(fields), dtype,
+                        weights_seed(cell.workload, run.seed))
     model.eval()
     mark("build_s")
     engine = ServingEngine(
@@ -372,7 +384,7 @@ def run(run):
             engine, model, fields, mix, shape,
             int(cell.workload.get("check_output", 512)),
             int(cell.workload.get("tap_steps", 6)),
-            run.trace or run.rehearsal)
+            run.rehearsal)
         ref["seconds"] = time.perf_counter() - t_ref
     finally:
         engine.close()

@@ -148,28 +148,34 @@ def _read(name, ctx):
 def test_the_fixture_manifest_mirrors_the_real_cell():
     fixture, real = _json(MANIFEST), _json(harness.MANIFEST)
     assert harness.manifest_problems(fixture) == []
-    mine = {m["name"]: m for m in real["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert len(mine) == 23 and all(n.endswith(".sdar") for n in mine)
+    # one entry a family and moved metric, which lists every cell that
+    # reads it: the fixture's entries are the real cell's, each listing
+    # the fixture's cell alone
+    mine = {m["name"]: dict(m, workloads=None) for m in real["per_layer"]
+            if REAL_CELL in m.get("workloads", ())}
     assert all(m["moves"] == "serve_tok_s" for m in mine.values())
-    assert {m["name"]: dict(m, workloads=[REAL_CELL])
+    assert all(m["workloads"] == [CELL] for m in fixture["per_layer"])
+    assert {m["name"]: dict(m, workloads=None)
             for m in fixture["per_layer"]} == mine
     cell = harness.load_cell(harness.MANIFEST, REAL_CELL)
     assert cell.workload["driver"] == "serve_blockdiff"
     assert {m["name"] for m in cell.end_to_end} == {"serve_tok_s", "setup_s"}
-    for m in real["per_layer"]:  # no other cell reads the new families
+    cells = [w["name"] for w in real["workloads"]]
+    for m in real["per_layer"]:  # no cell before it reads the new families
         if m["name"].split(".")[0] in (
                 "forwards_per_block", "moe_rows_per_expert",
                 "moe_gmm_busy_share", "moe_gmm_hbm_roofline",
                 "block_attn_hbm_roofline", "block_attn_busy_share",
                 "blockdiff_prefill_busy_share", "step_mfu"):
-            assert m["workloads"] == [REAL_CELL]
+            assert not set(m.get("workloads", cells)) & set(
+                cells[:cells.index(REAL_CELL)])
     for name, layer, better, unit in (
-            ("moe_gmm_hbm_roofline.sdar", "kernels", "higher", "%"),
-            ("block_attn_hbm_roofline.sdar", "kernels", "higher", "%"),
-            ("step_mfu.sdar", "model step", "higher", "%"),
-            ("forwards_per_block.sdar", "scheduler", "lower", "count"),
-            ("moe_rows_per_expert.sdar", "expert layer", "higher", "count")):
+            ("moe_gmm_hbm_roofline.serve", "kernels", "higher", "%"),
+            ("block_attn_hbm_roofline.serve", "kernels", "higher", "%"),
+            ("step_mfu.serve", "model step", "higher", "%"),
+            ("forwards_per_block.serve", "scheduler", "lower", "count"),
+            ("moe_rows_per_expert.serve", "expert layer", "higher",
+             "count")):
         assert (mine[name]["layer"], mine[name]["better"],
                 mine[name]["unit"]) == (layer, better, unit)
 
@@ -506,9 +512,10 @@ def test_rehearsal_runs_the_block_diffusion_driver_end_to_end(
     cell = harness.load_cell(MANIFEST, CELL)
     if trace:
         # what needs no device trace is read from the counters
-        assert {"forwards_per_block.sdar", "moe_rows_per_expert.sdar",
-                "decoded_per_step.sdar", "sched_step_mean_ms.sdar",
-                "kv_used_share.sdar", "kv_donated_share.sdar"} \
+        assert {"forwards_per_block.serve", "moe_rows_per_expert.serve",
+                "decoded_per_step.serve", "sched_step_mean_ms.serve",
+                "kv_used_share.serve", "kv_donated_share.serve",
+                "decode_ahead_share.serve"} \
             <= set(last["rehearsal"]["would_report"]) \
             <= {m["name"] for m in cell.per_layer}
     else:

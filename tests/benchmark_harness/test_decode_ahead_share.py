@@ -41,12 +41,29 @@ def test_the_real_manifest_lists_decode_ahead_share_in_the_mistral_cells():
         manifest = json.load(f)
     assert not harness.manifest_problems(manifest)
     by = {m["name"]: m for m in manifest["per_layer"]}
-    for tag, cell, moves in (
-            ("sat", "mistral7b-batch-saturated", "serve_tok_s"),
-            ("steady", "mistral7b-chat-steady", "itl_p95_ms")):
+    # the block path's dispatches run ahead too: its cell is listed
+    for tag, cells, moves in (
+            ("serve", ["mistral7b-batch-saturated",
+                       "sdar30b-blockdiff-saturated",
+                       "jamba3b-reasoning-saturated",
+                       "xing29b-reasoning-saturated"], "serve_tok_s"),
+            ("steady", ["mistral7b-chat-steady"], "itl_p95_ms")):
         m = by["decode_ahead_share." + tag]
-        assert m["workloads"] == [cell] and m["moves"] == moves
+        assert m["workloads"] == cells and m["moves"] == moves
         assert (m["unit"], m["better"], m["source"], m["layer"]) == (
             "%", "higher", "program_counter", "scheduler")
-    # the block path is in order by construction: no entry for its cell
-    assert "decode_ahead_share.sdar" not in by
+
+
+def test_the_block_path_gives_a_share():
+    """The block-diffusion fixture's cell in rehearsal: its counters hold
+    the block steps' dispatches, and the reader makes a share of them."""
+    fixture = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "fixtures", "blockdiff", "BENCHMARK.json")
+    line, ctx, _notes = harness.run_cell(fixture, "tiny-blockdiff",
+                                         2**31 + 29, 1.5, False, True)
+    assert line["correct"]
+    counters = ctx["counters"]
+    assert counters["serving.decode.ahead"] \
+        + counters.get("serving.decode.in_order", 0) > 0
+    share = _read("decode_ahead_share.serve", ctx)
+    assert share is not None and 0 <= share <= 100

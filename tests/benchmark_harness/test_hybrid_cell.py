@@ -149,32 +149,44 @@ def test_the_fixture_manifest_mirrors_the_real_cell():
     fixture, real = _json(MANIFEST), _json(harness.MANIFEST)
     assert harness.manifest_problems(fixture) == []
     assert harness.manifest_problems(real) == []
-    mine = {m["name"]: m for m in real["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert len(mine) == 25 and all(n.endswith(".jamba") for n in mine)
+    # one entry a family and moved metric, which lists every cell that
+    # reads it: the fixture's entries are the real cell's, each listing
+    # the fixture's cell alone
+    mine = {m["name"]: dict(m, workloads=None) for m in real["per_layer"]
+            if REAL_CELL in m.get("workloads", ())}
     assert all(m["moves"] == "serve_tok_s" for m in mine.values())
-    assert {m["name"]: dict(m, workloads=[REAL_CELL])
+    assert all(m["workloads"] == [CELL] for m in fixture["per_layer"])
+    assert {m["name"]: dict(m, workloads=None)
             for m in fixture["per_layer"]} == mine
     cell = harness.load_cell(harness.MANIFEST, REAL_CELL)
     assert cell.workload["driver"] == "serve_hybrid" and cell.chips == 1
     assert {m["name"] for m in cell.end_to_end} == {"serve_tok_s", "setup_s"}
-    for m in real["per_layer"]:  # no other cell reads the new families
+    cells = [w["name"] for w in real["workloads"]]
+    for m in real["per_layer"]:  # no cell before it reads the new families
         if m["name"].split(".")[0] in NEW_FAMILIES:
-            assert m["workloads"] == [REAL_CELL]
+            assert not set(m.get("workloads", cells)) & set(
+                cells[:cells.index(REAL_CELL)])
     for name, layer, better in (
-            ("ssm_update_hbm_roofline.jamba", "kernels", "higher"),
-            ("ssm_scan_hbm_roofline.jamba", "kernels", "higher"),
-            ("ssm_update_busy_share.jamba", "kernels", "lower"),
-            ("decode_step_mfu.jamba", "model step", "higher"),
-            ("decode_step_hbm_roofline.jamba", "model step", "higher"),
-            ("hybrid_prefill_busy_share.jamba", "model step", "lower")):
+            ("ssm_update_hbm_roofline.serve", "kernels", "higher"),
+            ("ssm_scan_hbm_roofline.serve", "kernels", "higher"),
+            ("ssm_update_busy_share.serve", "kernels", "lower"),
+            ("decode_step_mfu.serve", "model step", "higher"),
+            ("decode_step_hbm_roofline.serve", "model step", "higher"),
+            ("hybrid_prefill_busy_share.serve", "model step", "lower")):
         assert (mine[name]["layer"], mine[name]["better"],
                 mine[name]["unit"]) == (layer, better, "%")
     # what the accepted benchmark had is as it was: entries were appended
-    assert [w["name"] for w in real["workloads"]][-1] == REAL_CELL
-    assert [c["name"] for c in real["configs"]][-1] == "ai21-jamba2-3b"
-    assert next(m for m in real["end_to_end"] if m["name"]
-                == "serve_tok_s")["workloads"][-1] == REAL_CELL
+    # behind the block-diffusion cell's (a later cell goes behind these in
+    # turn, so nothing here pins the lists' ends)
+    assert cells.index(REAL_CELL) == cells.index(
+        "sdar30b-blockdiff-saturated") + 1
+    configs = [c["name"] for c in real["configs"]]
+    assert configs.index("ai21-jamba2-3b") == configs.index(
+        "sdar-30b-a3b-chat") + 1
+    serving = next(m for m in real["end_to_end"]
+                   if m["name"] == "serve_tok_s")["workloads"]
+    assert serving.index(REAL_CELL) == serving.index(
+        "sdar30b-blockdiff-saturated") + 1
 
 
 def test_the_real_cell_is_the_issues_traffic_and_configuration():
@@ -424,9 +436,9 @@ def test_rehearsal_runs_the_hybrid_driver_end_to_end(capsys, tmp_path,
     assert last["metrics"] == {} and "breakdown" not in last
     cell = harness.load_cell(MANIFEST, CELL)
     # what needs no device trace is read from the counters
-    assert {"decoded_per_step.jamba", "sched_step_mean_ms.jamba",
-            "kv_used_share.jamba", "kv_donated_share.jamba",
-            "decode_ahead_share.jamba", "host_ms_per_step.jamba"} \
+    assert {"decoded_per_step.serve", "sched_step_mean_ms.serve",
+            "kv_used_share.serve", "kv_donated_share.serve",
+            "decode_ahead_share.serve", "host_ms_per_step.serve"} \
         <= set(last["rehearsal"]["would_report"]) \
         <= {m["name"] for m in cell.per_layer}
     notes = notes["notes"]

@@ -37,10 +37,13 @@ def test_kv_donated_share_is_donated_over_all_pool_writing_calls(
 def test_the_real_manifest_lists_kv_donated_share_in_the_serving_cells():
     with open(harness.MANIFEST) as f:
         by = {m["name"]: m for m in json.load(f)["per_layer"]}
-    for tag, cell, moves in (
-            ("sat", "mistral7b-batch-saturated", "serve_tok_s"),
-            ("steady", "mistral7b-chat-steady", "itl_p95_ms")):
+    for tag, cells, moves in (
+            ("serve", ["mistral7b-batch-saturated",
+                       "sdar30b-blockdiff-saturated",
+                       "jamba3b-reasoning-saturated",
+                       "xing29b-reasoning-saturated"], "serve_tok_s"),
+            ("steady", ["mistral7b-chat-steady"], "itl_p95_ms")):
         m = by["kv_donated_share." + tag]
-        assert m["workloads"] == [cell] and m["moves"] == moves
+        assert m["workloads"] == cells and m["moves"] == moves
         assert (m["unit"], m["better"], m["source"], m["layer"]) == (
             "%", "higher", "program_counter", "KV cache")

@@ -171,16 +171,19 @@ def test_the_fixture_manifest_mirrors_the_real_cell():
     fixture, real = _json(MANIFEST), _json(harness.MANIFEST)
     assert harness.manifest_problems(fixture) == []
     assert harness.manifest_problems(real) == []
-    mine = {m["name"]: m for m in real["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert len(mine) == 23 and all(n.endswith(".xing") for n in mine)
+    # one entry a family and moved metric, which lists every cell that
+    # reads it: the fixture's entries are the real cell's, each listing
+    # the fixture's cell alone
+    mine = {m["name"]: dict(m, workloads=None) for m in real["per_layer"]
+            if REAL_CELL in m.get("workloads", ())}
     assert all(m["moves"] == "serve_tok_s" for m in mine.values())
-    assert {m["name"]: dict(m, workloads=[REAL_CELL])
+    assert all(m["workloads"] == [CELL] for m in fixture["per_layer"])
+    assert {m["name"]: dict(m, workloads=None)
             for m in fixture["per_layer"]} == mine
     cell = harness.load_cell(harness.MANIFEST, REAL_CELL)
     assert cell.workload["driver"] == "serve_latent" and cell.chips == 1
     assert {m["name"] for m in cell.end_to_end} == {"serve_tok_s", "setup_s"}
-    assert {f + ".xing" for f in NEW_FAMILIES} <= set(mine)
+    assert {f + ".serve" for f in NEW_FAMILIES} <= set(mine)
     # the families whose count would be wrong here are not reported
     families = {n.split(".")[0] for n in mine}
     assert not families & {"paged_attn_busy_share",
@@ -188,11 +191,11 @@ def test_the_fixture_manifest_mirrors_the_real_cell():
                            "moe_gmm_hbm_roofline", "decode_step_mfu",
                            "decode_step_hbm_roofline"}
     for name, layer, better in (
-            ("mla_attn_hbm_roofline.xing", "kernels", "higher"),
-            ("sparse_gmm_hbm_roofline.xing", "kernels", "higher"),
-            ("mla_attn_busy_share.xing", "kernels", "lower"),
-            ("mla_step_mfu.xing", "model step", "higher"),
-            ("mla_step_hbm_roofline.xing", "model step", "higher")):
+            ("mla_attn_hbm_roofline.serve", "kernels", "higher"),
+            ("sparse_gmm_hbm_roofline.serve", "kernels", "higher"),
+            ("mla_attn_busy_share.serve", "kernels", "lower"),
+            ("mla_step_mfu.serve", "model step", "higher"),
+            ("mla_step_hbm_roofline.serve", "model step", "higher")):
         assert (mine[name]["layer"], mine[name]["better"],
                 mine[name]["unit"], mine[name]["source"]) \
             == (layer, better, "%", "device_trace")
@@ -262,6 +265,30 @@ def test_the_real_cell_is_the_issues_traffic_and_configuration():
             built.kv_lora_rank, built.hc_mult) == (6, 1, 32, 512, 4)
     assert built.rope_scaling["factor"] == 64
     assert abs(built.softmax_scale - 0.14468) < 1e-5
+
+
+def test_the_real_cell_serves_one_set_of_weights_on_every_seed(monkeypatch):
+    cell = harness.load_cell(harness.MANIFEST, REAL_CELL)
+    driver = harness.load_module(cell.driver_path)
+    fixed = cell.workload["weights_seed"]
+    assert isinstance(fixed, int) and 0 <= fixed < 2 ** 64
+    assert {driver.weights_seed(cell.workload, s)
+            for s in (1, 2 ** 31 + 23, 3910003003)} == {fixed}
+    # a workload that names no key draws the weights from the run's seed
+    fixture = harness.load_cell(MANIFEST, CELL)
+    assert driver.weights_seed(fixture.workload, 7) == 7
+
+    class Built(Exception):
+        pass
+
+    def build(_cls, _config, _dtype, seed):
+        raise Built(seed)
+
+    # the key the run's driver hands the builder is the workload's
+    monkeypatch.setattr(driver, "build_model", build)
+    with pytest.raises(Built) as got:
+        driver.run(harness.Run(cell, 2 ** 31 + 23, 1.0, False, True, None))
+    assert got.value.args == (fixed,)
 
 
 # -- the counts ------------------------------------------------------------------
@@ -430,10 +457,10 @@ def test_rehearsal_runs_the_latent_driver_end_to_end(capsys, tmp_path,
     # a rehearsal writes no number under a device metric's name
     assert last["metrics"] == {} and "breakdown" not in last
     cell = harness.load_cell(MANIFEST, CELL)
-    assert {"decoded_per_step.xing", "sched_step_mean_ms.xing",
-            "kv_used_share.xing", "kv_donated_share.xing",
-            "decode_ahead_share.xing", "host_ms_per_step.xing",
-            "moe_rows_per_expert.xing"} \
+    assert {"decoded_per_step.serve", "sched_step_mean_ms.serve",
+            "kv_used_share.serve", "kv_donated_share.serve",
+            "decode_ahead_share.serve", "host_ms_per_step.serve",
+            "moe_rows_per_expert.serve"} \
         <= set(last["rehearsal"]["would_report"]) \
         <= {m["name"] for m in cell.per_layer}
     notes = notes["notes"]
@@ -449,7 +476,7 @@ def test_rehearsal_runs_the_latent_driver_end_to_end(capsys, tmp_path,
     assert set(ref["limits"]) | {"read"} == set(ref["worst"])
     # float32 on both sides of the maps and of the router
     assert ref["worst"]["mix"] < 1e-5 and ref["worst"]["router"] < 1e-5
-    # a rehearsal reads the planted faults as a traced run does, each in
+    # a rehearsal reads the planted faults, each in
     # the reading that is there for it (YaRN's factor of the scale is
     # seen at the published factor of 64, not at the fixture's 8)
     assert set(ref["planted"]) == set(reference.FAULTS) | {
